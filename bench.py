@@ -17,7 +17,7 @@ Baseline: the Go toolchain is not present in this image, so the reference's
 own benches cannot run here.  ``vs_baseline`` compares against the **host
 oracle path** (the faithful reimplementation of the reference algorithm on
 the same store) measured in this process on a proportionally scaled
-workload, normalized per decision.  See BASELINE.md.
+workload, normalized per decision.
 
 An end-to-end "phone-home" measurement (reference: cmd/swarm-bench) runs
 the full pipeline — control API -> orchestrator -> device scheduler ->
@@ -1771,15 +1771,9 @@ def run_e2e(n_agents=5,
     # which would refuse every e2e task and starve the attribution
     from swarmkit_tpu.obs.journey import journeys
     journeys.reset(sample_rate=1.0)
-    try:
-        mgr = Manager(dispatcher_config=Config_(
-            heartbeat_period=2.0, process_updates_interval=0.05,
-            assignment_batching_wait=0.05))
-    except ImportError as e:
-        # image without the `cryptography` package (ROADMAP env note):
-        # the manager's CA bootstrap is unavailable — report instead of
-        # failing the whole bench artifact
-        return {"error": f"skipped: {e}"}
+    mgr = Manager(dispatcher_config=Config_(
+        heartbeat_period=2.0, process_updates_interval=0.05,
+        assignment_batching_wait=0.05))
     mgr.run()
     agents = []
     try:
@@ -2106,6 +2100,12 @@ def main():
 
     tpu = TPUPlanner
 
+    import jax
+    devices = jax.devices()
+    device = {"platform": devices[0].platform,
+              "kind": devices[0].device_kind, "count": len(devices)}
+    print(f"bench: device {device}", file=sys.stderr)
+
     # warm the kernel compile cache for each (node-bucket, spread-level)
     # jit signature used below, outside the timed regions
     rack_pref = [PlacementPreference(
@@ -2425,6 +2425,8 @@ def main():
                   f"{N_NODES // 1000}k nodes (single tick, store-committed)",
         "value": round(tpu_dps, 1),
         "unit": "decisions/sec",
+        # what every number in this artifact ran on
+        "device": device,
         "vs_baseline": round(vs, 2),
         "tick_p50_s": round(med, 3),
         "tick_p99_s": round(ticks[-1], 3),
@@ -2439,7 +2441,7 @@ def main():
         if rep[1] else None,
         "trials": len(trials),
         "baseline": "host-oracle path, same store+commit framework "
-                    "(Go toolchain unavailable; see BASELINE.md)",
+                    "(Go toolchain unavailable)",
         "baseline_decisions_per_sec": round(host_dps, 1) if host_dps
         else None,
         "obs": obs_stats,
@@ -2505,6 +2507,7 @@ def _append_history(artifact):
         "metric": artifact["metric"],
         "value": artifact["value"],
         "unit": artifact["unit"],
+        "device": artifact["device"],
         "tick_p50_s": artifact["tick_p50_s"],
         "headline_variance_x": artifact["headline_variance_x"],
         "obs_overhead_pct": (artifact["obs"] or {}).get("overhead_pct"),

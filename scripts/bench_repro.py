@@ -21,6 +21,10 @@ Bar (each configurable):
 
 Exit status: 0 when every repeat holds the bar, 1 otherwise.
 
+One process per chip: this parent never imports JAX, and the bench
+children run one at a time, each holding the chip alone.  Keep it so —
+a parent that touched JAX would hold the chip its children need.
+
 Usage:
     python scripts/bench_repro.py              # 3 repeats, full bar
     python scripts/bench_repro.py --repeat 5
@@ -88,7 +92,8 @@ def check(artifact, args):
                                   or native.get("fallbacks")):
         problems.append(
             f"native commit plane fell back to Python ({native})")
-    row = {"headline": artifact.get("value"), "cfg6_dps": dps,
+    row = {"device": artifact.get("device"),
+           "headline": artifact.get("value"), "cfg6_dps": dps,
            "shape_cost_x": shape, "plan_hidden_frac": hidden,
            "pipeline_depth": depth, "commit_phase_s": commit_s,
            "native_commit": native}
@@ -122,6 +127,7 @@ def main(argv=None) -> int:
         row, problems = check(artifact, args)
         status = "ok" if not problems else "FAIL"
         print(f"run {i + 1}/{args.repeat}: {status}  "
+              f"device={row['device']}  "
               f"cfg6={row['cfg6_dps']:,.0f} dec/s  "
               f"shape_cost_x={row['shape_cost_x']}  "
               f"plan_hidden_frac={row['plan_hidden_frac']}  "

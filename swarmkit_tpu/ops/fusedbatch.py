@@ -37,9 +37,8 @@ from __future__ import annotations
 import os
 from typing import Callable, Dict, List, Optional, Tuple
 
+import jax
 import numpy as np
-
-from jax.experimental import enable_x64
 
 from ..models.objects import Task
 from ..models.types import PublishMode, TaskState
@@ -92,7 +91,7 @@ def split_hash(h: int) -> Tuple[int, int]:
 def x64():
     """The scoped-x64 guard every fused trace/dispatch/transfer runs
     under (int64 resource carry — see module docstring)."""
-    return enable_x64()
+    return jax.enable_x64(True)
 
 
 def default_chunk_groups() -> int:

@@ -177,7 +177,7 @@ class ResidentState:
                       "incremental": 0, "full": 0, "rows": 0,
                       "dirty_frac": 0.0, "device_syncs": 0,
                       "svc_evictions": 0, "bytes_avoided": 0,
-                      "shard_syncs": 0}
+                      "shard_syncs": 0, "scatter_failures": 0}
 
     # --------------------------------------------------------- mesh tier
 
@@ -731,6 +731,8 @@ class ResidentState:
                             u_mem, u_total)
         except Exception:
             log.exception("resident device scatter failed; re-uploading")
+            self.stats["scatter_failures"] += 1
+            _metrics.counter("swarm_streaming_scatter_failures")
             _devtel.note_retired(old_ids)   # buffers gone either way
             self.dev = None
             self._device_upload()
@@ -778,6 +780,8 @@ class ResidentState:
             "device_syncs": self.stats["device_syncs"],
             "bytes_avoided": self.stats["bytes_avoided"],
             "shard_syncs": self.stats["shard_syncs"],
+            "scatter_failures": self.stats["scatter_failures"],
+            "device_enabled": self.device_enabled,
             "mesh_devices": self._mesh_devices(),
         }
 

@@ -24,12 +24,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
 
 from ..ops.kernel import (
     FusedCarry, FusedGroups, FusedShared, FusedStrategy, GroupInputs,
@@ -113,15 +109,15 @@ def plan_group_sharded(nodes: NodeInputs, group: GroupInputs, L: int,
         hier_specs = (tuple((P(NODE_AXIS), P()) for _ in upper), P())
     else:
         hier_specs = ()
-    # check_rep=False: this jax version's replication checker mistypes the
-    # scan carry inside psum-reducing kernels (mismatched replication
-    # [None, set(), None] vs [None, set(), {'nodes'}]); the checker is
-    # advisory — the collectives themselves are unchanged
+    # check_vma=False: the kernel body is shared with the single-device
+    # program, whose loop carries start from replicated constants and
+    # pick up shard-varying values; typing that needs pvary casts the
+    # single-device trace has no axis for
     fn = shard_map(kernel, mesh=mesh,
                    in_specs=(_node_specs(nodes), _GROUP_SPECS,
                              hier_specs),
                    out_specs=(P(NODE_AXIS), P(), P()),
-                   check_rep=False)
+                   check_vma=False)
     return fn(nodes, group, hier)
 
 
@@ -152,13 +148,12 @@ def plan_strategy_sharded(nodes: NodeInputs, group: GroupInputs,
         return plan_strategy(nodes_l, group_l, sin_l, strategy,
                              reduce=reduce, idx_offset=offset)
 
-    # check_rep=False: same advisory-checker mistyping as
-    # plan_group_sharded (fori_loop carries inside psum kernels)
+    # check_vma=False: as in plan_group_sharded
     fn = shard_map(kernel, mesh=mesh,
                    in_specs=(_node_specs(nodes), _GROUP_SPECS,
                              _STRATEGY_SPECS),
                    out_specs=(P(NODE_AXIS), P(), P()),
-                   check_rep=False)
+                   check_vma=False)
     return fn(nodes, group, sin)
 
 
@@ -213,8 +208,7 @@ def plan_fused_sharded(shared: FusedShared, groups: FusedGroups,
         return plan_fused(shared_l, groups_l, carry_l, L, reduce=reduce,
                           idx_offset=offset, strat=strat_l)
 
-    # check_rep=False: same advisory-checker mistyping as
-    # plan_group_sharded above (scan carries inside psum kernels)
+    # check_vma=False: as in plan_group_sharded
     fn = shard_map(kernel, mesh=mesh,
                    in_specs=(_FUSED_SHARED_SPECS,
                              _fused_group_specs(groups),
@@ -223,7 +217,7 @@ def plan_fused_sharded(shared: FusedShared, groups: FusedGroups,
                              else None),
                    out_specs=(P(None, NODE_AXIS), P(), P(),
                               _FUSED_CARRY_SPECS),
-                   check_rep=False)
+                   check_vma=False)
     return fn(shared, groups, carry, strat)
 
 
@@ -293,7 +287,7 @@ def scatter_rows_sharded(valid, ready, cpu, mem, total, idx,
     fn = shard_map(kernel, mesh=mesh,
                    in_specs=(RESIDENT_SPEC,) * 5 + (SCATTER_SPEC,) * 6,
                    out_specs=(RESIDENT_SPEC,) * 5,
-                   check_rep=False)
+                   check_vma=False)
     return fn(valid, ready, cpu, mem, total, idx,
               u_valid, u_ready, u_cpu, u_mem, u_total)
 

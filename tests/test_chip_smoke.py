@@ -1,0 +1,157 @@
+"""The bring-up pieces, on the forced CPU at tiny sizes: where the
+compile cache goes, the resident scatter's failure counter, and
+chip_smoke.py turning every quiet retreat into a non-zero exit."""
+
+import json
+import logging
+import os
+import re
+import sys
+
+import jax
+import numpy as np
+import pytest
+
+from swarmkit_tpu.ops import TPUPlanner, planner as planner_mod
+from swarmkit_tpu.ops import streaming
+from swarmkit_tpu.scheduler import Scheduler
+from swarmkit_tpu.state import MemoryStore
+from swarmkit_tpu.utils import compilecache
+from swarmkit_tpu.utils.metrics import registry
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+import chip_smoke  # noqa: E402
+
+CPU = {"platform": "cpu", "kind": "cpu", "count": 1}
+
+
+# ------------------------------------------------------------ compile cache
+
+@pytest.fixture
+def cache_dir_config():
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        yield
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+
+def test_compile_cache_left_alone_when_placed_from_outside(
+        monkeypatch, tmp_path, cache_dir_config):
+    jax.config.update("jax_compilation_cache_dir", None)
+    monkeypatch.setenv(compilecache.ENV_VAR, str(tmp_path))
+    assert compilecache.ensure_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir is None
+
+
+def test_compile_cache_defaults_into_the_checkout_whatever_the_cwd(
+        monkeypatch, tmp_path, cache_dir_config):
+    monkeypatch.delenv(compilecache.ENV_VAR, raising=False)
+    first = compilecache.ensure_compile_cache()
+    monkeypatch.chdir(tmp_path)
+    second = compilecache.ensure_compile_cache()
+    assert first == second == os.path.join(REPO, ".jax_cache")
+    assert jax.config.jax_compilation_cache_dir == first
+    with open(os.path.join(REPO, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
+
+
+# ------------------------------------------------- scatter failure counter
+
+def test_failed_scatter_is_counted_and_reuploaded(monkeypatch):
+    store = MemoryStore()
+    store.update(lambda tx: [tx.create(n)
+                             for n in chip_smoke.make_nodes(8, seed=0)])
+    planner = TPUPlanner()
+    sched = Scheduler(store, batch_planner=planner, pipeline_depth=1)
+    store.view(sched._setup_tasks_list)
+    planner.begin_tick(sched)
+    planner.end_tick()
+    st = planner._streaming
+    info = sched.node_set.nodes["node-00000"]
+    info.available_resources.nano_cpus -= 12345
+    sched.delta.mark("node-00000")
+
+    def boom(*args):
+        raise RuntimeError("scatter refused")
+    monkeypatch.setattr(streaming, "_scatter_rows_jit", boom)
+    before = registry.get_counter("swarm_streaming_scatter_failures", 0)
+    st.refresh(sched)
+    assert st.stats["scatter_failures"] == 1
+    assert st.snapshot()["scatter_failures"] == 1
+    assert registry.get_counter(
+        "swarm_streaming_scatter_failures", 0) == before + 1
+    # the production guarantee stays: the tier re-uploaded and is right
+    row = st.row_of["node-00000"]
+    assert int(np.asarray(st.dev[2])[row]) == \
+        info.available_resources.nano_cpus
+
+
+# ------------------------------------------------------------- chip_smoke
+
+def test_smoke_refuses_to_run_without_a_tpu(capsys):
+    assert chip_smoke.main([]) != 0
+    out = capsys.readouterr().out.strip().splitlines()
+    assert out == ["device platform=cpu kind=cpu count=8"]
+
+
+def test_last_line_is_ok_and_device_and_nothing_else(capsys):
+    """What the driver parses: exactly ``ok`` and ``device``; the rest
+    of the report rides the ``summary:`` line above it."""
+    tpu = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+    smoke = chip_smoke.Smoke()
+    assert chip_smoke.verdict(smoke, tpu, {"nodes": 4, "claim": None}) == 0
+    *_, summary, last = capsys.readouterr().out.splitlines()
+    assert json.loads(last) == {"ok": True, "device": tpu}
+    assert summary.startswith("summary: ")
+    assert summary.endswith('"claim": null}')
+
+    smoke.fail("served: planner groups_device_error=1")
+    capsys.readouterr()
+    assert chip_smoke.verdict(smoke, tpu, {"claim": None}) != 0
+    captured = capsys.readouterr()
+    assert captured.out == ""
+    assert "groups_device_error=1" in captured.err
+
+
+def test_every_program_matches_its_oracle_on_the_cpu():
+    smoke = chip_smoke.Smoke()
+    chip_smoke.programs_phase(smoke, n_nodes=64, k=512, seed=3)
+    assert smoke.failures == []
+    assert [row["program"].split("/")[0] for row in smoke.programs] == [
+        "_scatter_rows_jit", "gang_fit_jit", "gang_fit_fused_jit",
+        "select_victims_jit", "plan_group_jit", "plan_group_jit",
+        "plan_strategy_jit", "plan_strategy_jit", "plan_strategy_jit",
+        "plan_fused_jit", "feasibility_jit"]
+    assert all(row["ok"] for row in smoke.programs)
+    assert smoke.exit_code() == 0
+
+
+def test_forced_retreats_become_a_nonzero_exit(monkeypatch, capsys):
+    """A device path that raises (groups_device_error, then a tripped
+    breaker) and a disabled native plane: the production path carries
+    on and places every task — the smoke must not."""
+    def boom(*args, **kwargs):
+        raise RuntimeError("device refused")
+    monkeypatch.setattr(TPUPlanner, "_call_plan_fn", boom)
+    monkeypatch.setattr(TPUPlanner, "_call_strategy_fn", boom)
+    monkeypatch.setattr(planner_mod, "plan_fused_jit", boom)
+    # every group is worth the device, whatever this host's probe says
+    monkeypatch.setattr(TPUPlanner, "_launch_overhead_shared", 1e-9)
+    monkeypatch.setenv("SWARM_NATIVE_COMMIT", "0")
+    # other test modules switch logging off process-wide at import
+    monkeypatch.setattr(logging.root.manager, "disable", logging.NOTSET)
+    monkeypatch.setattr(chip_smoke, "programs_phase",
+                        lambda *args: None)
+    rc = chip_smoke.run(CPU, n_nodes=64, n_agents=2, replicas=2000,
+                        timeout=120.0)
+    captured = capsys.readouterr()
+    assert rc != 0
+    assert '"ok"' not in captured.out
+    failures = captured.err
+    assert "groups_device_error=" in failures
+    assert re.search(r"breaker (open|half-open) .*'trips': [1-9]", failures)
+    assert "native commit plane" in failures
+    assert "retreat logged: tpu-planner" in failures

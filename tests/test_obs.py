@@ -328,6 +328,14 @@ def test_bench_tiny_config_emits_valid_trace(tmp_path, monkeypatch,
     monkeypatch.syspath_prepend(repo_root)
     import bench
     bench = importlib.reload(bench)   # re-read env-derived constants
+    # a standalone bench process has no rollout in flight; in suite
+    # order the manager/integration modules leave theirs frozen
+    # mid-update in the process-global registry, and the all-pass health
+    # verdict below would judge them as this run's.  Park them the way
+    # orchestrator/update.py parks a deleted service's.
+    from swarmkit_tpu.utils.metrics import registry
+    for name in registry.gauges_snapshot('swarm_update_state{service="'):
+        registry.gauge(name, -1.0)
     try:
         bench.main()
     finally:
