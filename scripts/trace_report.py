@@ -7,6 +7,7 @@ Usage:
     python scripts/trace_report.py --diff A.json B.json
     python scripts/trace_report.py --critical-path BENCH_ART.json
     python scripts/trace_report.py --device BENCH_ART.json
+    python scripts/trace_report.py trace.json --service SERVICE_ID
 
 Works on any trace the obs tracer emits: ``bench.py``'s BENCH_TRACE_OUT,
 ``python -m swarmkit_tpu.sim --trace-json``, or a ``/debug/trace``
@@ -24,6 +25,13 @@ saturation windows and prints one row per plane — which plane owns the
 slow tail, and whether that plane's occupancy/backlog corroborates it.
 Exits 1 when the attribution is missing, empty, or does not account
 for ~100% of the tail (the CI wiring keys on that).
+``--service ID`` follows one deploy: every span that carries the service
+id (or nests under one that does), from ``api.create_service`` to
+``commit.publish``, in start order with its thread.  The plain table
+also prints ``self_s`` and ``cpu_s`` per phase, the scheduler loop's time
+between ticks (``sched.idle`` / ``sched.debounce`` / ``sched.events``,
+which are not tick time) and the trace's ``thread_cpu_s``: the CPU
+seconds each thread used while the tracer was on.
 ``--device ART`` also takes a bench artifact: it renders the device
 telemetry ledger (kernel rows per compile bucket joined with the device
 plane's occupancy window, per-reason transfer bytes, the compile-cache
@@ -40,9 +48,9 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 from swarmkit_tpu.obs.report import (  # noqa: E402
-    config_windows, device_table, diff_phase_tables, format_device_table,
-    format_diff, format_table, phase_table, validate_chrome_trace,
-    x_events,
+    config_windows, device_table, diff_phase_tables, follow_service,
+    format_device_table, format_diff, format_table, phase_table,
+    validate_chrome_trace, x_events,
 )
 
 
@@ -216,6 +224,9 @@ def main(argv=None) -> int:
                    help="per-plane attribution of time-to-running p99 "
                         "from a bench ARTIFACT (exit 1 when empty or "
                         "malformed)")
+    p.add_argument("--service", metavar="ID",
+                   help="follow one deploy: the spans that carry this "
+                        "service id, in start order")
     p.add_argument("--device", action="store_true",
                    help="device-telemetry ledger from a bench ARTIFACT: "
                         "kernel rows per compile bucket + device-plane "
@@ -252,14 +263,31 @@ def main(argv=None) -> int:
         print(f"warning: {len(problems)} schema problems "
               f"(run --validate)", file=sys.stderr)
 
+    if args.service:
+        rows = follow_service(doc, args.service)
+        if args.json:
+            print(json.dumps(rows, indent=2, sort_keys=True))
+            return 0 if rows else 1
+        for r in rows:
+            print(f"{r['ts'] / 1e3:>12.3f}ms {r['dur'] / 1e3:>10.3f}ms "
+                  f"{r['thread']:<16} {r['name']:<24} {r['args']}")
+        return 0 if rows else 1
+
     tables = _tables(doc)
+    threads = (doc.get("otherData") or {}).get("thread_cpu_s")
     if args.json:
+        if threads is not None:
+            tables = dict(tables, thread_cpu_s=threads)
         print(json.dumps(tables, indent=2, sort_keys=True))
         return 0
     for name, table in tables.items():
         print(f"=== {name} ===")
         print(format_table(table))
         print()
+    if threads:
+        print("thread CPU seconds while the tracer was on:")
+        for name, used in sorted(threads.items(), key=lambda kv: -kv[1]):
+            print(f"  {name:<28} {used:>10.3f}")
     return 0
 
 

@@ -24,6 +24,7 @@ from ..models.specs import (
 from ..models.types import (
     EndpointResolutionMode, NodeRole, PublishMode, TaskState, Version, now,
 )
+from ..obs.trace import tracer
 from ..scheduler import constraint as constraint_mod
 from ..scheduler import strategy as strategy_mod
 from ..state.store import (
@@ -406,24 +407,28 @@ class ControlAPI:
 
     def create_service(self, spec: ServiceSpec) -> Service:
         """reference: service.go:727 CreateService."""
-        validate_service_spec(spec)
-        self._check_port_conflicts(spec, "")
-        self._check_dependency_cycles(spec, "")
-        spec = _normalized_service_spec(spec)
-        service = Service(id=new_id(), spec=spec,
-                          spec_version=Version(index=1))
+        with tracer.span("api.create_service", "api") as sp:
+            validate_service_spec(spec)
+            self._check_port_conflicts(spec, "")
+            self._check_dependency_cycles(spec, "")
+            spec = _normalized_service_spec(spec)
+            service = Service(id=new_id(), spec=spec,
+                              spec_version=Version(index=1))
+            if sp is not None:
+                # the identifier every later span of this deploy carries
+                sp.args = {"service": service.id}
 
-        def cb(tx):
-            self._check_secret_existence(tx, spec)
-            self._check_config_existence(tx, spec)
-            tx.create(service)
+            def cb(tx):
+                self._check_secret_existence(tx, spec)
+                self._check_config_existence(tx, spec)
+                tx.create(service)
 
-        try:
-            self.store.update(cb)
-        except NameConflict:
-            raise AlreadyExists(
-                f"service {spec.annotations.name} already exists")
-        return self.store.view(lambda tx: tx.get(Service, service.id))
+            try:
+                self.store.update(cb)
+            except NameConflict:
+                raise AlreadyExists(
+                    f"service {spec.annotations.name} already exists")
+            return self.store.view(lambda tx: tx.get(Service, service.id))
 
     def get_service(self, service_id: str) -> Service:
         s = self.store.view(lambda tx: tx.get(Service, service_id))

@@ -19,6 +19,11 @@ PLAN_PHASES = ("plan.dispatch", "plan.d2h", "plan.feasibility",
                # wait alone cannot see (ops/planner.py _note_inflight)
                "plan.inflight")
 COMMIT_PHASES = ("sched.commit",)
+# the scheduler thread between ticks (scheduler.py _LoopAccount): with
+# ``sched.tick`` they cover the thread, so none of them is tick time.
+# ``sched.events`` is a total placed at its episode's end, not an
+# interval: it overlaps the debounce it is placed in.
+LOOP_PHASES = ("sched.idle", "sched.debounce", "sched.events")
 
 
 def x_events(doc: Dict[str, Any]) -> List[Dict[str, Any]]:
@@ -85,22 +90,37 @@ def phase_table(doc: Dict[str, Any],
     phases: Dict[str, Dict[str, float]] = {}
     plan_iv: List[Tuple[int, int]] = []
     commit_iv: List[Tuple[int, int]] = []
-    for e in x_events(doc):
+    events = x_events(doc)
+    # a span's self time: its duration less what its children cover
+    children: Dict[int, List[Tuple[int, int]]] = {}
+    for e in events:
+        parent = (e.get("args") or {}).get("parent_id")
+        if parent:
+            children.setdefault(parent, []).append(
+                (e["ts"], e["ts"] + e["dur"]))
+    for e in events:
         ts, dur = e["ts"], e["dur"]
         if window is not None and not (window[0] <= ts <= window[1]):
             continue
         row = phases.setdefault(
-            e["name"], {"count": 0, "total_s": 0.0, "max_s": 0.0})
+            e["name"], {"count": 0, "total_s": 0.0, "max_s": 0.0,
+                        "self_s": 0.0, "cpu_s": 0.0})
         row["count"] += 1
         row["total_s"] += dur / 1e6
         row["max_s"] = max(row["max_s"], dur / 1e6)
+        kids = children.get((e.get("args") or {}).get("span_id"))
+        covered = _union_seconds(_merge(
+            [(max(a, ts), min(b, ts + dur)) for a, b in kids
+             if min(b, ts + dur) > max(a, ts)])) if kids else 0.0
+        row["self_s"] += dur / 1e6 - covered
+        row["cpu_s"] += e.get("tdur", 0) / 1e6
         if e["name"] in PLAN_PHASES:
             plan_iv.append((ts, ts + dur))
         elif e["name"] in COMMIT_PHASES:
             commit_iv.append((ts, ts + dur))
     for row in phases.values():
-        row["total_s"] = round(row["total_s"], 6)
-        row["max_s"] = round(row["max_s"], 6)
+        for key in ("total_s", "max_s", "self_s", "cpu_s"):
+            row[key] = round(row[key], 6)
     plan_iv = _merge(plan_iv)
     commit_iv = _merge(commit_iv)
     plan_s = _union_seconds(plan_iv)
@@ -108,6 +128,9 @@ def phase_table(doc: Dict[str, Any],
     overlap = _overlap_seconds(plan_iv, commit_iv)
     return {
         "phases": dict(sorted(phases.items())),
+        # the scheduler thread outside its ticks, by what it was doing
+        "loop_s": {name: phases[name]["total_s"]
+                   for name in LOOP_PHASES if name in phases},
         "plan_wall_s": round(plan_s, 6),
         "commit_wall_s": round(commit_s, 6),
         "plan_commit_overlap_s": round(overlap, 6),
@@ -122,16 +145,51 @@ def phase_table(doc: Dict[str, Any],
 
 
 def format_table(table: Dict[str, Any]) -> str:
-    lines = [f"{'phase':<28} {'count':>8} {'total_s':>12} {'max_s':>12}"]
+    lines = [f"{'phase':<28} {'count':>8} {'total_s':>12} {'max_s':>12} "
+             f"{'self_s':>12} {'cpu_s':>12}"]
     for name, row in table["phases"].items():
         lines.append(f"{name:<28} {row['count']:>8} "
-                     f"{row['total_s']:>12.6f} {row['max_s']:>12.6f}")
+                     f"{row['total_s']:>12.6f} {row['max_s']:>12.6f} "
+                     f"{row.get('self_s', 0.0):>12.6f} "
+                     f"{row.get('cpu_s', 0.0):>12.6f}")
     lines.append("")
+    for name, seconds in table.get("loop_s", {}).items():
+        lines.append(f"{name:<14}: {seconds:.6f}s (between ticks)")
     lines.append(f"plan wall   : {table['plan_wall_s']:.6f}s")
     lines.append(f"commit wall : {table['commit_wall_s']:.6f}s")
     lines.append(f"overlap     : {table['plan_commit_overlap_s']:.6f}s "
                  f"(plan hidden: {table['plan_hidden_frac'] * 100:.1f}%)")
     return "\n".join(lines)
+
+
+def follow_service(doc: Dict[str, Any], service: str
+                   ) -> List[Dict[str, Any]]:
+    """Every span that carries ``service`` (a service id), in start
+    order, with its thread: one deploy from ``api.create_service`` to
+    ``commit.publish``.  Spans nested under one that carries it (the
+    ``commit.*`` stages under a group's ``sched.commit``, a lock wait
+    under the RPC) belong to it too."""
+    events = x_events(doc)
+    threads = {e["tid"]: e["args"]["name"]
+               for e in doc.get("traceEvents", ())
+               if e.get("ph") == "M" and e.get("name") == "thread_name"}
+    by_id = {e["args"]["span_id"]: e for e in events
+             if "span_id" in (e.get("args") or {})}
+
+    def carries(e) -> bool:
+        for _ in range(64):       # spans nest a few deep; no cycles
+            if e["args"].get("service") == service:
+                return True
+            e = by_id.get(e["args"].get("parent_id"))
+            if e is None:
+                return False
+        return False
+    return [{"name": e["name"], "thread": threads.get(e["tid"], "?"),
+             "ts": e["ts"], "dur": e["dur"],
+             "args": {k: v for k, v in e["args"].items()
+                      if k not in ("span_id", "parent_id")}}
+            for e in sorted(events, key=lambda e: e["ts"])
+            if carries(e)]
 
 
 def diff_phase_tables(a: Dict[str, Any], b: Dict[str, Any]

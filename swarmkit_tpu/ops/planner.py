@@ -795,6 +795,45 @@ class TPUPlanner:
             return False
         return self.fetch_group(handle)
 
+    def _route_to_host(self, t: Task, k: int):
+        """The router's decision for one group of ``k`` tasks like ``t``:
+        (the reason it rides the host path, or None for the device; its
+        resolved strategy).  Routing counters, breaker bookkeeping and
+        column-cache invalidation are applied here, exactly once."""
+        if not self._supported(t):
+            self._fallback()
+            return "fallback", None
+        sinfo = strategy_mod.resolve(strategy_mod.strategy_of(t))
+        if sinfo is None:
+            # unknown strategy name (written behind the API): the host
+            # path serves it through the spread tree and counts the
+            # strategy fallback
+            self._fallback()
+            return "fallback", None
+        if sinfo.sid != strategy_mod.STRAT_SPREAD \
+                and self._plan_fn is not plan_group_jit \
+                and not hasattr(self._plan_fn, "strategy"):
+            # an injected plan_fn (test stubs) owns the device path and
+            # has no strategy twin: the group rides its HOST ORACLE —
+            # identical placements by the seam's bit-parity contract,
+            # one densify on the host instead.  Mesh ShardedPlanFn
+            # exposes .strategy and keeps non-spread groups on device.
+            self._count("groups_strategy_host")
+            self._cache = None   # host path mutates NodeInfos
+            return "strategy_host", sinfo
+        if not self.breaker.allow_device():
+            # degraded mode: a sick device routes every group to the
+            # host oracle until the breaker's cooldown/probe admits it
+            self._count("groups_breaker_to_host")
+            self._cache = None   # host path mutates NodeInfos
+            return "breaker", sinfo
+        if self._below_break_even(k):
+            self._count("groups_small_to_host")
+            self.breaker.abort_probe()   # never reached the device
+            self._cache = None   # host path mutates NodeInfos
+            return "host_small", sinfo
+        return None, sinfo
+
     def dispatch_group(self, sched, task_group: Dict[str, Task],
                        decisions) -> Optional[_InFlightPlan]:
         """Pipeline stage 1: route, densify, and async-dispatch one
@@ -810,37 +849,12 @@ class TPUPlanner:
         dispatched placement would be read against stale columns.
         """
         t = next(iter(task_group.values()))
-        if not self._supported(t):
-            self._fallback()
-            return None
-        sinfo = strategy_mod.resolve(strategy_mod.strategy_of(t))
-        if sinfo is None:
-            # unknown strategy name (written behind the API): the host
-            # path serves it through the spread tree and counts the
-            # strategy fallback
-            self._fallback()
-            return None
-        if sinfo.sid != strategy_mod.STRAT_SPREAD \
-                and self._plan_fn is not plan_group_jit \
-                and not hasattr(self._plan_fn, "strategy"):
-            # an injected plan_fn (test stubs) owns the device path and
-            # has no strategy twin: the group rides its HOST ORACLE —
-            # identical placements by the seam's bit-parity contract,
-            # one densify on the host instead.  Mesh ShardedPlanFn
-            # exposes .strategy and keeps non-spread groups on device.
-            self._count("groups_strategy_host")
-            self._cache = None   # host path mutates NodeInfos
-            return None
-        if not self.breaker.allow_device():
-            # degraded mode: a sick device routes every group to the
-            # host oracle until the breaker's cooldown/probe admits it
-            self._count("groups_breaker_to_host")
-            self._cache = None   # host path mutates NodeInfos
-            return None
-        if self._below_break_even(len(task_group)):
-            self._count("groups_small_to_host")
-            self.breaker.abort_probe()   # never reached the device
-            self._cache = None   # host path mutates NodeInfos
+        with tracer.span("plan.route", "plan", tasks=len(task_group),
+                         service=t.service_id) as sp:
+            host, sinfo = self._route_to_host(t, len(task_group))
+            if sp is not None:
+                sp.args["route"] = host or "device"
+        if host is not None:
             return None
 
         import time as _time
@@ -856,7 +870,8 @@ class TPUPlanner:
                 "dispatch_group with a plan already in flight: fetch it "
                 "first (its apply feeds this group's input columns)")
         flat = sinfo.sid != strategy_mod.STRAT_SPREAD
-        with tracer.span("plan.build_inputs", "plan", tasks=k):
+        with tracer.span("plan.build_inputs", "plan", tasks=k,
+                         service=t.service_id):
             built = self._build_device_inputs(sched, t, k, flat=flat)
         if built is None:
             self.breaker.abort_probe()
@@ -867,8 +882,16 @@ class TPUPlanner:
             return None
         nodes_in, group_in, L, hier = built[7], built[8], built[9], \
             built[10]
+        # the launch's name in the compile ledger, and on its span (so
+        # it stands in a captured profile beside the launch)
+        bucket = _bucket_label(nodes_in, group_in, L, hier)
+        if flat:
+            bucket += f"_st{sinfo.sid}"
+        route = "strategy" if flat else "group"
         try:
-            with tracer.span("plan.dispatch", "plan", tasks=k):
+            with tracer.span("plan.dispatch", "plan", tasks=k,
+                             service=t.service_id, label=bucket,
+                             route=route):
                 if flat:
                     sin = self._build_strategy_inputs(built, t, sinfo)
                     arrays = self._call_strategy_fn(nodes_in, group_in,
@@ -888,12 +911,9 @@ class TPUPlanner:
             return None
         if flat:
             strategy_mod.count_group(sinfo.name, "device")
-        bucket = _bucket_label(nodes_in, group_in, L, hier)
-        if flat:
-            bucket += f"_st{sinfo.sid}"
         handle = _InFlightPlan(sched, t, task_group, decisions, built,
                                _plan_t0, arrays, bucket=bucket,
-                               route="strategy" if flat else "group")
+                               route=route)
         self._inflight.append(handle)
         return handle
 
@@ -1222,7 +1242,8 @@ class TPUPlanner:
             return tasks   # below device break-even: host loop
         import time as _time
         _plan_t0 = _time.perf_counter()
-        with tracer.span("plan.build_inputs", "plan", tasks=len(tasks)):
+        with tracer.span("plan.build_inputs", "plan", tasks=len(tasks),
+                         service=t.service_id):
             built = self._build_device_inputs(sched, t, len(tasks))
         if built is None or built[1] == 0:
             self.breaker.abort_probe()
@@ -1234,10 +1255,11 @@ class TPUPlanner:
             return tasks   # per-task claim bookkeeping: host path
 
         import jax as _jax
+        _feas_bucket = "feas_" + _bucket_label(nodes_in, group_in, 1, ())
         try:
-            with tracer.span("plan.feasibility", "plan", tasks=len(tasks)):
-                _feas_bucket = "feas_" + _bucket_label(nodes_in, group_in,
-                                                       1, ())
+            with tracer.span("plan.feasibility", "plan", tasks=len(tasks),
+                             service=t.service_id, label=_feas_bucket,
+                             route="feasibility"):
                 _cache_before = _jit_cache_size(feasibility_jit)
                 _devtel.note_h2d("group_inputs",
                                  _devtel.tree_nbytes((nodes_in, group_in)))
@@ -1278,7 +1300,8 @@ class TPUPlanner:
         if not items:
             return remaining
 
-        with tracer.span("plan.apply", "plan", tasks=len(items)):
+        with tracer.span("plan.apply", "plan", tasks=len(items),
+                         service=t.service_id):
             self._apply_assignments(
                 sched, t, items, slots, infos, decisions, cpu_d, mem_d,
                 used, cpu, mem, total,
@@ -1327,7 +1350,7 @@ class TPUPlanner:
         # one round-trip for all outputs: each fetch is a host sync
         _d2h_t0 = _time.perf_counter()
         try:
-            with tracer.span("plan.d2h", "plan"):
+            with tracer.span("plan.d2h", "plan", service=t.service_id):
                 x, fail_counts, spill = fetch_plan(handle.arrays)
         except Exception:
             # fetch failure: the plan is lost but the group is not — it
@@ -1387,7 +1410,8 @@ class TPUPlanner:
             # its Python cost dominates large groups when run per task)
             placed = min(len(items), len(slots))
             counts = np.asarray(x)
-            with tracer.span("plan.apply", "plan", tasks=placed):
+            with tracer.span("plan.apply", "plan", tasks=placed,
+                             service=t.service_id):
                 self._apply_assignments(sched, t, items[:placed],
                                         slots[:placed], infos, decisions,
                                         cpu_d, mem_d, counts, cpu, mem,
@@ -1400,7 +1424,8 @@ class TPUPlanner:
         else:
             # generic resources / host ports need per-task claim bookkeeping
             self._cache = None   # add_task mutates behind the columns
-            with tracer.span("plan.apply", "plan", tasks=len(slots)):
+            with tracer.span("plan.apply", "plan", tasks=len(slots),
+                             service=t.service_id):
                 for (task_id, task), node_i in zip(items, slots):
                     info = infos[node_i]
                     new_t = _fast_assign(task, info.id, shared_status)
@@ -1433,9 +1458,12 @@ class TPUPlanner:
         try:
             before = _jit_cache_size(_preempt.select_victims_jit)
             t0 = _time.perf_counter()
-            with tracer.span("plan.preempt", "plan", picks=n_picks):
+            with tracer.span("plan.preempt", "plan", picks=n_picks,
+                             route="preempt") as sp:
                 picks, bucket, fn = _preempt.plan_victims(
                     cand, cpu_d, mem_d, gen_d, n_picks, budget)
+                if sp is not None:     # the label is the plan's to give
+                    sp.args["label"] = bucket
             dt = _time.perf_counter() - t0
             comp = _observe_compile(fn, bucket, before, dt)
             _devtel.note_kernel(bucket, "preempt", dispatch_s=dt,
@@ -1481,7 +1509,8 @@ class TPUPlanner:
                                  _devtel.tree_nbytes((nodes_in, group_in)))
                 t0 = _time.perf_counter()
                 with tracer.span("plan.gang_fit", "plan",
-                                 k=int(group_in.k)):
+                                 k=int(group_in.k), label=bucket,
+                                 route="gang"):
                     fit, _fc = gang_fit_jit(nodes_in, group_in)
                     fit = bool(fit)
                 dt = _time.perf_counter() - t0
@@ -1545,7 +1574,8 @@ class TPUPlanner:
                     (stacked_nodes, stacked_groups)))
                 t0 = _time.perf_counter()
                 with tracer.span("plan.gang_fit_fused", "plan",
-                                 gangs=len(rows)):
+                                 gangs=len(rows), label=label + "_gfF",
+                                 route="gang_fused"):
                     fits, _fcs = gang_fit_fused_jit(stacked_nodes,
                                                     stacked_groups)
                     fits = [bool(f) for f in fits]
@@ -1586,13 +1616,16 @@ class TPUPlanner:
         if self.breaker.state != BREAKER_CLOSED:
             return []
         specs = []
-        for group in glist[start:]:
-            if self._below_break_even(len(group)):
-                break   # below device break-even: host path
-            spec = fusedbatch.probe_group(self, sched, group)
-            if spec is None:
-                break
-            specs.append(spec)
+        with tracer.span("plan.fused_probe", "plan") as sp:
+            for group in glist[start:]:
+                if self._below_break_even(len(group)):
+                    break   # below device break-even: host path
+                spec = fusedbatch.probe_group(self, sched, group)
+                if spec is None:
+                    break
+                specs.append(spec)
+            if sp is not None:
+                sp.args = {"groups": len(specs)}
         return specs
 
     def dispatch_fused_run(self, sched, specs):
@@ -1601,7 +1634,10 @@ class TPUPlanner:
         first dispatch fails — the caller falls back group-by-group
         (identical placements; no mirror state was touched here)."""
         try:
-            run = fusedbatch.build_run(self, sched, specs)
+            with tracer.span("plan.fused_build", "plan",
+                             services=len(specs),
+                             service=specs[0].t.service_id):
+                run = fusedbatch.build_run(self, sched, specs)
         except Exception:
             log.exception("fused batch build failed; per-group path")
             self._fused_dead = True
@@ -1610,7 +1646,8 @@ class TPUPlanner:
             self._count("fused_overflows")
             return None
         try:
-            with fusedbatch.x64():
+            with tracer.span("plan.fused_prepare", "plan"), \
+                    fusedbatch.x64():
                 run.shared, run.carry = self._prepare_fused(run.shared,
                                                             run.carry)
             self._dispatch_fused_chunks(run)
@@ -1732,7 +1769,9 @@ class TPUPlanner:
             c.t0 = _time.perf_counter()
             try:
                 with tracer.span("plan.dispatch", "plan", tasks=c.tasks,
-                                 fused_groups=c.count):
+                                 fused_groups=c.count,
+                                 service=run.specs[c.start].t.service_id,
+                                 label=bucket, route="fused"):
                     with fusedbatch.x64():
                         fn = (self._fused_fn.fused
                               if self._fused_fn is not None
@@ -1775,7 +1814,8 @@ class TPUPlanner:
         c = run.chunks[run.next_fetch]
         _d2h_t0 = _time.perf_counter()
         try:
-            with tracer.span("plan.d2h", "plan"):
+            with tracer.span("plan.d2h", "plan", fused_groups=c.count,
+                             service=run.specs[c.start].t.service_id):
                 xs, fcs, spills = fetch_plan(c.arrays)
         except Exception:
             log.exception("fused fetch failed; remaining groups ride "
@@ -1819,7 +1859,8 @@ class TPUPlanner:
         slots = np.repeat(np.arange(x.shape[0]), x).tolist()
         items = list(task_group.items())
         placed = min(len(items), len(slots))
-        with tracer.span("plan.apply", "plan", tasks=placed):
+        with tracer.span("plan.apply", "plan", tasks=placed,
+                         service=t.service_id):
             self._apply_assignments(sched, t, items[:placed],
                                     slots[:placed], infos, decisions,
                                     spec.cpu_d, spec.mem_d, x, cpu, mem,

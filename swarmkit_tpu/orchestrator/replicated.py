@@ -45,6 +45,9 @@ class Orchestrator:
         self.updater = updater or UpdateSupervisor(store, self.restarts)
         self.cluster: Optional[Cluster] = None
         self.reconcile_services: Dict[str, Service] = {}
+        # [tasks set out to create, store transactions taken] by the
+        # reconcile under way: the ``orchestrator.service`` span's counts
+        self._made = [0, 0]
         self.restart_tasks: Dict[str, None] = {}   # insertion-ordered set
         self._stop = threading.Event()
         self._done = threading.Event()
@@ -217,10 +220,21 @@ class Orchestrator:
             return
         services, self.reconcile_services = self.reconcile_services, {}
         with tracer.span("orchestrator.reconcile", "orchestrator",
-                         kind="replicated", services=len(services)):
+                         kind="replicated",
+                         services=len(services)) as batch_sp:
+            created_all = 0
             with _RECONCILE_TIMER.time():
                 for s in services.values():
-                    self._reconcile(s)
+                    with tracer.span("orchestrator.service",
+                                     "orchestrator", service=s.id) as sp:
+                        made = self._made = [0, 0]
+                        self._reconcile(s)
+                        if sp is not None:
+                            sp.args.update(created=made[0],
+                                           batches=made[1])
+                    created_all += made[0]
+            if batch_sp is not None:
+                batch_sp.args["created"] = created_all
 
     # ------------------------------------------------------------- reconcile
 
@@ -261,6 +275,7 @@ class Orchestrator:
                                 specified - num_slots)
                 self._delete_tasks(batch, dead_slots)
 
+            self._made[0] += specified - num_slots
             self._safe_batch(cb)
         elif specified < num_slots:
             # running slots sort first (removal takes from the end, so
@@ -349,8 +364,11 @@ class Orchestrator:
                 batch.update(one)
 
     def _safe_batch(self, cb) -> None:
+        def counted(batch: Batch) -> Batch:
+            cb(batch)
+            return batch
         try:
-            self.store.batch(cb)
+            self._made[1] += self.store.batch(counted).flushes
         except Exception:
             log.exception("reconcile batch failed")
 

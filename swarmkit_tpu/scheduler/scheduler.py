@@ -19,11 +19,12 @@ from __future__ import annotations
 import logging
 import queue
 import threading
+import time
 from typing import Dict, Iterable, List, Optional, Tuple
 
 from ..models.objects import Cluster, Node, Service, Task, Volume
 from ..models.types import (
-    Resources, TaskState, TaskStatus, now,
+    Resources, TaskState, TaskStatus, now, time_source_installed,
 )
 from ..obs import planes as _planes
 from ..obs.trace import tracer
@@ -60,6 +61,12 @@ class SchedulingDecision:
     def __init__(self, old: Task, new: Task):
         self.old = old
         self.new = new
+
+
+def _services_of(ids: List[str]) -> dict:
+    """``service`` (the first) and ``services`` (how many) for the span
+    of a commit that several services' decisions share."""
+    return {"service": ids[0], "services": len(set(ids))} if ids else {}
 
 
 class _TickCommitter:
@@ -104,9 +111,11 @@ class _TickCommitter:
         drafts remain unresolved.  Tickets resolve in submission order
         (single FIFO committer), so a monotonic resolved-prefix index
         keeps this O(1) amortized per call."""
-        while len(self._tickets) - self._resolved > max_inflight:
-            self._tickets[self._resolved]["done"].wait()
-            self._resolved += 1
+        if len(self._tickets) - self._resolved > max_inflight:
+            with tracer.span("sched.commit_wait", "sched"):
+                while len(self._tickets) - self._resolved > max_inflight:
+                    self._tickets[self._resolved]["done"].wait()
+                    self._resolved += 1
         _metrics.gauge("swarm_scheduler_chunk_inflight",
                        float(len(self._tickets) - self._resolved))
 
@@ -148,7 +157,12 @@ class _TickCommitter:
                             for olds, _, _ in ticket["draft"])
                     t0 = now()
                     with tracer.span("sched.commit", "sched",
-                                     decisions=n):
+                                     decisions=n) as sp:
+                        if sp is not None:
+                            # a draft holds one group's block
+                            sp.args.update(_services_of(
+                                [olds[0].service_id for olds, _, _
+                                 in ticket["draft"] if olds]))
                         c, _, f = sched._commit_draft(
                             ticket["draft"], want_ids=False,
                             missing_out=ticket["missing"])
@@ -176,6 +190,64 @@ class _TickCommitter:
             for old, nid in t["missing"]:
                 self._sched._on_block_missing(old, nid)
         return committed, failed
+
+
+class _LoopAccount:
+    """What ``Scheduler.run`` did with its thread, kept per debounce
+    episode and never per event (events run to thousands a second).
+
+    An episode runs from the ``EventCommit`` that opened it to the
+    instant a deadline fired and the loop acted.  Spans, all retroactive
+    (``record_complete``) and only while the tracer is on:
+    ``sched.idle`` from the end of the last episode to this one's first
+    ``EventCommit``; ``sched.debounce`` over the episode, with the
+    deadline that fired; ``sched.events``, whose duration is the SUMMED
+    time inside ``_handle_event`` / ``_resync`` since the last episode
+    closed — a total, placed at the episode's end, not an interval in
+    which the events ran.  With ``sched.tick`` they cover the thread.
+    Counters (``Scheduler.stats``) advance once, when the episode
+    closes, tracer or not."""
+
+    __slots__ = ("stats", "events", "commits", "event_s", "idle_from",
+                 "wall0", "cpu0")
+
+    def __init__(self, stats: dict):
+        self.stats = stats
+        self.events = self.commits = 0
+        self.event_s = 0.0
+        self.idle_from = self.wall0 = now()
+        self.cpu0 = time.thread_time()
+
+    def open_episode(self, started: float) -> None:
+        if tracer.enabled:
+            tracer.record_complete("sched.idle", "sched",
+                                   started - self.idle_from)
+
+    def fire(self, started: float, fired: str, queued: int,
+             ticked: bool) -> None:
+        if tracer.enabled:
+            tracer.record_complete(
+                "sched.debounce", "sched", now() - started,
+                events=self.events, commits=self.commits, fired=fired,
+                queued=queued, ticked=ticked)
+            tracer.record_complete("sched.events", "sched", self.event_s,
+                                   events=self.events)
+
+    def close_episode(self, at: Optional[float] = None) -> None:
+        """``at``: the instant the episode's work ended (its tick's
+        end), where the idle stretch after it begins."""
+        stats = self.stats
+        stats["events_handled"] += self.events
+        stats["commits_seen"] += self.commits
+        self.events = self.commits = 0
+        self.event_s = 0.0
+        t = now() if at is None else at
+        if not time_source_installed():
+            cpu = time.thread_time()
+            stats["thread_cpu_s"] += cpu - self.cpu0
+            stats["loop_wall_s"] += t - self.wall0
+            self.cpu0 = cpu
+        self.wall0 = self.idle_from = t
 
 
 class Scheduler:
@@ -280,11 +352,16 @@ class Scheduler:
         self._stop = threading.Event()
         self._done = threading.Event()
         self._thread: Optional[threading.Thread] = None
-        # stats for benchmarking / tests (bounded: long-lived managers
-        # tick many times per second)
-        from collections import deque
+        # counters for benchmarking / tests.  The loop's own
+        # (``ticks_by_*`` .. ``loop_wall_s``) advance once per debounce
+        # episode of run(): which deadline fired each tick, the events
+        # and commits the loop consumed, and the scheduler thread's CPU
+        # seconds beside the wall seconds they were spent in (both stay
+        # zero under an installed time source).
         self.stats = {"ticks": 0, "decisions": 0, "commit_seconds": 0.0,
-                      "tick_seconds": deque(maxlen=1024)}
+                      "ticks_by_gap": 0, "ticks_by_max_latency": 0,
+                      "events_handled": 0, "commits_seen": 0,
+                      "thread_cpu_s": 0.0, "loop_wall_s": 0.0}
 
         # scheduler-plane saturation probe (obs/planes.py): backlog
         # depth and oldest pending age, read lazily at window-roll time.
@@ -375,6 +452,7 @@ class Scheduler:
 
                 debounce_started: Optional[float] = None
                 tick_required = False
+                loop = _LoopAccount(self.stats)
 
                 while not self._stop.is_set():
                     if debounce_started is None:
@@ -392,23 +470,48 @@ class Scheduler:
 
                     if event is None:
                         if debounce_started is not None:
+                            fired = ("max_latency"
+                                     if debounce_started + self.max_latency
+                                     <= self._last_event + self.debounce_gap
+                                     else "gap")
+                            loop.fire(debounce_started, fired,
+                                      len(self.unassigned_tasks),
+                                      tick_required)
                             if len(self.pending_preassigned_tasks) > 0:
                                 self._process_preassigned_tasks()
+                            tick_end = None
                             if tick_required:
                                 self.tick()
+                                # before anything allocates: the tick
+                                # paused the collector, and the first
+                                # allocation after it pays the pause
+                                tick_end = now()
+                                self.stats["ticks_by_" + fired] += 1
                                 tick_required = False
                             debounce_started = None
+                            loop.close_episode(tick_end)
                         continue
 
                     if isinstance(event, EventCommit):
                         self._last_event = now()
+                        loop.commits += 1
                         if debounce_started is None:
                             debounce_started = self._last_event
+                            loop.open_episode(debounce_started)
                     elif isinstance(event, EventSnapshotRestore):
+                        loop.events += 1
+                        t_ev = now()
                         self._resync()
+                        loop.event_s += now() - t_ev
                         tick_required = True
                     elif isinstance(event, Event):
-                        tick_required |= self._handle_event(event)
+                        loop.events += 1
+                        if tracer.enabled:
+                            t_ev = now()
+                            tick_required |= self._handle_event(event)
+                            loop.event_s += now() - t_ev
+                        else:
+                            tick_required |= self._handle_event(event)
             finally:
                 self.store.queue.unsubscribe(sub)
         finally:
@@ -643,6 +746,10 @@ class Scheduler:
             n = self._tick_inner()
             if sp is not None:
                 sp.args = {"decisions": n}
+        if sp is not None and sp.cpu is not None:
+            # wall minus thread CPU: what the tick spent off the CPU
+            # (the GIL, the update lock, the device, the committer)
+            sp.args["offcpu_ms"] = round((sp.duration - sp.cpu) * 1e3, 3)
         _dt = now() - t0
         _TICK_TIMER.observe(_dt)
         _planes.plane(_planes.SCHEDULER).note_busy(_dt)
@@ -662,8 +769,9 @@ class Scheduler:
         # fresh mirror; admission charges accumulate on top of it as
         # the priority-ordered queue below is walked
         if self.quota_enabled:
-            self.quota.begin_tick(self.all_tasks)
-            self._ensure_quota_filter_last()
+            with tracer.span("sched.quota_begin", "sched"):
+                self.quota.begin_tick(self.all_tasks)
+                self._ensure_quota_filter_last()
         decisions: Dict[str, SchedulingDecision] = {}
 
         # groups are maintained incrementally by _enqueue/_dequeue; take
@@ -677,16 +785,17 @@ class Scheduler:
             one_off_tasks = groups.pop(None, {})
             if sp is not None:
                 sp.args = {"groups": len(groups),
-                           "one_off": len(one_off_tasks)}
+                           **self._queue_wait(groups, one_off_tasks, t0)}
 
         # gang units leave the normal walk and admit atomically first
         # (scheduler/gang.py) — a pure no-op extraction when no task
         # opts in, so non-gang ticks stay byte-identical
-        gang_units = gang_mod.take_gangs(groups, one_off_tasks)
-        if gang_units or self.gang.blocked or self.gang.first_pending:
-            self.gang.prune([k for k, _ in gang_units])
-        n_gang = (gang_mod.admit_gangs(self, gang_units, decisions)
-                  if gang_units else 0)
+        with tracer.span("sched.gangs", "sched"):
+            gang_units = gang_mod.take_gangs(groups, one_off_tasks)
+            if gang_units or self.gang.blocked or self.gang.first_pending:
+                self.gang.prune([k for k, _ in gang_units])
+            n_gang = (gang_mod.admit_gangs(self, gang_units, decisions)
+                      if gang_units else 0)
 
         planner = self.batch_planner
         use_pipeline = (self.pipeline_depth > 1 and self.block_mode
@@ -696,7 +805,8 @@ class Scheduler:
         pipe_committed = 0
         pipe_failed: List[Tuple[Task, str]] = []
         if planner is not None and hasattr(planner, "begin_tick"):
-            planner.begin_tick(self)
+            with tracer.span("plan.begin_tick", "plan"):
+                planner.begin_tick(self)
         try:
             if use_pipeline:
                 pipe_block, pipe_committed, pipe_failed = \
@@ -706,7 +816,8 @@ class Scheduler:
                 self._run_groups_serial(groups, one_off_tasks, decisions)
         finally:
             if planner is not None and hasattr(planner, "end_tick"):
-                planner.end_tick()
+                with tracer.span("plan.end_tick", "plan"):
+                    planner.end_tick()
 
         n_decisions = n_gang + len(decisions) + pipe_block + sum(
             len(olds) for olds, _, _ in self.block_draft)
@@ -745,7 +856,8 @@ class Scheduler:
 
         # priority preemption: higher-priority groups the normal pass
         # left infeasible may evict strictly-lower-priority running work
-        n_decisions += self._preempt_pass()
+        with tracer.span("sched.preempt_pass", "sched"):
+            n_decisions += self._preempt_pass()
 
         if not decisions and self.volumes.frees_pending:
             # releases without new decisions (task shutdowns) must still
@@ -758,8 +870,33 @@ class Scheduler:
                 log.exception("freeing volumes failed")
 
         self.stats["decisions"] += n_decisions
-        self.stats["tick_seconds"].append(now() - t0)
         return n_decisions
+
+    def _queue_wait(self, groups, one_off_tasks, ts: float) -> dict:
+        """``sched.batch_build``'s arguments about the tick's queue (the
+        tracer is on): how many tasks it took and how long they had
+        waited since their PENDING stamp."""
+        tasks = [t for group in (*groups.values(), one_off_tasks)
+                 for t in group.values() if t is not None]
+        ages = [ts - t.status.timestamp for t in tasks
+                if t.status.timestamp] or [0.0]
+        return {"tasks": len(tasks),
+                "wait_mean_ms": round(1e3 * sum(ages) / len(ages), 3),
+                "wait_max_ms": round(1e3 * max(ages), 3)}
+
+    def _collect_groups(self, groups, one_off_tasks, decisions
+                        ) -> List[Dict[str, Task]]:
+        """``_tick_groups`` walked to its end, under one span: pruning,
+        the priority sort, the pipeline gate and the quota clamp."""
+        with tracer.span("sched.groups", "sched") as sp:
+            deferred = self.stats.get("deferred_tasks", 0)
+            glist = list(self._tick_groups(groups, one_off_tasks,
+                                           decisions))
+            if sp is not None:
+                sp.args = {"groups": len(glist),
+                           "deferred": self.stats.get("deferred_tasks", 0)
+                           - deferred}
+        return glist
 
     def _tick_groups(self, groups, one_off_tasks, decisions=None
                      ) -> Iterable[Dict[str, Task]]:
@@ -922,7 +1059,7 @@ class Scheduler:
         committer = _TickCommitter(self)
         inflight: Optional[Tuple[object, Dict[str, Task]]] = None
         n_block = 0
-        glist = list(self._tick_groups(groups, one_off_tasks, decisions))
+        glist = self._collect_groups(groups, one_off_tasks, decisions)
         can_fuse = hasattr(planner, "probe_fused_run")
         i = 0
         try:
@@ -968,7 +1105,8 @@ class Scheduler:
             if inflight is not None and hasattr(planner,
                                                 "discard_inflight"):
                 planner.discard_inflight()
-            committed, failed = committer.close()
+            with tracer.span("sched.commit_join", "sched"):
+                committed, failed = committer.close()
         return n_block, committed, failed
 
     def _run_groups_serial(self, groups, one_off_tasks, decisions) -> None:
@@ -981,7 +1119,7 @@ class Scheduler:
         planner = self.batch_planner
         can_fuse = (planner is not None
                     and hasattr(planner, "probe_fused_run"))
-        glist = list(self._tick_groups(groups, one_off_tasks, decisions))
+        glist = self._collect_groups(groups, one_off_tasks, decisions)
         i = 0
         while i < len(glist):
             specs = (planner.probe_fused_run(self, glist, i)
@@ -1009,46 +1147,48 @@ class Scheduler:
         continues per-group from the first unconsumed group — without
         re-probing a spilled group for fusion, which would replan it
         against identical node state and spill again."""
-        planner = self.batch_planner
-        run = planner.dispatch_fused_run(self, specs)
-        if run is None:
-            return 0, 0, False
-        n_block = 0
-        consumed = 0
-        try:
-            while True:
-                out = planner.fetch_fused_chunk(run)
-                if out is None:
-                    break
-                xs, fcs, spills, start, count = out
-                for j in range(count):
-                    gi = start + j
-                    if bool(spills[j]):
-                        # exact reference parity requires the host
-                        # oracle for this group; later groups were
-                        # planned against a placement that no longer
-                        # happens, so the run aborts here
-                        planner.note_fused_spill(run)
-                        return consumed, n_block, True
-                    planner.apply_fused_group(run, gi, xs[j], fcs[j],
-                                              decisions)
-                    group = run.specs[gi].group
-                    if group:
-                        self._no_suitable_node(
-                            group, decisions,
-                            explanation=getattr(planner,
-                                                "last_explanation", ""))
-                    consumed += 1
-                    if committer is not None and self.block_draft:
-                        draft, self.block_draft = self.block_draft, []
-                        n_block += sum(len(olds)
-                                       for olds, _, _ in draft)
-                        committer.submit(draft)
-                        committer.throttle(max(1,
-                                               self.pipeline_depth - 1))
-        finally:
-            planner.abort_fused_run(run)
-        return consumed, n_block, False
+        with tracer.span("sched.fused_run", "sched", groups=len(specs),
+                         service=specs[0].t.service_id):
+            planner = self.batch_planner
+            run = planner.dispatch_fused_run(self, specs)
+            if run is None:
+                return 0, 0, False
+            n_block = 0
+            consumed = 0
+            try:
+                while True:
+                    out = planner.fetch_fused_chunk(run)
+                    if out is None:
+                        break
+                    xs, fcs, spills, start, count = out
+                    for j in range(count):
+                        gi = start + j
+                        if bool(spills[j]):
+                            # exact reference parity requires the host
+                            # oracle for this group; later groups were
+                            # planned against a placement that no longer
+                            # happens, so the run aborts here
+                            planner.note_fused_spill(run)
+                            return consumed, n_block, True
+                        planner.apply_fused_group(run, gi, xs[j], fcs[j],
+                                                  decisions)
+                        group = run.specs[gi].group
+                        if group:
+                            self._no_suitable_node(
+                                group, decisions,
+                                explanation=getattr(planner,
+                                                    "last_explanation", ""))
+                        consumed += 1
+                        if committer is not None and self.block_draft:
+                            draft, self.block_draft = self.block_draft, []
+                            n_block += sum(len(olds)
+                                           for olds, _, _ in draft)
+                            committer.submit(draft)
+                            committer.throttle(max(1,
+                                                   self.pipeline_depth - 1))
+            finally:
+                planner.abort_fused_run(run)
+            return consumed, n_block, False
 
     def _finish_inflight(self, inflight, decisions,
                          committer: _TickCommitter) -> int:
@@ -1056,26 +1196,28 @@ class Scheduler:
         to the commit pipeline.  Returns the number of block decisions
         drafted for the group."""
         handle, group = inflight
-        planner = self.batch_planner
-        handled = planner.fetch_group(handle)
-        if not handled:
-            # spill: exact reference parity requires the host oracle's
-            # convergence loop for this group (same as the serial path)
-            self._schedule_group_host(group, decisions)
-            return 0
-        if group:
-            self._no_suitable_node(
-                group, decisions,
-                explanation=getattr(planner, "last_explanation", ""))
-        if not self.block_draft:
-            return 0
-        draft, self.block_draft = self.block_draft, []
-        n = sum(len(olds) for olds, _, _ in draft)
-        committer.submit(draft)
-        # bounded depth: one plan in flight on the device + at most
-        # depth-1 unacked commits behind it
-        committer.throttle(max(1, self.pipeline_depth - 1))
-        return n
+        with tracer.span("sched.finish_group", "sched",
+                         service=handle.t.service_id):
+            planner = self.batch_planner
+            handled = planner.fetch_group(handle)
+            if not handled:
+                # spill: exact reference parity requires the host oracle's
+                # convergence loop for this group (same as the serial path)
+                self._schedule_group_host(group, decisions)
+                return 0
+            if group:
+                self._no_suitable_node(
+                    group, decisions,
+                    explanation=getattr(planner, "last_explanation", ""))
+            if not self.block_draft:
+                return 0
+            draft, self.block_draft = self.block_draft, []
+            n = sum(len(olds) for olds, _, _ in draft)
+            committer.submit(draft)
+            # bounded depth: one plan in flight on the device + at most
+            # depth-1 unacked commits behind it
+            committer.throttle(max(1, self.pipeline_depth - 1))
+            return n
 
     # ----------------------------------------------------------- preemption
 
@@ -1406,7 +1548,14 @@ class Scheduler:
             return [], []
         t0 = now()
         try:
-            return self._apply_decisions_inner(decisions)
+            with tracer.span("sched.apply_decisions", "sched",
+                             decisions=len(decisions)) as sp:
+                if sp is not None:
+                    # the host route's decisions commit together, at the
+                    # tick's end: the first service and how many
+                    sp.args.update(_services_of(
+                        [d.old.service_id for d in decisions.values()]))
+                return self._apply_decisions_inner(decisions)
         finally:
             dt = now() - t0
             self.stats["commit_seconds"] += dt
@@ -1614,7 +1763,8 @@ class Scheduler:
             if sinfo is not None:
                 try:
                     with tracer.span("sched.strategy_host", "sched",
-                                     tasks=len(task_group)):
+                                     tasks=len(task_group),
+                                     service=t.service_id):
                         strategy_mod.schedule_group_host(
                             self, task_group, decisions, sinfo)
                 except Exception:
@@ -1649,7 +1799,7 @@ class Scheduler:
 
         prefs = t.spec.placement.preferences if t.spec.placement else []
         with tracer.span("sched.host_fallback", "sched",
-                         tasks=len(task_group)):
+                         tasks=len(task_group), service=t.service_id):
             tree = self.node_set.tree(t.service_id, prefs, len(task_group),
                                       self.pipeline.process, node_less)
             self._schedule_n_tasks_on_subtree(len(task_group), task_group,
@@ -1759,32 +1909,38 @@ class Scheduler:
     def _no_suitable_node(self, task_group: Dict[str, Task],
                           decisions: Dict[str, SchedulingDecision],
                           explanation: Optional[str] = None) -> None:
-        if explanation is None:
-            explanation = self.pipeline.explain()
-        # one service lookup per group, not per task: all tasks in a group
-        # share (service_id, spec_version)
-        services: Dict[str, Optional[Service]] = {}
-        for t in task_group.values():
-            if t.service_id not in services:
-                services[t.service_id] = self.store.raw_get(
-                    Service, t.service_id)
-            service = services[t.service_id]
-            if service is None:
-                continue
-            new_t = t.copy()
-            new_t.status.timestamp = now()
-            sv = service.spec_version
-            tv = new_t.spec_version
-            if sv is not None and tv is not None and sv.index > tv.index:
-                if (t.status.state == TaskState.PENDING
-                        and t.desired_state >= TaskState.SHUTDOWN):
-                    new_t.status.state = TaskState.SHUTDOWN
-                    new_t.status.err = ""
-            else:
-                if explanation:
-                    new_t.status.err = f"no suitable node ({explanation})"
+        with tracer.span("sched.no_suitable_node", "sched",
+                         tasks=len(task_group)) as sp:
+            if sp is not None and task_group:
+                sp.args["service"] = \
+                    next(iter(task_group.values())).service_id
+            if explanation is None:
+                explanation = self.pipeline.explain()
+            # one service lookup per group, not per task: all tasks in a
+            # group share (service_id, spec_version)
+            services: Dict[str, Optional[Service]] = {}
+            for t in task_group.values():
+                if t.service_id not in services:
+                    services[t.service_id] = self.store.raw_get(
+                        Service, t.service_id)
+                service = services[t.service_id]
+                if service is None:
+                    continue
+                new_t = t.copy()
+                new_t.status.timestamp = now()
+                sv = service.spec_version
+                tv = new_t.spec_version
+                if sv is not None and tv is not None and sv.index > tv.index:
+                    if (t.status.state == TaskState.PENDING
+                            and t.desired_state >= TaskState.SHUTDOWN):
+                        new_t.status.state = TaskState.SHUTDOWN
+                        new_t.status.err = ""
                 else:
-                    new_t.status.err = "no suitable node"
-                self._enqueue(new_t)
-            self.all_tasks[t.id] = new_t
-            decisions[t.id] = SchedulingDecision(t, new_t)
+                    if explanation:
+                        new_t.status.err = \
+                            f"no suitable node ({explanation})"
+                    else:
+                        new_t.status.err = "no suitable node"
+                    self._enqueue(new_t)
+                self.all_tasks[t.id] = new_t
+                decisions[t.id] = SchedulingDecision(t, new_t)

@@ -31,6 +31,7 @@ defensive copies via ``obj.copy()``).
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import threading
 import time
@@ -43,6 +44,7 @@ from ..models.objects import (
     Task, Volume, STORE_OBJECT_TYPES,
 )
 from ..models.types import now
+from ..obs.trace import tracer
 from ..utils.metrics import registry as _metrics
 from ..utils.pipeline import default_pipeline_depth
 from .events import Event, EventCommit, EventSnapshotRestore, EventTaskBlock
@@ -54,6 +56,9 @@ MAX_CHANGES_PER_TX = 200  # reference: memory.go:45-51
 # memory.go:45-51 MaxTransactionBytes = 1.5MB)
 MAX_TX_BYTES = 1_500_000
 WEDGE_TIMEOUT = 30.0      # reference: memory.go:79-146 deadlock tripwire
+# a wait for the update lock this long or longer gets a span of its own
+# (``store.lock_wait``, with the holder's name) while the tracer is on
+LOCK_WAIT_SPAN_S = 0.001
 
 log = logging.getLogger("store")
 
@@ -83,6 +88,9 @@ class _TimedLock:
 
     def acquire(self) -> None:
         t0 = time.monotonic()
+        # who held it when this writer arrived: the name on the wait's
+        # span (racy by design; "" = it was free)
+        holder = self._holder
         while not self._lock.acquire(timeout=WEDGE_TIMEOUT):
             log.error(
                 "store update lock wedged: held for %.0fs by %r "
@@ -91,7 +99,11 @@ class _TimedLock:
         self._acquired_at = time.monotonic()
         self._holder = threading.current_thread().name
         # reference: memory.go:84-112 lockTimer — contention visibility
-        self._wait_timer.observe(self._acquired_at - t0)
+        wait = self._acquired_at - t0
+        self._wait_timer.observe(wait)
+        if wait >= LOCK_WAIT_SPAN_S and tracer.enabled:
+            tracer.record_complete("store.lock_wait", "store", wait,
+                                   holder=holder)
 
     def release(self) -> None:
         held = time.monotonic() - self._acquired_at
@@ -919,6 +931,18 @@ class MemoryStore:
 
     # --------------------------------------------- columnar scheduler commits
 
+    @contextlib.contextmanager
+    def _commit_lock(self):
+        """The update lock for a scheduler commit, its wait under a span
+        of its own (``commit.lock_wait``): with ``commit.apply`` and
+        ``commit.publish`` the three stages of ``sched.commit``."""
+        with tracer.span("commit.lock_wait", "commit"):
+            self._update_lock.acquire()
+        try:
+            yield
+        finally:
+            self._update_lock.release()
+
     def bulk_update_tasks(self, new_tasks: Sequence[Task], on_missing,
                           on_assigned,
                           guard_state: int = 192,  # TaskState.ASSIGNED
@@ -959,7 +983,7 @@ class MemoryStore:
         ts = now()
         if not isinstance(new_tasks, list):
             new_tasks = list(new_tasks)
-        with self._update_lock:
+        with self._commit_lock():
             table = self._tables["tasks"]
             objects = table.objects
             if table.overlay:
@@ -979,65 +1003,69 @@ class MemoryStore:
             i = 0
             while i < n:
                 stop = min(i + MAX_CHANGES_PER_TX, n)
-                with self._lock:
-                    seq = self._version
-                if hp is not None:
-                    committed, failed, stamped, actions, events = \
-                        hp.commit_prepare(
-                            new_tasks, i, stop, objects, seq, ts,
-                            int(guard_state),
-                            StoreAction if want_actions else None,
-                            Event if want_events else None,
-                            on_missing, on_assigned)
-                else:
-                    committed, failed, stamped, actions, events = \
-                        self._commit_prepare_py(
-                            new_tasks, i, stop, objects, seq, ts,
-                            guard_state, want_actions, want_events,
-                            on_missing, on_assigned)
-                i = stop
-                failed_idx.extend(failed)
-                if not stamped:
-                    continue
-
-                def apply_chunk(stamped=stamped):
+                with tracer.span("commit.apply", "commit"):
                     with self._lock:
-                        if hp is not None:
-                            hp.commit_apply(stamped, objects, table.by_node,
-                                            self._reindex_pair)
-                        else:
-                            self._commit_apply_py(stamped, table)
-                        self._version += len(stamped)
-                        for t in stamped:
-                            # old ref elided on this path (replays carry
-                            # old=None)
-                            self._log_change_locked(
-                                ("one", t.meta.version.index, "update",
-                                 t, None), 1)
+                        seq = self._version
+                    if hp is not None:
+                        committed, failed, stamped, actions, events = \
+                            hp.commit_prepare(
+                                new_tasks, i, stop, objects, seq, ts,
+                                int(guard_state),
+                                StoreAction if want_actions else None,
+                                Event if want_events else None,
+                                on_missing, on_assigned)
+                    else:
+                        committed, failed, stamped, actions, events = \
+                            self._commit_prepare_py(
+                                new_tasks, i, stop, objects, seq, ts,
+                                guard_state, want_actions, want_events,
+                                on_missing, on_assigned)
+                    i = stop
+                    failed_idx.extend(failed)
+                    if not stamped:
+                        continue
 
-                if want_actions:
-                    try:
-                        # commit runs inside the consensus apply path (see
-                        # Proposer.propose)
-                        self._propose_fenced(self._proposer, actions,
-                                             apply_chunk, epoch)
-                    except Exception:
-                        # per-chunk failure granularity: earlier chunks are
-                        # committed and stay committed; this chunk and all
-                        # remaining items fail so the caller rolls back only
-                        # what the store did not apply
-                        log.exception("bulk task-update proposal failed")
-                        failed_idx.extend(committed)
-                        failed_idx.extend(range(i, n))
-                        break
-                else:
-                    apply_chunk()
-                committed_idx.extend(committed)
-                if want_events:
-                    publish = self.queue.publish
-                    for ev in events:
-                        publish(ev)
-                self.queue.publish(EventCommit(self._version))
+                    def apply_chunk(stamped=stamped):
+                        with self._lock:
+                            if hp is not None:
+                                hp.commit_apply(stamped, objects,
+                                                table.by_node,
+                                                self._reindex_pair)
+                            else:
+                                self._commit_apply_py(stamped, table)
+                            self._version += len(stamped)
+                            for t in stamped:
+                                # old ref elided on this path (replays
+                                # carry old=None)
+                                self._log_change_locked(
+                                    ("one", t.meta.version.index, "update",
+                                     t, None), 1)
+
+                    if want_actions:
+                        try:
+                            # commit runs inside the consensus apply
+                            # path (see Proposer.propose)
+                            self._propose_fenced(self._proposer, actions,
+                                                 apply_chunk, epoch)
+                        except Exception:
+                            # per-chunk failure granularity: earlier
+                            # chunks are committed and stay committed;
+                            # this chunk and all remaining items fail so
+                            # the caller rolls back only what the store
+                            # did not apply
+                            log.exception("bulk task-update proposal failed")
+                            failed_idx.extend(committed)
+                            failed_idx.extend(range(i, n))
+                            break
+                    else:
+                        apply_chunk()
+                    committed_idx.extend(committed)
+                with tracer.span("commit.publish", "commit"):
+                    if want_events:
+                        publish = self.queue.publish
+                        for ev in events:
+                            publish(ev)
+                    self.queue.publish(EventCommit(self._version))
         return committed_idx, failed_idx
 
     @property
@@ -1108,88 +1136,90 @@ class MemoryStore:
                 old_tasks, node_ids, int(state), message,
                 on_missing, on_assigned, int(guard_state), ts,
                 epoch=epoch)
-        with self._update_lock:
-            table = self._tables["tasks"]
-            objects = table.objects
-            overlay = table.overlay
-            by_node = table.by_node
-            hp = native.get()
-            with self._lock:
-                seq = self._version
-                # slow-path index updates batch into ONE pass per chunk
-                # (_batch_index_tasks) — runs in the finally so an
-                # overlay entry can never outlive its index update
-                pend_index: List[Tuple[str, str, str]] = []
-                try:
-                    slow: Sequence[int] = range(len(old_tasks))
-                    if hp is not None:
-                        fast, slow, seq = hp.block_commit(
-                            old_tasks, node_ids, objects, overlay,
-                            by_node, ts, int(state), message, seq,
-                            int(guard_state))
-                        committed_idx.extend(fast)
-                    for i in slow:
-                        old = old_tasks[i]
-                        tid = old.id
-                        cur = objects.get(tid)
-                        if cur is not old or tid in overlay:
-                            # mirror is not the stored instance: run the
-                            # full bulk-path checks against the stored one
-                            if cur is not None and tid in overlay:
-                                cur = self._materialize_locked(table, tid)
-                            if cur is None:
-                                # callbacks run after the loop: an
-                                # exception here must not strand
-                                # committed versions (see finally)
-                                missing.append((old, node_ids[i]))
-                                continue
-                            cs = cur.status
-                            if cs.state == state \
-                                    and cs.message == message:
-                                continue
-                            if cs.state >= guard_state and \
+        with self._commit_lock():
+            with tracer.span("commit.apply", "commit"):
+                table = self._tables["tasks"]
+                objects = table.objects
+                overlay = table.overlay
+                by_node = table.by_node
+                hp = native.get()
+                with self._lock:
+                    seq = self._version
+                    # slow-path index updates batch into ONE pass per chunk
+                    # (_batch_index_tasks) — runs in the finally so an
+                    # overlay entry can never outlive its index update
+                    pend_index: List[Tuple[str, str, str]] = []
+                    try:
+                        slow: Sequence[int] = range(len(old_tasks))
+                        if hp is not None:
+                            fast, slow, seq = hp.block_commit(
+                                old_tasks, node_ids, objects, overlay,
+                                by_node, ts, int(state), message, seq,
+                                int(guard_state))
+                            committed_idx.extend(fast)
+                        for i in slow:
+                            old = old_tasks[i]
+                            tid = old.id
+                            cur = objects.get(tid)
+                            if cur is not old or tid in overlay:
+                                # mirror is not the stored instance: run the
+                                # full bulk-path checks against the stored one
+                                if cur is not None and tid in overlay:
+                                    cur = self._materialize_locked(table, tid)
+                                if cur is None:
+                                    # callbacks run after the loop: an
+                                    # exception here must not strand
+                                    # committed versions (see finally)
+                                    missing.append((old, node_ids[i]))
+                                    continue
+                                cs = cur.status
+                                if cs.state == state \
+                                        and cs.message == message:
+                                    continue
+                                if cs.state >= guard_state and \
+                                        not on_assigned(old, node_ids[i]):
+                                    failed_idx.append(i)
+                                    continue
+                                if cur.meta.version.index != \
+                                        old.meta.version.index:
+                                    failed_idx.append(i)
+                                    continue
+                            elif cur.status.state >= guard_state and \
                                     not on_assigned(old, node_ids[i]):
                                 failed_idx.append(i)
                                 continue
-                            if cur.meta.version.index != \
-                                    old.meta.version.index:
-                                failed_idx.append(i)
-                                continue
-                        elif cur.status.state >= guard_state and \
-                                not on_assigned(old, node_ids[i]):
-                            failed_idx.append(i)
-                            continue
-                        seq += 1
-                        nid = node_ids[i]
-                        overlay[tid] = (nid, seq, ts, state, message)
-                        pend_index.append((tid, old.node_id, nid))
-                        committed_idx.append(i)
-                finally:
-                    self._batch_index_tasks(by_node, pend_index)
-                    # already-written overlay entries carry versions up to
-                    # seq — the counter must advance past them even if a
-                    # callback raised, or the next commit would reissue
-                    # duplicate version indices
-                    base = self._version
-                    self._version = seq
-                    olds_c = nids_c = None
-                    if committed_idx:
-                        # one columnar changelog entry for the whole
-                        # block: replay materializes per-task lazily.
-                        # Version order within the block matches commit
-                        # order (fast-path items first, then slow).
-                        olds_c = [old_tasks[i] for i in committed_idx]
-                        nids_c = [node_ids[i] for i in committed_idx]
-                        self._log_change_locked(
-                            ("block", base, olds_c, nids_c,
-                             int(state), message, ts),
-                            len(committed_idx))
-            if olds_c and self.queue.has_subscribers():
-                # one coalesced event for the whole block; per-task
-                # events synthesize lazily, shared across subscribers
-                self.queue.publish(EventTaskBlock(
-                    olds_c, nids_c, base, int(state), message, ts))
-            self.queue.publish(EventCommit(self._version))
+                            seq += 1
+                            nid = node_ids[i]
+                            overlay[tid] = (nid, seq, ts, state, message)
+                            pend_index.append((tid, old.node_id, nid))
+                            committed_idx.append(i)
+                    finally:
+                        self._batch_index_tasks(by_node, pend_index)
+                        # already-written overlay entries carry versions up to
+                        # seq — the counter must advance past them even if a
+                        # callback raised, or the next commit would reissue
+                        # duplicate version indices
+                        base = self._version
+                        self._version = seq
+                        olds_c = nids_c = None
+                        if committed_idx:
+                            # one columnar changelog entry for the whole
+                            # block: replay materializes per-task lazily.
+                            # Version order within the block matches commit
+                            # order (fast-path items first, then slow).
+                            olds_c = [old_tasks[i] for i in committed_idx]
+                            nids_c = [node_ids[i] for i in committed_idx]
+                            self._log_change_locked(
+                                ("block", base, olds_c, nids_c,
+                                 int(state), message, ts),
+                                len(committed_idx))
+            with tracer.span("commit.publish", "commit"):
+                if olds_c and self.queue.has_subscribers():
+                    # one coalesced event for the whole block; per-task
+                    # events synthesize lazily, shared across subscribers
+                    self.queue.publish(EventTaskBlock(
+                        olds_c, nids_c, base, int(state), message, ts))
+                self.queue.publish(EventCommit(self._version))
         for old, nid in missing:
             on_missing(old, nid)
         return committed_idx, failed_idx
@@ -1223,51 +1253,52 @@ class MemoryStore:
         committed_idx: List[int] = []
         failed_idx: List[int] = []
         missing: List[Tuple[Task, str]] = []
-        with self._update_lock:
+        with self._commit_lock():
             table = self._tables["tasks"]
             objects = table.objects
             overlay = table.overlay
             by_node = table.by_node
-            with self._lock:
-                base = self._version
-                if hp is not None:
-                    fast, slow = hp.block_validate(
-                        old_tasks, node_ids, objects, overlay,
-                        int(guard_state))
-                    # all-fast blocks keep the range lazy (no 100k-int
-                    # list); slow leftovers force a mutable list
-                    accepted = list(fast) if slow else fast
-                else:
-                    accepted = []
-                    slow = range(len(old_tasks))
-                for i in slow:
-                    old = old_tasks[i]
-                    tid = old.id
-                    cur = objects.get(tid)
-                    if cur is not old or tid in overlay:
-                        # mirror is not the stored instance: full checks
-                        # against the stored one (bulk-path semantics)
-                        if cur is not None and tid in overlay:
-                            cur = self._materialize_locked(table, tid)
-                        if cur is None:
-                            missing.append((old, node_ids[i]))
-                            continue
-                        cs = cur.status
-                        if cs.state == state and cs.message == message:
-                            continue
-                        if cs.state >= guard_state and \
+            with tracer.span("commit.apply", "commit", stage="validate"):
+                with self._lock:
+                    base = self._version
+                    if hp is not None:
+                        fast, slow = hp.block_validate(
+                            old_tasks, node_ids, objects, overlay,
+                            int(guard_state))
+                        # all-fast blocks keep the range lazy (no 100k-int
+                        # list); slow leftovers force a mutable list
+                        accepted = list(fast) if slow else fast
+                    else:
+                        accepted = []
+                        slow = range(len(old_tasks))
+                    for i in slow:
+                        old = old_tasks[i]
+                        tid = old.id
+                        cur = objects.get(tid)
+                        if cur is not old or tid in overlay:
+                            # mirror is not the stored instance: full checks
+                            # against the stored one (bulk-path semantics)
+                            if cur is not None and tid in overlay:
+                                cur = self._materialize_locked(table, tid)
+                            if cur is None:
+                                missing.append((old, node_ids[i]))
+                                continue
+                            cs = cur.status
+                            if cs.state == state and cs.message == message:
+                                continue
+                            if cs.state >= guard_state and \
+                                    not on_assigned(old, node_ids[i]):
+                                failed_idx.append(i)
+                                continue
+                            if cur.meta.version.index != \
+                                    old.meta.version.index:
+                                failed_idx.append(i)
+                                continue
+                        elif cur.status.state >= guard_state and \
                                 not on_assigned(old, node_ids[i]):
                             failed_idx.append(i)
                             continue
-                        if cur.meta.version.index != \
-                                old.meta.version.index:
-                            failed_idx.append(i)
-                            continue
-                    elif cur.status.state >= guard_state and \
-                            not on_assigned(old, node_ids[i]):
-                        failed_idx.append(i)
-                        continue
-                    accepted.append(i)
+                        accepted.append(i)
             # ---- chunked proposals, optionally pipelined.  With a
             # proposer exposing propose_async/wait_proposal and
             # pipeline_depth > 1, up to ``window`` chunk proposals ride
@@ -1288,15 +1319,18 @@ class MemoryStore:
             def reap(entry) -> bool:
                 chunk, olds_c, nids_c, cb_base, waiter = entry
                 try:
-                    proposer.wait_proposal(waiter)
+                    with tracer.span("commit.apply", "commit",
+                                     stage="wait"):
+                        proposer.wait_proposal(waiter)
                 except Exception:
                     log.exception("columnar block proposal failed")
                     failed_idx.extend(chunk)
                     return False
                 committed_idx.extend(chunk)
                 if self.queue.has_subscribers():
-                    self.queue.publish(EventTaskBlock(
-                        olds_c, nids_c, cb_base, state, message, ts))
+                    with tracer.span("commit.publish", "commit"):
+                        self.queue.publish(EventTaskBlock(
+                            olds_c, nids_c, cb_base, state, message, ts))
                 return True
 
             pos = 0
@@ -1371,8 +1405,10 @@ class MemoryStore:
                         ok_to_submit = False
                 else:
                     try:
-                        self._propose_fenced(proposer, [action],
-                                             apply_chunk, epoch)
+                        with tracer.span("commit.apply", "commit",
+                                         stage="propose"):
+                            self._propose_fenced(proposer, [action],
+                                                 apply_chunk, epoch)
                     except Exception:
                         log.exception("columnar block proposal failed")
                         failed_idx.extend(chunk)
@@ -1380,13 +1416,15 @@ class MemoryStore:
                         continue
                     committed_idx.extend(chunk)
                     if self.queue.has_subscribers():
-                        self.queue.publish(EventTaskBlock(
-                            olds_c, nids_c, chunk_base, state, message,
-                            ts))
+                        with tracer.span("commit.publish", "commit"):
+                            self.queue.publish(EventTaskBlock(
+                                olds_c, nids_c, chunk_base, state,
+                                message, ts))
                 chunk_base += len(chunk)
             while pending:
                 reap(pending.popleft())
-            self.queue.publish(EventCommit(self._version))
+            with tracer.span("commit.publish", "commit"):
+                self.queue.publish(EventCommit(self._version))
         for old, nid in missing:
             on_missing(old, nid)
         return committed_idx, failed_idx
@@ -1681,6 +1719,7 @@ class Batch:
         self._tx: Optional[WriteTx] = None
         self.applied = 0    # callbacks run
         self.committed = 0  # changes committed
+        self.flushes = 0    # transactions committed
         self._staged_bytes = 0   # serialized size of staged changes
         self._measured = 0       # changes already size-accounted
 
@@ -1716,6 +1755,7 @@ class Batch:
             n = len(tx._changes)
             self._store._propose_and_commit(tx)
             self.committed += n
+            self.flushes += 1
         finally:
             self._store._update_lock.release()
 

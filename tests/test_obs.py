@@ -328,14 +328,22 @@ def test_bench_tiny_config_emits_valid_trace(tmp_path, monkeypatch,
     monkeypatch.syspath_prepend(repo_root)
     import bench
     bench = importlib.reload(bench)   # re-read env-derived constants
-    # a standalone bench process has no rollout in flight; in suite
-    # order the manager/integration modules leave theirs frozen
-    # mid-update in the process-global registry, and the all-pass health
-    # verdict below would judge them as this run's.  Park them the way
-    # orchestrator/update.py parks a deleted service's.
+    # a standalone bench process starts with nothing on the books; in
+    # suite order whichever modules shared this xdist worker left their
+    # rollouts, overload gauges and plane windows in the process-wide
+    # registry and plane table, and the all-pass health verdict below
+    # would judge them as this run's.  The run is given clean books
+    # (reset in place: components hold their Timer references), less
+    # the compile counters: they are how the artifact names a bucket
+    # that an earlier module of this process already compiled.
+    from swarmkit_tpu.obs import planes
     from swarmkit_tpu.utils.metrics import registry
-    for name in registry.gauges_snapshot('swarm_update_state{service="'):
-        registry.gauge(name, -1.0)
+    compiled = {k: v for k, v in dict(registry.counters).items()
+                if k.startswith('swarm_planner_compiles{')}
+    registry.reset()
+    planes.reset()
+    for key, n in compiled.items():
+        registry.counter(key, n)
     try:
         bench.main()
     finally:
