@@ -643,7 +643,7 @@ def served_phase(smoke: Smoke, n_nodes: int, n_agents: int, replicas: int,
         check_placements(smoke, nodes, created, tasks, agent_ids,
                          host_tasks)
         return report_planner(smoke, planner, replicas, served_s,
-                              compiles_before)
+                              compiles_before, n_nodes)
     finally:
         for agent in agents:
             agent.stop()
@@ -734,7 +734,7 @@ def check_placements(smoke, nodes, created, tasks, agent_ids,
 
 
 def report_planner(smoke, planner, replicas, served_s,
-                   compiles_before) -> dict:
+                   compiles_before, n_nodes) -> dict:
     """Print what the leader's planner did, and fail on any retreat."""
     from swarmkit_tpu import native
     from swarmkit_tpu.ops import TPUPlanner
@@ -744,7 +744,19 @@ def report_planner(smoke, planner, replicas, served_s,
     device_tasks = stats.get("tasks_planned", 0)
     streaming = planner.streaming_snapshot()
     compiled, _dispatched = _compile_ledger_growth(compiles_before)
+    # the break-even router's two sides as this process measured them,
+    # and the smallest group that rides the device at this cluster's size
+    overhead = planner._launch_overhead or 0.0
+    per_node = planner.host_cost_per_node or 0.0
     report = {
+        "router": {
+            "launch_overhead_s": overhead,
+            "host_cost_per_node_s": per_node,
+            "host_cost_per_task_s": planner.host_cost_per_task,
+            "nodes": n_nodes,
+            "break_even_tasks": round(max(
+                0.0, (0.8 * overhead - per_node * n_nodes)
+                / planner.host_cost_per_task), 1)},
         "routes_groups": routes,
         "tasks_device": device_tasks,
         "tasks_host": replicas - device_tasks,
@@ -821,9 +833,9 @@ def run(device: dict, n_nodes: int = N_NODES, n_agents: int = N_AGENTS,
             overhead = TPUPlanner._launch_overhead_shared
             smoke.check(overhead and not probe.stats.get(
                 "launch_probe_failures"), "launch-overhead probe failed")
-            print(f"launch_overhead_s={overhead} break_even_tasks="
-                  f"{(overhead or 0) * 0.8 / probe.host_cost_per_task:.1f}",
-                  flush=True)
+            # the router's other side, the host scan's cost a node, is
+            # timed on a node mirror: the served phase prints both
+            print(f"launch_overhead_s={overhead}", flush=True)
         with smoke.phase("programs"):
             programs_phase(smoke, n_nodes, max(replicas // 5, 1), seed)
         with smoke.phase("served"):

@@ -11,12 +11,15 @@ discrete) generic resources in *node* inventories, and spread-preference
 trees deeper than 4 levels.  Multi-level spread (up to 4 levels) runs on
 device via the kernel's hierarchical stage-A water-fill.
 
-Small groups route to the host path: a device launch costs a fixed
-round-trip (measured once per process, see _measure_launch_overhead)
-while the host oracle costs tens of microseconds per task, so below the
-measured break-even the pipeline seam simply keeps the group on the
-host.  Large groups — where the kernel's margin is 30x+ per decision —
-go to the device.
+Small groups on small clusters route to the host path: a device launch
+costs a fixed round-trip (measured once per process, see
+_measure_launch_overhead) while the host oracle costs a scan of every
+mirrored node per group (measured once per process too, see
+_measure_host_cost_per_node) plus tens of microseconds per task, so
+below the measured break-even the pipeline seam simply keeps the group
+on the host.  Large groups — where the kernel's margin is 30x+ per
+decision — and every group of a cluster whose scan alone outweighs a
+launch go to the device.
 
 Densification builds SoA arrays from the scheduler's NodeSet mirror.  The
 group-independent node columns are built once per tick (begin_tick), kept
@@ -40,7 +43,9 @@ from ..models.types import (
 )
 from ..scheduler import constraint as constraint_mod
 from ..scheduler import strategy as strategy_mod
-from ..scheduler.filters import normalize_arch, _references_volume_plugin
+from ..scheduler.filters import (
+    Pipeline, normalize_arch, _references_volume_plugin,
+)
 from ..scheduler.nodeinfo import NodeInfo
 from ..models.types import TaskState, TaskStatus
 from ..obs import devicetelemetry as _devtel
@@ -351,10 +356,14 @@ class TPUPlanner:
         self.stats = {"groups_planned": 0, "groups_fallback": 0,
                       "groups_small_to_host": 0,
                       "tasks_planned": 0, "plan_seconds": 0.0}
-        # measured fixed launch overhead (dispatch + D2H round-trip on a
-        # minimal workload) vs. the host oracle's per-task cost: groups too
+        # the break-even router's two sides (_route_costs): the measured
+        # fixed launch overhead (dispatch + D2H round-trip on a minimal
+        # workload) vs. what the host route costs, a scan of every
+        # mirrored node per group (measured, _measure_host_cost_per_node)
+        # plus the host oracle's marginal cost of a task.  Groups too
         # small to amortize a device round-trip stay on the host path
         self._launch_overhead = None
+        self.host_cost_per_node = None
         self.host_cost_per_task = 50e-6
         # set False to force every supported group onto the device (bench
         # warm-ups, differential tests)
@@ -732,18 +741,83 @@ class TPUPlanner:
             self._count("launch_probe_failures")
             self._launch_overhead = 0.0
 
-    def _below_break_even(self, n_tasks: int) -> bool:
-        """True when a group is too small to amortize the device launch
-        overhead.  The single predicate every routing site shares —
-        dispatch_group, the host pre-validate path, and the fused-run
-        probe must agree on it, or fused and per-group routing drift
-        apart silently."""
-        if not self.enable_small_group_routing:
-            return False
+    _host_cost_per_node_shared: Optional[float] = None  # per-process
+    #: what the scan is taken to cost a node when its timing fails
+    HOST_COST_PER_NODE_FALLBACK = 3e-6
+
+    def _measure_host_cost_per_node(self, sched, t: Task) -> None:
+        """Time what the host route pays per mirrored node, whatever
+        the group's size: the host oracle's densify (the filter
+        pipeline and the per-node resource and count reads,
+        ``strategy.build_host_columns``) over up to 1,024 of the
+        mirror's NodeInfos, after one warm pass, as the launch probe.
+        A small mirror is scanned as often as makes 1,024 visits, so
+        one preempted pass cannot set the figure.  A property of the
+        process's CPU, so it is measured once and shared across planner
+        instances; it reads the mirror and mutates nothing (its filter
+        pipeline is its own, not the scheduler's)."""
+        import itertools
+        import time as _time
+        from types import SimpleNamespace
+        cls = type(self)
+        if cls._host_cost_per_node_shared is None:
+            try:
+                infos = list(itertools.islice(
+                    sched.node_set.nodes.values(), 1024))
+                # all build_host_columns asks of a scheduler
+                scan = SimpleNamespace(pipeline=Pipeline())
+                ts = self.fail_ts()
+                passes = -(-1024 // len(infos))
+                strategy_mod.build_host_columns(scan, t, 1, infos, ts)
+                t0 = _time.perf_counter()
+                for _ in range(passes):
+                    strategy_mod.build_host_columns(scan, t, 1, infos, ts)
+                # only successful timings are shared, as the launch probe's
+                cls._host_cost_per_node_shared = \
+                    (_time.perf_counter() - t0) / (passes * len(infos))
+            except Exception:
+                log.exception("host-scan timing failed; constant used")
+                self._count("host_cost_probe_failures")
+        shared = cls._host_cost_per_node_shared
+        self.host_cost_per_node = self.HOST_COST_PER_NODE_FALLBACK \
+            if shared is None else shared
+        self.stats["host_cost_per_node"] = self.host_cost_per_node
+
+    def _route_costs(self, sched, t: Task, n_tasks: int, scan: bool = True):
+        """The two sides of the break-even, in seconds, and the mirror's
+        size: (what the host route would cost a group of ``n_tasks``
+        like ``t``, what a device launch must beat, the ``nodes`` the
+        host route scans for it: every mirrored node, or none where
+        ``scan`` is False).  A pure function of (n_tasks, nodes) once
+        the two probes ran."""
+        nodes = len(sched.node_set.nodes) if scan else 0
         if self._launch_overhead is None:
             self._measure_launch_overhead()
-        return (n_tasks * self.host_cost_per_task
-                < 0.8 * self._launch_overhead)
+        if self.host_cost_per_node is None and nodes:
+            self._measure_host_cost_per_node(sched, t)
+        host = (nodes * (self.host_cost_per_node or 0.0)
+                + n_tasks * self.host_cost_per_task)
+        return host, 0.8 * self._launch_overhead, nodes
+
+    def _below_break_even(self, sched, t: Task, n_tasks: int,
+                          scan: bool = True, sp=None) -> bool:
+        """True when a group of ``n_tasks`` like ``t`` is too small to
+        amortize the device launch overhead: the host route, which
+        scans the scheduler's node mirror for it (``scan``), is
+        estimated cheaper.  The single predicate every routing site
+        shares — dispatch_group, the host pre-validate path, and the
+        fused-run probe must agree on it, or fused and per-group routing
+        drift apart silently.  A span ``sp`` is given the comparison
+        that decided, to hold against sched.host_fallback's and
+        plan.inflight's readings."""
+        if not self.enable_small_group_routing:
+            return False
+        host, device, nodes = self._route_costs(sched, t, n_tasks, scan)
+        if sp is not None:
+            sp.args.update(host_est_ms=round(host * 1e3, 3),
+                           device_est_ms=round(device * 1e3, 3),
+                           nodes=nodes)
+        return host < device
 
     def _fallback(self) -> bool:
         # the host path will mutate NodeInfos the cached columns mirror
@@ -795,11 +869,12 @@ class TPUPlanner:
             return False
         return self.fetch_group(handle)
 
-    def _route_to_host(self, t: Task, k: int):
+    def _route_to_host(self, sched, t: Task, k: int, sp=None):
         """The router's decision for one group of ``k`` tasks like ``t``:
         (the reason it rides the host path, or None for the device; its
         resolved strategy).  Routing counters, breaker bookkeeping and
-        column-cache invalidation are applied here, exactly once."""
+        column-cache invalidation are applied here, exactly once; the
+        ``plan.route`` span ``sp`` gets the break-even's two sides."""
         if not self._supported(t):
             self._fallback()
             return "fallback", None
@@ -827,7 +902,7 @@ class TPUPlanner:
             self._count("groups_breaker_to_host")
             self._cache = None   # host path mutates NodeInfos
             return "breaker", sinfo
-        if self._below_break_even(k):
+        if self._below_break_even(sched, t, k, sp=sp):
             self._count("groups_small_to_host")
             self.breaker.abort_probe()   # never reached the device
             self._cache = None   # host path mutates NodeInfos
@@ -851,7 +926,8 @@ class TPUPlanner:
         t = next(iter(task_group.values()))
         with tracer.span("plan.route", "plan", tasks=len(task_group),
                          service=t.service_id) as sp:
-            host, sinfo = self._route_to_host(t, len(task_group))
+            host, sinfo = self._route_to_host(sched, t, len(task_group),
+                                              sp)
             if sp is not None:
                 sp.args["route"] = host or "device"
         if host is not None:
@@ -1237,7 +1313,9 @@ class TPUPlanner:
             # dispatch_group so route breakdowns stay honest
             self._count("groups_breaker_to_host")
             return tasks
-        if self._below_break_even(len(tasks)):
+        # the host loop checks each task against its own node
+        # (_task_fit_node): it scans none, so no node term here
+        if self._below_break_even(sched, t, len(tasks), scan=False):
             self.breaker.abort_probe()
             return tasks   # below device break-even: host loop
         import time as _time
@@ -1618,7 +1696,8 @@ class TPUPlanner:
         specs = []
         with tracer.span("plan.fused_probe", "plan") as sp:
             for group in glist[start:]:
-                if self._below_break_even(len(group)):
+                if self._below_break_even(
+                        sched, next(iter(group.values())), len(group)):
                     break   # below device break-even: host path
                 spec = fusedbatch.probe_group(self, sched, group)
                 if spec is None:

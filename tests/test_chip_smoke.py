@@ -143,6 +143,8 @@ def test_forced_retreats_become_a_nonzero_exit(monkeypatch, capsys):
     monkeypatch.setenv("SWARM_NATIVE_COMMIT", "0")
     # other test modules switch logging off process-wide at import
     monkeypatch.setattr(logging.root.manager, "disable", logging.NOTSET)
+    # and a logger that was asked while it was off remembers the answer
+    logging.root.manager._clear_cache()
     monkeypatch.setattr(chip_smoke, "programs_phase",
                         lambda *args: None)
     rc = chip_smoke.run(CPU, n_nodes=64, n_agents=2, replicas=2000,
