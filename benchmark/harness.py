@@ -639,7 +639,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
         obs.slice_wall = profiled.slice_wall
         obs.slice_dispatches = profiled.dispatches()
         obs.peak_bytes_per_s = peaks["hbm_bytes_per_s"] if peaks else None
-        metrics = readers.read_all(name, obs)
+        metrics = readers.read_all(name, obs, bench["per_layer"])
         log("window counters " + json.dumps(
             {src: {k: v for k, v in obs.counters[src].items() if v}
              for src in ("planner.stats", "scheduler.stats",

@@ -1,9 +1,12 @@
 """The reader kinds of the per-layer metrics.
 
 A per-layer metric is one file, ``layer_metrics/<name>.json``: its
-``layer``, ``unit``, ``better``, ``moves``, ``workloads``, and a
-``reader`` of one of four kinds with that kind's parameters.  A later PR
-adds a metric that an existing kind can read as one new file.
+``layer``, ``unit``, ``better``, ``moves``, and a ``reader`` of one of
+four kinds with that kind's parameters.  Which cells report it is said
+once, by the ``workloads`` list of its ``per_layer`` entry in
+``BENCHMARK.json``.  A later PR adds a metric that an existing kind can
+read as one new file and one appended entry, and a cell to a metric as
+one appended item of that list.
 
 ``client``        a series the harness's own clients recorded (host clock)
 ``span``          spans of the program's tracer, by name
@@ -116,10 +119,12 @@ def read_span(params: dict, obs: Observations) -> Optional[float]:
             return None
         return 100.0 * _arg_sum(obs, params["num"], whole) / den
     if how == "arg_mean":
-        rows = [args.get(params["arg"], 0)
+        # over the spans that carry the argument: a tree whose spans
+        # lack it has nothing to read, not a mean of nought
+        rows = [args[params["arg"]]
                 for _t, name, _a, b, args in obs.spans
                 if name == params["span"] and args
-                and b <= obs.window_wall[1]]
+                and params["arg"] in args and b <= obs.window_wall[1]]
         return sum(rows) / len(rows) if rows else None
     spans = [sp[3] - sp[2] for sp in obs.spans if sp[1] == params["span"]]
     ticks = _ticks(obs)
@@ -209,12 +214,16 @@ def load_layer_metrics() -> Dict[str, dict]:
     return out
 
 
-def read_all(cell: str, obs: Observations) -> Dict[str, dict]:
-    """{name: {"value", "unit"}} for every per-layer metric that lists
-    ``cell`` and found something to read."""
+def read_all(cell: str, obs: Observations,
+             per_layer: List[dict]) -> Dict[str, dict]:
+    """{name: {"value", "unit"}} for every per-layer metric that found
+    something to read, of those whose entry in ``per_layer``
+    (``BENCHMARK.json``'s list) names ``cell``."""
+    reporting = {entry["name"] for entry in per_layer
+                 if cell in entry["workloads"]}
     out = {}
     for name, spec in load_layer_metrics().items():
-        if cell not in spec["workloads"]:
+        if name not in reporting:
             continue
         value = KINDS[spec["reader"]["kind"]](spec["reader"], obs)
         if value is not None:

@@ -1,9 +1,13 @@
 """Whole runs of cell 1, of the cell kept as data and of the planted
-faults on the forced CPU at a tiny size, in a process of their own: a run leaves the program's
-process-wide state behind (metrics registry, flight recorder, tracer),
-which the tests that share a worker with ``test_benchmark_harness.py``
-must not inherit.  Takes the runs to make as arguments and prints one
-JSON object: {name: [exit code, line]}.
+faults on the forced CPU at a tiny size, and the small traced deploy the
+per-layer metrics' tests read (``deploy``), in a process of their own: a
+run leaves the program's process-wide state behind (metrics registry,
+flight recorder, tracer, a warm planner), which the tests that share a
+worker with ``tests/benchmark`` must not inherit.  Takes the runs to make
+as arguments and prints one JSON object: {name: [exit code, line]}.
+``--root <tree>`` first makes them on the benchmark under that tree (its
+``BENCHMARK.json``, data files and ``tests/benchmark/shrink``) in this
+repo's place.
 
 The TPU check is switched off here, in the tests, and nowhere in the
 command."""
@@ -14,21 +18,27 @@ import sys
 import time
 
 os.environ["JAX_PLATFORMS"] = "cpu"
-REPO = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
+HERE = os.path.dirname(os.path.abspath(__file__))
+REPO = os.path.dirname(os.path.dirname(HERE))
 sys.path.insert(0, REPO)
 
-#: the cells cut to what a test run can hold
-SHRINK = {
-    "swarm-10k.deploys": {
-        "cluster": {"nodes": 300, "racks_per_zone": 5, "agents": 6},
-        "traffic": {"tasks_per_s": 150}},
-    "harness-100k.backlog": {
-        "cluster": {"nodes": 1200, "racks_per_zone": 10, "agents": 6},
-        "traffic": {"clients": [
-            {"shape": s, "replicas": 120}
-            for s in ("spread", "constrained", "binpack", "topology")]}},
-}
+
+def load_shrinks(root: str) -> dict:
+    """{cell: its cut to what a test run can hold}: overrides of the
+    configuration's ``cluster`` and of the traffic's parameters, one file
+    a cell, ``tests/benchmark/shrink/<cell>.json``, so that a new cell
+    brings its own."""
+    folder = os.path.join(root, "tests", "benchmark", "shrink")
+    out = {}
+    for fname in sorted(os.listdir(folder)):
+        if fname.endswith(".json"):
+            with open(os.path.join(folder, fname)) as f:
+                out[fname[:-len(".json")]] = json.load(f)
+    return out
+
+
+#: this repo's (``scripts/servedpath_trace.py --cpu`` reads it too)
+SHRINK = load_shrinks(REPO)
 FAULTS = ("host_route", "answer_altered", "group_on_one_node")
 #: cells kept as data files that ``BENCHMARK.json`` does not list (PERF.md
 #: 7, first row): a rehearsal brings the ``workloads`` entry itself
@@ -41,9 +51,26 @@ KEPT = {
 }
 
 
+def deploy() -> dict:
+    """``tests/servedpath_deploy.py``'s traced deploy, as the plain data
+    an ``Observations`` is filled from."""
+    sys.path.insert(1, os.path.dirname(HERE))
+    import servedpath_deploy
+    made = servedpath_deploy.traced_deploy()
+    return {"spans": [s[:5] for s in made["spans"]], "wall": made["wall"],
+            "counters": made["counters"]}
+
+
 def main(names) -> None:
-    """``names``: ``<cell>:plain``, ``<cell>:traced`` or a fault's name
-    (a plain run of cell 1 with that fault planted)."""
+    """``names``: ``<cell>:plain``, ``<cell>:traced``, a fault's name
+    (a plain run of cell 1 with that fault planted) or ``deploy``."""
+    shrinks = SHRINK
+    if names[:1] == ["--root"]:
+        root, names = names[1], names[2:]
+        shrinks = load_shrinks(root)
+        sys.path.insert(1, HERE)
+        import contract
+        contract.point_at(root)
     # two cores and a low priority: the tier-1 run has timing-sensitive
     # daemon tests beside this process
     try:
@@ -60,14 +87,17 @@ def main(names) -> None:
     real_stdout, sys.stdout = sys.stdout, sys.stderr
     try:
         for name in names:
+            if name == "deploy":
+                out[name] = 0, deploy()
+                continue
             if name in FAULTS:
                 cell, trace, seed, seconds = \
                     "swarm-10k.deploys", False, 91, 3
-                rehearsal = Rehearsal(name, SHRINK[cell], require_tpu=False)
+                rehearsal = Rehearsal(name, shrinks[cell], require_tpu=False)
             else:
                 cell, how = name.split(":")
                 trace, seed, seconds = how == "traced", 2 ** 31 + 77, 4
-                rehearsal = Rehearsal(None, SHRINK[cell], require_tpu=False,
+                rehearsal = Rehearsal(None, shrinks[cell], require_tpu=False,
                                       cell=KEPT.get(cell))
             out[name] = harness.run_cell(
                 cell, seed=seed, seconds=seconds, trace=trace,

@@ -1,15 +1,16 @@
 """The benchmark's harness, rehearsed on the forced CPU at a tiny size:
-the contract of ``BENCHMARK.json`` and its data files, the generator, the
-plain reference and its controls, and whole runs of cell 1 and of the
-cell kept as data (``rehearse_cells.KEPT``) through
-``harness.run_cell`` (``rehearse_cells.py`` makes them in a process of its
-own; the TPU check is switched off there, in the tests, and nowhere in the
-command)."""
+the contract of ``BENCHMARK.json`` and its data files (every such test is
+made twice, on the repo's benchmark and on the one ``one_more.py``
+assembles with one more cell, configuration, traffic file and two more
+per-layer metrics from new data only), the generator, the plain reference
+and its controls, and whole runs of cell 1, of the added cell and of the
+cell kept as data (``rehearse_cells.KEPT``) through ``harness.run_cell``
+(``rehearse_cells.py`` makes them in a process of its own; the TPU check
+is switched off there, in the tests, and nowhere in the command)."""
 
 import collections
 import json
 import os
-import re
 import subprocess
 import sys
 
@@ -22,85 +23,155 @@ sys.path.insert(1, os.path.dirname(os.path.abspath(__file__)))
 
 from benchmark import cluster, harness, readers, reference, traffic  # noqa: E402
 from benchmark import warmup  # noqa: E402
-from rehearse_cells import KEPT  # noqa: E402
+import contract  # noqa: E402
+import one_more  # noqa: E402
+from rehearse_cells import KEPT, load_shrinks  # noqa: E402
 
-NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
-UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
-SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 BENCH = harness.load_benchmark()
 CELLS = [w["name"] for w in BENCH["workloads"]]
 
 # ------------------------------------------------------- the data's contract
+#
+# Every test that reads ``BENCHMARK.json`` or a metric's file is made on
+# two trees (``conftest.py``: fixture ``bench``).
 
-def test_benchmark_json_has_exactly_the_contract_keys():
-    assert set(BENCH) == {"command", "paths", "run_seconds", "configs",
+#: what each tree holds, for the tests that take one case a name; the
+#: added names are ``one_more``'s constants, and
+#: ``test_one_more_of_everything_is_only_additions`` holds the tree to them
+METRICS_OF = {"repo": sorted(readers.load_layer_metrics())}
+METRICS_OF["one_more"] = sorted(METRICS_OF["repo"]
+                                + list(one_more.NEW_METRICS))
+CELLS_OF = {"repo": CELLS, "one_more": CELLS + [one_more.CELL]}
+TREES = one_more.TREES
+EVERY_TREE = pytest.mark.parametrize("bench", TREES, indirect=True)
+
+
+def _per(names_of):
+    """One case a tree and a name of it."""
+    cases = [(tree, name) for tree in TREES for name in names_of[tree]]
+    return pytest.mark.parametrize(
+        "bench,name", cases, indirect=["bench"],
+        ids=[f"{tree}-{name}" for tree, name in cases])
+
+
+def _cell(bench, name):
+    return {w["name"]: w for w in bench["workloads"] + list(KEPT.values())
+            }[name]
+
+
+@EVERY_TREE
+def test_benchmark_json_has_exactly_the_contract_keys(bench):
+    assert set(bench) == {"command", "paths", "run_seconds", "configs",
                           "workloads", "end_to_end", "per_layer"}
-    assert BENCH["paths"] == ["benchmark", "tests/benchmark"]
-    assert 1 <= BENCH["run_seconds"] <= 51
-    assert any(m["name"] == "setup_s" for m in BENCH["end_to_end"])
-    assert os.path.getsize(os.path.join(REPO, "BENCHMARK.json")) < 64 << 10
+    assert bench["paths"] == ["benchmark", "tests/benchmark"]
+    assert 1 <= bench["run_seconds"] <= 51
+    assert any(m["name"] == "setup_s" for m in bench["end_to_end"])
+    assert os.path.getsize(
+        os.path.join(harness.ROOT, "BENCHMARK.json")) < 64 << 10
 
 
-@pytest.mark.parametrize("cell", BENCH["workloads"], ids=CELLS)
-def test_every_cell_resolves_to_a_configuration_and_a_traffic_file(cell):
-    assert set(cell) == {"name", "config", "traffic", "chips", "why"}
-    configs = {c["name"]: c for c in BENCH["configs"]}
-    entry = configs[cell["config"]]
-    assert entry["file"] == f"benchmark/configs/{cell['config']}.json"
-    config = cluster.load_config(cell["config"])
-    assert config["source"] == entry["source"] and len(entry["source"]) <= 200
-    assert config["reduced"] == entry["reduced"]
-    assert config["chips"] == cell["chips"] == 1
-    params = traffic.load(cell["traffic"])
-    assert params["generator"] in traffic.GENERATORS
-    assert cell["name"] == f"{cell['config']}.{cell['traffic']}"
-    assert 1 <= len(cell["why"]) <= 200 and "\n" not in cell["why"]
+@_per(CELLS_OF)
+def test_every_cell_resolves_to_a_configuration_and_a_traffic_file(
+        bench, name):
+    contract.cell_resolves(bench, _cell(bench, name))
 
 
-def test_names_and_units_hold_only_the_allowed_characters():
-    names = [m["name"] for m in BENCH["end_to_end"] + BENCH["per_layer"]]
-    names += CELLS + [c["name"] for c in BENCH["configs"]]
-    names += [w["traffic"] for w in BENCH["workloads"]]
-    assert len(set(names[:len(BENCH["end_to_end"])
-                         + len(BENCH["per_layer"])])) \
-        == len(BENCH["end_to_end"]) + len(BENCH["per_layer"])
-    for name in names:
-        assert NAME.match(name), name
-    for m in BENCH["end_to_end"] + BENCH["per_layer"]:
-        assert UNIT.match(m["unit"]), m
-        assert m["better"] in ("lower", "higher")
-        assert m["source"] in SOURCES
-    for m in BENCH["end_to_end"]:
-        assert m["source"] in ("host_clock", "device_trace")
-        assert 0.01 <= m["bound"] <= 0.25
+@EVERY_TREE
+def test_names_and_units_hold_only_the_allowed_characters(bench):
+    contract.names_and_units(bench)
 
 
-LAYER_METRICS = readers.load_layer_metrics()
+@_per(METRICS_OF)
+def test_layer_metric_file_is_sound_and_matches_benchmark_json(bench, name):
+    contract.layer_metric_is_sound(
+        bench, name, readers.load_layer_metrics()[name])
 
 
-@pytest.mark.parametrize("name", sorted(LAYER_METRICS))
-def test_layer_metric_file_is_sound_and_matches_benchmark_json(name):
-    spec = LAYER_METRICS[name]
-    assert set(spec) == {"layer", "unit", "better", "moves", "workloads",
-                         "reader"}
-    assert spec["reader"]["kind"] in readers.KINDS
-    end_to_end = {m["name"]: m for m in BENCH["end_to_end"]}
-    moved = end_to_end[spec["moves"]]
-    reporting = set(moved.get("workloads", CELLS))
-    assert spec["workloads"] and set(spec["workloads"]) <= reporting
-    entry = {m["name"]: m for m in BENCH["per_layer"]}[name]
-    assert entry == {
-        "name": name, "unit": spec["unit"], "better": spec["better"],
-        "source": readers.SOURCE_OF_KIND[spec["reader"]["kind"]],
-        "layer": spec["layer"], "moves": spec["moves"],
-        "workloads": spec["workloads"]}
-    if name.endswith("_roofline") or "mfu" in name:
-        assert spec["unit"] == "%"
+@EVERY_TREE
+def test_every_per_layer_entry_has_its_file(bench):
+    contract.every_entry_has_its_file(bench, readers.load_layer_metrics())
 
 
-def test_every_per_layer_entry_has_its_file():
-    assert sorted(m["name"] for m in BENCH["per_layer"]) \
-        == sorted(LAYER_METRICS)
+@EVERY_TREE
+def test_benchmark_json_alone_says_which_cells_report_a_metric(bench):
+    """One list: the ``per_layer`` entry's.  No metric's file repeats it,
+    and ``readers.read_all`` is handed the entries."""
+    for name, spec in readers.load_layer_metrics().items():
+        assert "workloads" not in spec, name
+    obs = readers.Observations()
+    obs.series = {"create_rpc_s": [0.25, 0.75]}
+    entry = {m["name"]: m for m in bench["per_layer"]}["create_rpc_ms"]
+    there = dict(entry, workloads=["some.cell"])
+    assert readers.read_all("some.cell", obs, [there]) \
+        == {"create_rpc_ms": {"value": 500.0, "unit": "ms"}}
+    assert readers.read_all("other.cell", obs, [there]) == {}
+
+
+@EVERY_TREE
+def test_the_routing_counter_reads_the_host_route(bench):
+    """``host_route_groups_pct`` (ISSUE 28's; it came when PR 28 left the
+    two span-read host-route metrics nothing to read in the one cell that
+    listed them): counters, which say 0 where no group rides the host."""
+    entry = {m["name"]: m for m in bench["per_layer"]}[
+        "host_route_groups_pct"]
+    assert "swarm-10k.deploys" in entry["workloads"]
+    assert readers.load_layer_metrics()["host_route_groups_pct"] == {
+        "layer": "planner routing", "unit": "%", "better": "lower",
+        "moves": "decisions_per_s",
+        "reader": {"kind": "counter", "scale": 100.0,
+                   "num": {"source": "planner.stats",
+                           "key": "groups_small_to_host"},
+                   "den": {"source": "planner.stats",
+                           "keys": ["groups_small_to_host",
+                                    "groups_planned", "groups_fused"]}}}
+
+
+ROUTES = {
+    "most_on_the_host": ({"groups_small_to_host": 330, "groups_planned": 20,
+                          "groups_fused": 50}, 82.5),
+    "none_on_the_host": ({"groups_small_to_host": 0, "groups_planned": 140,
+                          "groups_fused": 250}, 0.0),
+    "no_group_at_all": ({"groups_small_to_host": 0, "groups_planned": 0},
+                        None)}
+
+
+@_per({tree: list(ROUTES) for tree in TREES})
+def test_host_route_groups_pct_reads_nought_and_not_nothing(bench, name):
+    routes, value = ROUTES[name]
+    obs = readers.Observations()
+    obs.counters = {"planner.stats": routes}
+    line = readers.read_all("swarm-10k.deploys", obs, bench["per_layer"])
+    if value is None:
+        assert "host_route_groups_pct" not in line
+    else:
+        assert line["host_route_groups_pct"] == {"value": value, "unit": "%"}
+
+
+def _spans(*rows):
+    """Spans of one thread inside a window of ten seconds."""
+    obs = readers.Observations()
+    obs.window_wall = (0.0, 10.0)
+    obs.spans = [("scheduler", name, a, b, args) for name, a, b, args in rows]
+    return obs
+
+
+@pytest.mark.parametrize("rows,value", [
+    ([("sched.batch_build", 1.0, 1.1, {"wait_mean_ms": 30.0}),
+      ("sched.batch_build", 2.0, 2.1, {"wait_mean_ms": 50.0})], 40.0),
+    ([("sched.batch_build", 1.0, 1.1, {"wait_mean_ms": 0.0}),
+      ("sched.batch_build", 2.0, 2.1, {"tasks": 7})], 0.0),
+    ([("sched.batch_build", 1.0, 1.1, {"tasks": 7}),
+      ("sched.batch_build", 2.0, 2.1, None)], None),
+    ([("sched.batch_build", 9.9, 10.2, {"wait_mean_ms": 30.0})], None),
+], ids=["on_every_span", "read_where_it_is", "on_no_span",
+        "only_past_the_close"])
+def test_arg_mean_leaves_out_what_no_span_of_the_window_carries(rows, value):
+    """A tree whose spans lack the argument (PR 26's had neither
+    ``wait_mean_ms`` nor ``offcpu_ms``) has nothing to read: the metric is
+    left out of the line, not written as a mean of nought."""
+    params = readers.load_layer_metrics()["queue_wait_ms"]["reader"]
+    assert params["reduce"] == "arg_mean"
+    assert readers.read_span(params, _spans(*rows)) == value
 
 
 # ---------------------------------------------------------- the generator
@@ -152,20 +223,37 @@ def test_a_cell_kept_as_data_still_resolves(cell):
     assert {c["shape"] for c in clients} <= set(config["shapes"])
 
 
-@pytest.mark.parametrize("cell", BENCH["workloads"] + list(KEPT.values()),
-                         ids=CELLS + list(KEPT))
-def test_warmup_enumerates_the_signatures_of_a_cell(cell):
-    config = cluster.load_config(cell["config"])
-    nodes = cluster.plain_nodes(config["cluster"], seed=3)
-    stacks, labels = warmup.plan(config, traffic.load(cell["traffic"]),
-                                 nodes)
-    nb = 16384 if len(nodes) == 10_000 else 131072
-    assert f"nb{nb}_cc1_p1_L1_h0" in labels
-    assert f"nb{nb}_cc1_p1_L1_h0_st1" in labels
-    leaves = 256 if nb == 16384 else 4096
-    assert f"nb{nb}_cc1_p1_L{leaves}_h2" in labels
-    assert sum(1 for lb in labels if lb.startswith("fused_")) == 4
-    assert ["topology"] in stacks and max(map(len, stacks)) == 3
+EVERY_CELL = _per({tree: CELLS_OF[tree] + list(KEPT) for tree in TREES})
+#: what the derivation has to give for the two sizes the repo has, checked
+#: once against the literals the warm-up test named before it derived them:
+#: (the preference tree's label, fused labels, the longest stack)
+TODAY = {"swarm-10k.deploys": ("nb16384_cc1_p1_L256_h2", 4, 3),
+         "harness-100k.backlog": ("nb131072_cc1_p1_L4096_h2", 4, 3)}
+
+
+@EVERY_CELL
+def test_warmup_enumerates_the_signatures_of_a_cell(bench, name):
+    stacks, labels, nb = contract.warmup_enumerates(_cell(bench, name))
+    if name in TODAY:
+        tree, fused, longest = TODAY[name]
+        assert tree in labels and tree.startswith(f"nb{nb}_")
+        assert f"nb{nb}_cc1_p1_L1_h0" in labels
+        assert f"nb{nb}_cc1_p1_L1_h0_st1" in labels
+        assert sum(lb.startswith("fused_") for lb in labels) == fused
+        assert ["topology"] in stacks and max(map(len, stacks)) == longest
+
+
+@EVERY_CELL
+def test_every_cell_brings_its_cut_to_a_test_s_size(bench, name):
+    contract.cut_to_a_test_s_size(load_shrinks(harness.ROOT),
+                                  _cell(bench, name))
+
+
+@EVERY_TREE
+def test_a_cell_without_its_cut_fails_by_name(bench):
+    cell = dict(bench["workloads"][0], name="swarm-10k.nightly")
+    with pytest.raises(AssertionError, match="swarm-10k.nightly.json"):
+        contract.cut_to_a_test_s_size(load_shrinks(harness.ROOT), cell)
 
 
 def test_warmup_reads_its_ladders_from_the_planner(monkeypatch):
@@ -310,10 +398,22 @@ def _check_line(line):
         assert number <= limit
 
 
-def test_one_whole_run_of_cell_1_through_run_cell():
+@pytest.fixture(scope="module")
+def tier1_runs(one_more_tree):
+    """The whole runs that stay in tier-1, made in one process on that
+    tree (for cell 1 the repo's own files, byte for byte:
+    ``test_one_more_of_everything_is_only_additions``): a plain run of
+    cell 1, and the added cell plain and traced beside cell 1 traced."""
+    tree, _ = one_more_tree
+    return _rehearse("--root", tree, "swarm-10k.deploys:plain",
+                     f"{one_more.CELL}:plain", f"{one_more.CELL}:traced",
+                     "swarm-10k.deploys:traced")
+
+
+def test_one_whole_run_of_cell_1_through_run_cell(tier1_runs):
     """``harness.run_cell`` end to end on the forced CPU: warm-up, window,
     drain, read-back, comparison, the contract's result line."""
-    code, line = _rehearse("swarm-10k.deploys:plain")["swarm-10k.deploys:plain"]
+    code, line = tier1_runs["swarm-10k.deploys:plain"]
     assert code == 0
     _check_line(line)
     assert line["correct"] is True and line["failed"] == 0
@@ -323,6 +423,87 @@ def test_one_whole_run_of_cell_1_through_run_cell():
     assert set(line["metrics"]) == e2e
     assert line["compared"]["window_compiles"] == [0, 0]
     assert list(line["compared"])[-1] == "failed"
+
+
+# ------------------------------------------- one more of everything, as data
+#
+# A later PR adds a deployment's cell with new files, appended entries and
+# appended list items, and edits nothing that is there.  ``one_more.py``
+# does exactly that in a temporary tree; the tests above are made on that
+# tree as on the repo's, and the tests below hold it to "only additions"
+# and run the added cell.
+
+def test_one_more_of_everything_is_only_additions(one_more_tree):
+    tree, extended = one_more_tree
+    contract.only_additions(BENCH, extended)
+    for key, n in (("configs", 1), ("workloads", 1), ("per_layer", 2),
+                   ("end_to_end", 0)):
+        assert len(extended[key]) == len(BENCH[key]) + n
+    was, now = contract.data_files(REPO), contract.data_files(tree)
+    assert {rel: now[rel] for rel in was} == was
+    assert sorted(set(now) - set(was)) == sorted(
+        [f"benchmark/configs/{one_more.CONFIG}.json",
+         f"benchmark/traffic/{one_more.TRAFFIC}.json",
+         f"tests/benchmark/shrink/{one_more.CELL}.json"]
+        + [f"benchmark/layer_metrics/{name}.json"
+           for name in one_more.NEW_METRICS])
+    lists = {m["name"]: m.get("workloads")
+             for m in extended["end_to_end"] + extended["per_layer"]}
+    for name, listed in lists.items():
+        if name in one_more.LISTED or name in one_more.NEW_METRICS:
+            assert listed[-1] == one_more.CELL
+        else:
+            assert listed is None or one_more.CELL not in listed
+
+
+@pytest.mark.parametrize("bench", ["one_more"], indirect=True)
+def test_warmup_enumerates_the_added_cell_at_a_third_size(bench):
+    """1,250 nodes, 10 racks a zone, a cycle of three shapes: the 2,048
+    bucket, 40 racks in the 256 leaf bucket, runs of two at the most."""
+    assert [w["name"] for w in bench["workloads"]] == CELLS_OF["one_more"]
+    stacks, labels, nb = contract.warmup_enumerates(bench["workloads"][-1])
+    assert nb == 2048
+    assert labels == [
+        "fused_g1_nb2048_cc1_p1_L1_s2_mx1", "nb2048_cc1_p1_L1_h0",
+        "nb2048_cc1_p1_L1_h0_st1", "nb2048_cc1_p1_L256_h2",
+        "stream_nb2048_d16", "stream_nb2048_d256", "stream_nb2048_d4096"]
+    assert sorted(stacks) == [["binpack"], ["spread"], ["spread", "binpack"],
+                              ["topology"]]
+
+
+def test_the_added_cell_runs_plain_and_reports_the_end_to_end_metrics(
+        tier1_runs):
+    code, line = tier1_runs[f"{one_more.CELL}:plain"]
+    assert code == 0
+    _check_line(line)
+    assert line["correct"] is True and line["failed"] == 0
+    assert line["attempted"] > 0
+    # ``assign_p50_ms``: the third list the cell joined, on the plain line
+    assert set(line["metrics"]) == {"decisions_per_s", "assign_p50_ms",
+                                    "setup_s"}
+
+
+def test_the_added_cell_s_traced_line_holds_what_its_lists_say(tier1_runs):
+    """The per-layer metrics whose lists the cell joined and the two it
+    brought, and none of the others (``lock_wait_ms`` only where a writer
+    waited a millisecond or longer: at this size not in every run); cell
+    1's line in the same tree holds what it held and neither new one."""
+    code, line = tier1_runs[f"{one_more.CELL}:traced"]
+    assert code == 0
+    _check_line(line)
+    assert line["correct"] is True and line["failed"] == 0
+    brought = set(one_more.NEW_METRICS)
+    assert {"tick_ms"} | brought <= set(line["metrics"]) \
+        <= {"tick_ms", "lock_wait_ms"} | brought
+    for name, spec in one_more.NEW_METRICS.items():
+        assert line["metrics"][name]["unit"] == spec["unit"]
+        assert line["metrics"][name]["value"] > 0
+    code, old = tier1_runs["swarm-10k.deploys:traced"]
+    assert code == 0 and old["correct"] is True
+    assert not brought & set(old["metrics"])
+    assert {"tick_ms", "tick_tasks", "device_route_pct",
+            "host_route_groups_pct"} <= set(old["metrics"])
+    assert set(old["metrics"]) <= {m["name"] for m in BENCH["per_layer"]}
 
 
 @pytest.fixture(scope="module")
