@@ -148,9 +148,11 @@ def main(argv=None) -> int:
             else:
                 os.environ["JAX_PLATFORMS"] = platforms
         from benchmark.control import Rehearsal
+        # a cell's cut to a test's size is its own file, so a new cell
+        # is traced here without an edit
         rehearsal = Rehearsal(
-            None, rehearse_cells.SHRINK[args.workload] if args.cpu
-            else None, require_tpu=not args.cpu,
+            None, rehearse_cells.load_shrinks(ROOT)[args.workload]
+            if args.cpu else None, require_tpu=not args.cpu,
             cell=rehearse_cells.KEPT.get(args.workload))
     from benchmark import harness
     from swarmkit_tpu.obs import tracer

@@ -354,7 +354,7 @@ class TPUPlanner:
         self._tick_ts = None         # failure-window ts frozen per tick
         self.last_explanation = ""
         self.stats = {"groups_planned": 0, "groups_fallback": 0,
-                      "groups_small_to_host": 0,
+                      "groups_small_to_host": 0, "route_switches": 0,
                       "tasks_planned": 0, "plan_seconds": 0.0}
         # the break-even router's two sides (_route_costs): the measured
         # fixed launch overhead (dispatch + D2H round-trip on a minimal
@@ -372,6 +372,13 @@ class TPUPlanner:
         # begin_tick, updated incrementally by the apply phase, invalidated
         # by host-path fallbacks (which mutate NodeInfos behind our back)
         self._cache = None
+        # why the group routed last rides the host (None: it rides the
+        # device), for the scheduler's ``sched.host_route`` span; and
+        # whether a host-routed group of this tick dropped the columns,
+        # so that the device-routed group that rebuilds them counts one
+        # ``route_switches``
+        self.last_host_reason: Optional[str] = None
+        self._host_routed = False
         # streaming scheduler (ops/streaming.py): the node columns above
         # — and their device copies — stay RESIDENT across ticks and
         # refresh from the scheduler's dirty-set tracker in O(churn);
@@ -550,6 +557,7 @@ class TPUPlanner:
         # the same instant or a failure aging out mid-tick breaks the
         # placement parity contract under a wall clock
         self._tick_ts = now()
+        self._host_routed = False
         st = self._streaming_for(sched)
         if st is not None:
             self._cache = st.refresh(sched)
@@ -704,6 +712,11 @@ class TPUPlanner:
             # re-cache after an invalidation: the fresh columns already
             # reflect any host-path mutations
             self._cache = cols
+            if self._host_routed:
+                # a device-routed group (a launch of its own or a fused
+                # run) follows a host-routed one: it paid this rebuild
+                self._host_routed = False
+                self._count("route_switches")
         return cols
 
     _launch_overhead_shared: Optional[float] = None  # per-process link cost
@@ -814,15 +827,25 @@ class TPUPlanner:
             return False
         host, device, nodes = self._route_costs(sched, t, n_tasks, scan)
         if sp is not None:
-            sp.args.update(host_est_ms=round(host * 1e3, 3),
+            sp.args = dict(sp.args or {},
+                           host_est_ms=round(host * 1e3, 3),
                            device_est_ms=round(device * 1e3, 3),
                            nodes=nodes)
         return host < device
 
-    def _fallback(self) -> bool:
-        # the host path will mutate NodeInfos the cached columns mirror
-        self._count("groups_fallback")
+    def _to_host(self, reason: str) -> str:
+        """The group rides the host route, which mutates NodeInfos the
+        cached columns mirror: they are dropped, the next device-routed
+        group of the tick rebuilds them (``route_switches``), and the
+        scheduler's ``sched.host_route`` span is told why."""
         self._cache = None
+        self._host_routed = True
+        self.last_host_reason = reason
+        return reason
+
+    def _fallback(self) -> bool:
+        self._count("groups_fallback")
+        self._to_host("fallback")
         return False
 
     def _node_value(self, info: NodeInfo, key: str) -> str:
@@ -894,19 +917,17 @@ class TPUPlanner:
             # one densify on the host instead.  Mesh ShardedPlanFn
             # exposes .strategy and keeps non-spread groups on device.
             self._count("groups_strategy_host")
-            self._cache = None   # host path mutates NodeInfos
-            return "strategy_host", sinfo
+            return self._to_host("strategy_host"), sinfo
         if not self.breaker.allow_device():
             # degraded mode: a sick device routes every group to the
             # host oracle until the breaker's cooldown/probe admits it
             self._count("groups_breaker_to_host")
-            self._cache = None   # host path mutates NodeInfos
-            return "breaker", sinfo
+            return self._to_host("breaker"), sinfo
         if self._below_break_even(sched, t, k, sp=sp):
             self._count("groups_small_to_host")
             self.breaker.abort_probe()   # never reached the device
-            self._cache = None   # host path mutates NodeInfos
-            return "host_small", sinfo
+            return self._to_host("host_small"), sinfo
+        self.last_host_reason = None
         return None, sinfo
 
     def dispatch_group(self, sched, task_group: Dict[str, Task],
@@ -983,7 +1004,7 @@ class TPUPlanner:
             log.exception("device dispatch failed; group routed to host")
             self._count("groups_device_error")
             self.breaker.record_failure()
-            self._cache = None
+            self._to_host("device_error")
             return None
         if flat:
             strategy_mod.count_group(sinfo.name, "device")
@@ -1439,7 +1460,7 @@ class TPUPlanner:
             self._observe_plan(_time.perf_counter() - _plan_t0)
             self._count("groups_device_error")
             self.breaker.record_failure()
-            self._cache = None
+            self._to_host("device_error")
             return False
         handle.arrays = None
         # the d2h wait IS the device plane's busy window: the host is
@@ -1460,7 +1481,7 @@ class TPUPlanner:
             # letting the host place this group
             self._observe_plan(_time.perf_counter() - _plan_t0)
             self._count("groups_spill_to_host")
-            self._cache = None
+            self._to_host("spill")
             return False
         self.last_explanation = self._explain(fail_counts)
         self._observe_plan(_time.perf_counter() - _plan_t0)
@@ -1696,15 +1717,18 @@ class TPUPlanner:
         specs = []
         with tracer.span("plan.fused_probe", "plan") as sp:
             for group in glist[start:]:
+                # the span keeps the break-even's two sides as the last
+                # group judged had them: the one that ended the run
                 if self._below_break_even(
-                        sched, next(iter(group.values())), len(group)):
+                        sched, next(iter(group.values())), len(group),
+                        sp=sp):
                     break   # below device break-even: host path
                 spec = fusedbatch.probe_group(self, sched, group)
                 if spec is None:
                     break
                 specs.append(spec)
             if sp is not None:
-                sp.args = {"groups": len(specs)}
+                sp.args = dict(sp.args or {}, groups=len(specs))
         return specs
 
     def dispatch_fused_run(self, sched, specs):

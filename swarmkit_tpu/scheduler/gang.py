@@ -500,7 +500,7 @@ def admit_gangs(sched, units, decisions) -> int:
         for g in member_groups:
             work = dict(g)
             sched._schedule_group_host(work, scratch,
-                                       defer_leftover=False)
+                                       defer_leftover=False, reason="gang")
             if work:
                 leftover.extend(work.values())
         if leftover and ATOMIC_ENFORCED:

@@ -361,7 +361,8 @@ class Scheduler:
         self.stats = {"ticks": 0, "decisions": 0, "commit_seconds": 0.0,
                       "ticks_by_gap": 0, "ticks_by_max_latency": 0,
                       "events_handled": 0, "commits_seen": 0,
-                      "thread_cpu_s": 0.0, "loop_wall_s": 0.0}
+                      "thread_cpu_s": 0.0, "loop_wall_s": 0.0,
+                      "host_route_groups": 0, "host_route_s": 0.0}
 
         # scheduler-plane saturation probe (obs/planes.py): backlog
         # depth and oldest pending age, read lazily at window-roll time.
@@ -1749,7 +1750,32 @@ class Scheduler:
 
     def _schedule_group_host(self, task_group: Dict[str, Task],
                              decisions: Dict[str, SchedulingDecision],
-                             defer_leftover: bool = True) -> None:
+                             defer_leftover: bool = True,
+                             reason: Optional[str] = None) -> None:
+        """One group placed on the host, under one ``sched.host_route``
+        span that says why it rides there: ``reason`` (the gang scratch
+        placement's) or, from the tick's own walk, what the planner's
+        router said of the group (``host_small``: its break-even priced
+        the host cheaper, which is its choice and no fault; ``breaker``,
+        ``fallback``, ``strategy_host``, ``spill``, ``device_error``),
+        ``no_planner`` without one.  ``stats["host_route_groups"]`` and
+        ``stats["host_route_s"]`` count the same groups and their wall
+        time with the tracer off too: a window in which no group rode
+        the host reads 0 there, where the span reads nothing."""
+        if reason is None:
+            planner = self.batch_planner
+            reason = "no_planner" if planner is None else (
+                getattr(planner, "last_host_reason", None) or "fallback")
+        t0 = time.perf_counter()
+        with tracer.span("sched.host_route", "sched", tasks=len(task_group),
+                         nodes=len(self.node_set.nodes), reason=reason):
+            self._place_group_host(task_group, decisions, defer_leftover)
+        self.stats["host_route_groups"] += 1
+        self.stats["host_route_s"] += time.perf_counter() - t0
+
+    def _place_group_host(self, task_group: Dict[str, Task],
+                          decisions: Dict[str, SchedulingDecision],
+                          defer_leftover: bool) -> None:
         """The host oracle path: spread tree + sorted round-robin
         (reference: scheduler.go:694 scheduleTaskGroup).  Non-spread
         strategies route to their host oracle (scheduler/strategy.py) —

@@ -43,7 +43,7 @@ from ..models.objects import (
     Cluster, Config, Extension, Network, Node, Resource, Secret, Service,
     Task, Volume, STORE_OBJECT_TYPES,
 )
-from ..models.types import now
+from ..models.types import now, time_source_installed
 from ..obs.trace import tracer
 from ..utils.metrics import registry as _metrics
 from ..utils.pipeline import default_pipeline_depth
@@ -101,7 +101,11 @@ class _TimedLock:
         # reference: memory.go:84-112 lockTimer — contention visibility
         wait = self._acquired_at - t0
         self._wait_timer.observe(wait)
-        if wait >= LOCK_WAIT_SPAN_S and tracer.enabled:
+        # a wait is read off the machine's clock: under an installed time
+        # source (the sim) a writer preempted for a millisecond on a
+        # loaded host would put a span into a seed-pure trace
+        if wait >= LOCK_WAIT_SPAN_S and tracer.enabled \
+                and not time_source_installed():
             tracer.record_complete("store.lock_wait", "store", wait,
                                    holder=holder)
 
@@ -575,14 +579,21 @@ class MemoryStore:
     def _materialize_locked(self, table: _Table, tid: str) -> Optional[Any]:
         """Turn an overlay entry into a real stored Task (caller holds
         ``_lock``).  Idempotent: a concurrent reader may have materialized
-        the id between the overlay check and lock acquisition."""
-        entry = table.overlay.pop(tid, None)
+        the id between the overlay check and lock acquisition.  The
+        stored object is written BEFORE the entry leaves the overlay:
+        ``raw_get`` reads without the lock, and an id that is in neither
+        place for the length of a task copy reads as its pre-assignment
+        object (a dispatcher session that reads a block's task so never
+        ships it: the task stays ASSIGNED for good)."""
+        entry = table.overlay.get(tid)
         old = table.objects.get(tid)
         if entry is None or old is None:
+            table.overlay.pop(tid, None)
             return old
         node_id, version, ts, state, message = entry
         new = _materialize_task(old, node_id, version, ts, state, message)
         table.objects[tid] = new
+        del table.overlay[tid]
         return new
 
     def _materialize_all_locked(self, table: _Table) -> None:

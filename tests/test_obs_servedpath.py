@@ -71,7 +71,10 @@ def test_loop_spans_cover_the_scheduler_thread(deploy):
     assert {n for t, n, *_ in deploy["spans"] if t == "scheduler"} \
         >= set(LOOP)
     lo, hi = min(a for a, _ in rows), max(b for _, b in rows)
-    assert hi - lo > 0.2
+    # two deploys, each a debounce gap of 50 ms and a tick at the least:
+    # the stretch is 0.19-0.21 s in a worker whose planner is warm, and
+    # longer only where the traced part still compiles
+    assert hi - lo > 0.1
     assert _union(rows) >= 0.98 * (hi - lo)
 
 
@@ -144,7 +147,12 @@ def test_tick_self_time_with_a_device_group_and_a_host_group(traced):
     from swarmkit_tpu.ops import TPUPlanner
     store = MemoryStore()
     planner = TPUPlanner()
-    planner._launch_overhead = 0.005      # break-even at 80 tasks
+    # both of the router's probes pinned: 4 ms of launch against 50 us
+    # a task and 3 us a node, so 70 tasks on 40 nodes ride the host (a
+    # scan timed on a loaded runner reads three or four times higher,
+    # and over 12.5 us a node they would ride the device)
+    planner._launch_overhead = 0.005
+    planner.host_cost_per_node = 3e-6
     sched = Scheduler(store, batch_planner=planner)
     nodes = [make_ready_node(f"n{i:02d}", cpus=640, mem=2048 << 30)
              for i in range(40)]
@@ -323,6 +331,15 @@ def test_span_cpu_and_one_clock_on_a_plain_tracer():
 
 
 # --------------------------------------------------- (f) who holds the lock
+
+def test_no_lock_wait_span_under_an_installed_time_source(traced):
+    """A wait is read off the machine's clock: the sim's trace, a pure
+    function of its seed, must not hold one because a writer was kept
+    waiting a millisecond on a loaded host."""
+    with VirtualClock(1000.0):
+        servedpath_deploy.contend(MemoryStore(), hold_s=0.02)
+    assert not [s for s in traced.spans() if s.name == "store.lock_wait"]
+
 
 def test_lock_wait_names_the_holder(traced):
     store = MemoryStore()

@@ -71,6 +71,49 @@ def test_block_commit_lazy_materialization():
     assert len(table.overlay) == 0
 
 
+@pytest.mark.parametrize("reader", ["raw_get", "view_get"])
+def test_lock_free_read_during_materialization_is_never_stale(
+        monkeypatch, reader):
+    """``raw_get`` takes no lock.  While another thread materializes a
+    block-committed task (a copy of the task: long enough for the
+    interpreter to switch threads), the id must stay in the overlay until
+    the assigned object is stored, or the reader gets the pre-assignment
+    object: PENDING, no node.  A dispatcher session that reads a block's
+    task like that never ships it (seen once on the chip, PR 30: one task
+    of 1,975 on agent nodes still ASSIGNED a minute after the close)."""
+    import threading
+    from swarmkit_tpu.state import store as store_mod
+    store, svc, nodes, tasks = _mk_store_with_tasks(2)
+    store.commit_task_block(
+        tasks, [nodes[0].id, nodes[1].id], int(TaskState.ASSIGNED),
+        "assigned", _noop_missing, _no_conflict)
+    tid, seen, done = tasks[0].id, [], threading.Event()
+
+    def read():
+        if reader == "raw_get":
+            seen.append(store.raw_get(Task, tid))
+        else:
+            seen.append(store.view(lambda tx: tx.get(Task, tid)))
+        done.set()
+    inner = store_mod._materialize_task
+
+    def mid_copy(old, *args):
+        # the other thread reads while this one is inside the copy; a
+        # correct reader waits for the store's lock, so give up on it
+        # here and let it finish once the materialization has
+        threading.Thread(target=read, daemon=True).start()
+        done.wait(0.2)
+        return inner(old, *args)
+    monkeypatch.setattr(store_mod, "_materialize_task", mid_copy)
+    found = store.view(lambda tx: tx.find(Task, ByNode(nodes[0].id)))
+    assert [t.id for t in found] == [tid]
+    assert done.wait(5)
+    assert seen[0].node_id == nodes[0].id
+    assert seen[0].status.state == TaskState.ASSIGNED
+    assert seen[0] is store.raw_get(Task, tid)
+    assert tid not in store._tables["tasks"].overlay
+
+
 def test_block_commit_conflict_semantics():
     store, svc, nodes, tasks = _mk_store_with_tasks(4)
     nid = nodes[0].id
