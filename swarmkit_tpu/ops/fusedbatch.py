@@ -255,6 +255,51 @@ def flat_leaf(infos, nb: int, descriptor: str
     return leaf, max(len(values), 1)
 
 
+def tree_inputs(segs, level_ids):
+    """The kernel's view of a numbered spread tree: ``(leaf, L, hier)``
+    from the per-level segment columns and path-prefix -> id maps.
+    ``hier`` = ((seg, parent) per upper level, leaf_parent), every
+    parent array at its level's ``l_bucket`` width."""
+    depth = len(segs)
+    L = l_bucket(max(len(level_ids[-1]), 1))
+    upper = []
+    for di in range(depth - 1):
+        parent = np.zeros(l_bucket(max(len(level_ids[di]), 1)), np.int32)
+        if di > 0:
+            for path, cid in level_ids[di].items():
+                parent[cid] = level_ids[di - 1][path[:di]]
+        upper.append((segs[di], parent))
+    leaf_parent = np.zeros(L, np.int32)
+    for path, cid in level_ids[-1].items():
+        leaf_parent[cid] = level_ids[-2][path[:depth - 1]]
+    return segs[-1], L, (tuple(upper), leaf_parent)
+
+
+def spread_path(info, descriptors) -> tuple:
+    """A node's branch path down a multi-level spread tree: its value
+    under each descriptor, "" where it has none."""
+    from ..scheduler.nodeset import _pref_value
+    return tuple(_pref_value(info, d) or "" for d in descriptors)
+
+
+def spread_tree(infos, nb: int, descriptors):
+    """Multi-level spread tree (two or more preferences): each level's
+    segment id identifies the node's branch path prefix, numbered in
+    first-appearance order in row order (a tie-break the kernel reads).
+    Returns ``tree_inputs``' (leaf [nb], L, hier)."""
+    paths = [spread_path(info, descriptors) for info in infos]
+    level_ids: List[Dict[tuple, int]] = []
+    segs: List[np.ndarray] = []
+    for di in range(len(descriptors)):
+        ids: Dict[tuple, int] = {}
+        seg = np.zeros(nb, np.int32)
+        for i, path in enumerate(paths):
+            seg[i] = ids.setdefault(path[:di + 1], len(ids))
+        level_ids.append(ids)
+        segs.append(seg)
+    return tree_inputs(segs, level_ids)
+
+
 # ----------------------------------------------------------- fusability
 
 class GroupSpec:

@@ -355,6 +355,8 @@ class TPUPlanner:
         self.last_explanation = ""
         self.stats = {"groups_planned": 0, "groups_fallback": 0,
                       "groups_small_to_host": 0, "route_switches": 0,
+                      "tree_cols_hits": 0, "tree_cols_builds": 0,
+                      "tree_cols_invalidations": 0,
                       "tasks_planned": 0, "plan_seconds": 0.0}
         # the break-even router's two sides (_route_costs): the measured
         # fixed launch overhead (dispatch + D2H round-trip on a minimal
@@ -577,7 +579,8 @@ class TPUPlanner:
             or getattr(self._fused_fn, "mesh", None)
         if self._streaming is None:
             from .streaming import ResidentState
-            self._streaming = ResidentState(self._node_value, mesh=mesh)
+            self._streaming = ResidentState(self._node_value, mesh=mesh,
+                                            count=self._count)
         else:
             # mesh teardown / shard-count change between ticks resyncs
             # the device tier (set_mesh is a no-op on identity)
@@ -1026,8 +1029,9 @@ class TPUPlanner:
             return (infos, 0, nb, valid, cpu, mem, total, None, None, 1,
                     (), 0, 0, [], False)
         # resident fast paths (ops/streaming.py): per-service counts,
-        # failure rows, platform hashes, constraint hash columns and
-        # flat spread leaves come from row-wise-maintained caches —
+        # failure rows, platform hashes, constraint hash columns, flat
+        # spread leaves and the level columns of a multi-level spread
+        # tree come from row-wise-maintained caches —
         # O(touched rows) instead of an O(cluster) Python loop per
         # group.  Values are byte-identical to the loops below by
         # construction (same per-row formulas); the loops remain as the
@@ -1174,37 +1178,14 @@ class TPUPlanner:
                                                       descriptor)
             L = _l_bucket(n_values)
         elif prefs:
-            from ..scheduler.nodeset import _pref_value
-            descriptors = [p.spread.spread_descriptor for p in prefs]
-            depth = len(descriptors)
-            paths = []
-            for info in infos:
-                paths.append(tuple(_pref_value(info, d) or ""
-                                   for d in descriptors))
-            level_ids: List[Dict[tuple, int]] = []
-            seg_arrays: List[np.ndarray] = []
-            for di in range(depth):
-                ids: Dict[tuple, int] = {}
-                seg = np.zeros(nb, np.int32)
-                for i, path in enumerate(paths):
-                    seg[i] = ids.setdefault(path[:di + 1], len(ids))
-                level_ids.append(ids)
-                seg_arrays.append(seg)
-            leaf = seg_arrays[-1]
-            L = _l_bucket(max(len(level_ids[-1]), 1))
-            if depth > 1:
-                upper = []
-                for di in range(depth - 1):
-                    L_d = _l_bucket(max(len(level_ids[di]), 1))
-                    parent = np.zeros(L_d, np.int32)
-                    if di > 0:
-                        for path, cid in level_ids[di].items():
-                            parent[cid] = level_ids[di - 1][path[:di]]
-                    upper.append((seg_arrays[di], parent))
-                leaf_parent = np.zeros(L, np.int32)
-                for path, cid in level_ids[-1].items():
-                    leaf_parent[cid] = level_ids[-2][path[:depth - 1]]
-                hier = (tuple(upper), leaf_parent)
+            # two or more levels: the resident twin of the tree (its
+            # level columns kept row-wise, as the flat leaf's) or the walk
+            descriptors = tuple(p.spread.spread_descriptor for p in prefs)
+            if st is not None:
+                leaf, L, hier = st.spread_tree(sched, descriptors)
+            else:
+                leaf, L, hier = fusedbatch.spread_tree(infos, nb,
+                                                       descriptors)
 
         nodes_in = NodeInputs(
             valid=valid, ready=ready, res_ok=res_ok, res_cap=res_cap,

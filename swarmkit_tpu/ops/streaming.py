@@ -4,9 +4,9 @@
 SoA columns every tick — O(cluster) Python work per tick, even when the
 tick's churn touched three nodes.  ``ResidentState`` keeps those columns
 (and the per-group column *precursors*: per-service task counts, node
-platform hashes, constraint hash columns, spread leaves, failure rows)
-alive across ticks and refreshes only the rows the scheduler's
-``DeltaTracker`` marked dirty — the hardware-task-scheduler move of
+platform hashes, constraint hash columns, spread leaves, the level
+columns of multi-level spread trees, failure rows) alive across ticks
+and refreshes only the rows the scheduler's ``DeltaTracker`` marked dirty — the hardware-task-scheduler move of
 amortizing decision cost across a persistent structure (PAPERS.md: HTS
 1907.00271, DaphneSched 2308.01607).
 
@@ -130,13 +130,43 @@ class _ConColumn:
         self.none_count = 0
 
 
+class _TreeColumns:
+    """One cached multi-level spread tree, as the per-group walk
+    (``fusedbatch.spread_tree``) numbers it: per level the segment-id
+    column and the path-prefix -> id map, each row's path, and the
+    kernel inputs derived from the numbering (``fusedbatch.tree_inputs``:
+    parent arrays at their bucket widths, ``leaf_parent``, ``L``),
+    derived again once an appended row opened a new branch."""
+
+    __slots__ = ("segs", "level_ids", "paths", "inputs")
+
+    def __init__(self, depth: int, nb: int):
+        self.segs = [np.zeros(nb, np.int32) for _ in range(depth)]
+        self.level_ids: List[Dict[tuple, int]] = [
+            {} for _ in range(depth)]
+        self.paths: List[tuple] = []
+        self.inputs = None
+
+    def append(self, path: tuple) -> None:
+        i = len(self.paths)
+        self.paths.append(path)
+        for di, ids in enumerate(self.level_ids):
+            known = len(ids)
+            self.segs[di][i] = ids.setdefault(path[:di + 1], known)
+            if len(ids) != known:
+                self.inputs = None
+
+
 class ResidentState:
     """Persistent densified node state, refreshed O(churn) per tick."""
 
     def __init__(self, node_value: Callable, device: bool = True,
-                 mesh=None):
+                 mesh=None, count: Optional[Callable] = None):
         #: planner._node_value — constraint-key lookup per NodeInfo
         self._node_value = node_value
+        #: planner._count — the resident tier's events that the planner
+        #: accounts for (``tree_cols_*``) go through its one counter sink
+        self._count = count or (lambda key, delta=1: None)
         #: planner mesh (parallel/sharded.py) — when set and the node
         #: bucket divides evenly over it, the device tier lives as
         #: node-axis-sharded arrays with per-shard donated scatters
@@ -162,6 +192,7 @@ class ResidentState:
         self.con_cols: Dict[str, _ConColumn] = {}
         self.leaf_cols: Dict[str, Tuple[np.ndarray, Dict[str, int],
                                         List[str]]] = {}
+        self.tree_cols: Dict[Tuple[str, ...], _TreeColumns] = {}
         self.epoch = _UNSET
         self._tracker = None
         # device tier
@@ -378,6 +409,8 @@ class ResidentState:
             self._recompute_con_row(key, i, info)
         for desc_key in list(self.leaf_cols):
             self._recompute_leaf_row(desc_key, i, info, append)
+        for descriptors in list(self.tree_cols):
+            self._recompute_tree_row(descriptors, i, info, append)
 
     def _recompute_platform_row(self, i: int, info) -> None:
         desc = info.node.description
@@ -426,6 +459,19 @@ class ResidentState:
         # it rebuilds lazily, exactly as a full rebuild would number it
         del self.leaf_cols[desc_key]
 
+    def _recompute_tree_row(self, descriptors: Tuple[str, ...], i: int,
+                            info, append: bool) -> None:
+        entry = self.tree_cols[descriptors]
+        path = fusedbatch.spread_path(info, descriptors)
+        if append:
+            entry.append(path)
+        elif entry.paths[i] != path:
+            # as a flat leaf's value change: ids at every level are
+            # first-appearance ordered in row order, so a moved row can
+            # renumber others — drop the tree, it rebuilds lazily
+            del self.tree_cols[descriptors]
+            self._count("tree_cols_invalidations")
+
     # ------------------------------------------------------- full rebuild
 
     def _rebuild(self, sched, reason: str, count: Optional[str] = None
@@ -464,6 +510,7 @@ class ResidentState:
         self.svc_cols = {}
         self.con_cols = {}
         self.leaf_cols = {}
+        self.tree_cols = {}
         self._pending_dev_rows = {}
         for i, info in enumerate(infos):
             self._recompute_row(i, info)
@@ -567,6 +614,29 @@ class ResidentState:
             self.leaf_cols[descriptor] = entry
         leaf, ids, _values = entry
         return leaf, max(len(ids), 1)
+
+    def spread_tree(self, sched, descriptors: Tuple[str, ...]):
+        """Streaming twin of ``fusedbatch.spread_tree`` for two or more
+        spread preferences: ``(leaf, L, hier)`` from the resident level
+        columns (read-only to callers).  An appended row extends each
+        level; a row whose path changed dropped the tree
+        (``_recompute_tree_row``), and it is walked again here."""
+        self.absorb(sched)
+        entry = self.tree_cols.get(descriptors)
+        if entry is None:
+            if len(self.tree_cols) >= LEAF_CACHE_CAP:
+                self.tree_cols.pop(next(iter(self.tree_cols)))
+            entry = _TreeColumns(len(descriptors), self.nb)
+            for info in self.infos:
+                entry.append(fusedbatch.spread_path(info, descriptors))
+            self.tree_cols[descriptors] = entry
+            self._count("tree_cols_builds")
+        else:
+            self._count("tree_cols_hits")
+        if entry.inputs is None:
+            entry.inputs = fusedbatch.tree_inputs(entry.segs,
+                                                  entry.level_ids)
+        return entry.inputs
 
     # --------------------------------------------------------- device tier
 

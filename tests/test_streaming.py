@@ -307,6 +307,209 @@ def test_resident_columns_match_full_rebuild(frozen_clock):
     np.testing.assert_array_equal(rarch, arch_h)
 
 
+# ------------------------------------------- resident spread-tree twin
+
+_TREE_LABELS = ("zone", "rack", "shelf")
+
+
+def _tree_node(i, rack):
+    """Node ``i`` in rack ``rack``; the rack decides the zone, every
+    rack has the one shelf: 15 racks give 15 ids at levels 1 and 2."""
+    node = _mk_node(i)
+    node.spec.annotations.labels = {
+        "zone": f"z{rack % 2}", "rack": f"r{rack:02d}", "shelf": "s0"}
+    return node
+
+
+def _tree_spec(depth):
+    return TaskSpec(resources=_RES, placement=Placement(preferences=[
+        PlacementPreference(spread=SpreadOver(
+            spread_descriptor=f"node.labels.{label}"))
+        for label in _TREE_LABELS[:depth]]))
+
+
+def _tree_sched(depth, n_nodes=30, services=(("tree", 7),)):
+    """(store, scheduler on the resident tier, event feed) over
+    ``n_nodes`` nodes dealt two to a rack, with ``services`` of the
+    ``depth``-level spread shape pending."""
+    store = MemoryStore()
+
+    def fill(tx):
+        for i in range(n_nodes):
+            tx.create(_tree_node(i, i % 15))
+        for sid, k in services:
+            svc, tasks = _mk_service(sid, k, _tree_spec(depth))
+            tx.create(svc)
+            for t in tasks:
+                tx.create(t)
+    store.update(fill)
+    planner = TPUPlanner()
+    planner.enable_small_group_routing = False
+    sched = Scheduler(store, batch_planner=planner, pipeline_depth=1)
+    _, sub = store.view_and_watch(
+        lambda tx: sched._setup_tasks_list(tx), accepts_blocks=True)
+    return store, sched, sub
+
+
+def _move_to_rack(store, node_id, rack):
+    def move(tx):
+        node = tx.get(Node, node_id).copy()
+        node.spec.annotations.labels = dict(
+            node.spec.annotations.labels,
+            rack=f"r{rack:02d}", zone=f"z{rack % 2}")
+        tx.update(node)
+    store.update(move)
+
+
+def _tree_inputs_of(planner, sched, depth):
+    """(leaf, L, hier) as ``_build_device_inputs`` hands them to the
+    kernel for a group of the ``depth``-level shape."""
+    t = Task(id="probe", service_id="tree", spec=_tree_spec(depth))
+    built = planner._build_device_inputs(sched, t, 1)
+    return built[7].leaf, built[9], built[10]
+
+
+def _assert_same_tree(got, want, depth):
+    """Every seg array, parent array, leaf_parent and width equal,
+    dtypes too: the kernel's inputs byte for byte."""
+    (leaf_g, L_g, (upper_g, lp_g)) = got
+    (leaf_w, L_w, (upper_w, lp_w)) = want
+    assert L_g == L_w and len(upper_g) == len(upper_w) == depth - 1
+    pairs = [(leaf_g, leaf_w), (lp_g, lp_w)]
+    for (seg_g, par_g), (seg_w, par_w) in zip(upper_g, upper_w):
+        pairs += [(seg_g, seg_w), (par_g, par_w)]
+    for g, w in pairs:
+        assert g.dtype == w.dtype == np.int32
+        np.testing.assert_array_equal(g, w)     # shape (each L_d) too
+    assert lp_g.shape == (L_g,)
+
+
+@pytest.mark.parametrize("depth", [2, 3])
+def test_resident_tree_columns_match_the_walk(frozen_clock, depth):
+    """The resident twin of a multi-level spread tree against the
+    per-group walk (a planner off the resident tier, on the same
+    mirror), through every way the columns are kept."""
+    store, sched, sub = _tree_sched(depth)
+    planner = sched.batch_planner
+    walk = TPUPlanner()
+    walk.streaming_enabled = False
+    stats = planner.stats
+
+    def same(builds, hits, invalidations):
+        _pump(sched, sub)
+        got = _tree_inputs_of(planner, sched, depth)
+        assert walk._resident_for(walk._densify(sched, None)) is None
+        _assert_same_tree(got, _tree_inputs_of(walk, sched, depth), depth)
+        assert (stats["tree_cols_builds"], stats["tree_cols_hits"],
+                stats["tree_cols_invalidations"]) \
+            == (builds, hits, invalidations), stats
+        return got
+
+    # (a) a cold build
+    assert same(1, 0, 0)[1] == 16
+    assert planner._resident_for(planner._densify(sched, None)) is not None
+    # (b) nodes appended: into a rack that is there, opening a 16th
+    # rack, opening a 17th: every level with 17 ids crosses its bucket's
+    # edge and the parent arrays come at the new width, with no walk
+    store.update(lambda tx: [tx.create(_tree_node(30, 4)),
+                             tx.create(_tree_node(31, 15))])
+    leaf, L, (upper, leaf_parent) = same(1, 1, 0)
+    # a 16th leaf: under zone z1 (id 1), or under the 16th rack
+    assert L == 16 and leaf[31] == 15
+    assert leaf_parent[15] == (1 if depth == 2 else 15)
+    store.update(lambda tx: tx.create(_tree_node(32, 16)))
+    leaf, L, (upper, leaf_parent) = same(1, 2, 0)
+    assert L == 256 and leaf[32] == 16
+    assert [len(parent) for _seg, parent in upper] == [16, 256][:depth - 1]
+    assert planner._streaming.stats["full"] == 1      # appended, not rebuilt
+
+    # (c) a node changes rack: other rows' ids may move, the entry goes
+    # and the next group walks again
+    _move_to_rack(store, "n0000", 9)
+    leaf, _L, _hier = same(2, 2, 1)
+    assert leaf[0] == 0 and leaf[9] == 0 and leaf[1] == 1   # r00 went
+    # (d) a node removed: the whole resident state is rebuilt, the tree
+    # with it (no invalidation: nothing was held)
+    store.update(lambda tx: tx.delete(Node, "n0005"))
+    same(3, 2, 1)
+    assert planner._streaming.stats["full"] == 2
+    # (e) dirty rows whose labels did not change: a node drained, a
+    # reservation taken behind a mark; no walk
+    def drain(tx):
+        node = tx.get(Node, "n0002").copy()
+        node.spec.availability = NodeAvailability.DRAIN
+        tx.update(node)
+    store.update(drain)
+    sched.node_set.nodes["n0007"].available_resources.nano_cpus -= 5
+    sched.delta.mark("n0007")
+    same(3, 3, 1)
+    same(3, 4, 1)
+
+
+def test_resident_tree_invalidation_is_what_keeps_it_true(frozen_clock,
+                                                          monkeypatch):
+    """The differential above fails on a tree that a label change does
+    not drop: with the invalidation taken out the resident columns keep
+    the old numbering and differ from the walk's."""
+    from swarmkit_tpu.ops import streaming
+    store, sched, sub = _tree_sched(2)
+    planner = sched.batch_planner
+    walk = TPUPlanner()
+    walk.streaming_enabled = False
+    _tree_inputs_of(planner, sched, 2)
+    monkeypatch.setattr(streaming.ResidentState, "_recompute_tree_row",
+                        lambda self, descriptors, i, info, append: None)
+    _move_to_rack(store, "n0000", 9)
+    _pump(sched, sub)
+    with pytest.raises(AssertionError):
+        _assert_same_tree(_tree_inputs_of(planner, sched, 2),
+                          _tree_inputs_of(walk, sched, 2), 2)
+
+
+def test_resident_tree_cache_is_bounded(frozen_clock):
+    """``LEAF_CACHE_CAP``'s discipline: the oldest-built tree goes."""
+    from swarmkit_tpu.ops import streaming
+    _store, sched, _sub = _tree_sched(2)
+    planner = sched.batch_planner
+    planner._densify(sched, None)
+    st = planner._streaming
+    keys = [(f"node.labels.a{i}", "node.labels.rack")
+            for i in range(streaming.LEAF_CACHE_CAP + 1)]
+    for key in keys:
+        st.spread_tree(sched, key)
+    assert list(st.tree_cols) == keys[1:]
+    assert planner.stats["tree_cols_builds"] == len(keys)
+
+
+def test_two_level_group_places_the_same_on_the_resident_tier_and_the_walk(
+        frozen_clock):
+    """``schedule_group`` on the resident tier and on a tracker-less
+    scheduler (the walk): two two-level groups one after the other, the
+    second over the first's dirty rows, the same tasks on every node."""
+    def counts(resident):
+        _store, sched, _sub = _tree_sched(
+            2, services=(("tree", 37), ("tree2", 11)))
+        planner = sched.batch_planner
+        if not resident:
+            sched.delta = None
+        per_node = {}
+        for group in list(sched.unassigned_groups.values()):
+            decisions, k = {}, len(group)
+            assert planner.schedule_group(sched, group, decisions)
+            assert len(decisions) == k
+            for d in decisions.values():
+                key = (d.new.service_id, d.new.node_id)
+                per_node[key] = per_node.get(key, 0) + 1
+        return per_node, planner
+    got, planner = counts(True)
+    want, walker = counts(False)
+    assert got == want and sum(got.values()) == 48
+    assert planner.stats["tree_cols_builds"] == 1
+    assert planner.stats["tree_cols_hits"] == 1
+    assert walker._streaming is None
+    assert walker.stats["tree_cols_builds"] == 0
+
+
 def test_epoch_change_forces_resync(frozen_clock):
     """A tick under a different leadership epoch must rebuild the
     resident state (successor-reign discipline) and count a resync."""

@@ -75,11 +75,11 @@ SWITCHES = sum(1 for (_, a), (_, b) in zip(TICK, TICK[1:])
                if a < BREAK_EVEN <= b)
 
 
-def _services():
+def _services(tick):
     """The tick's services and tasks under ids that sort in the tick's
     order, whatever order the store lists them in."""
     out = []
-    for i, (shape, k) in enumerate(TICK):
+    for i, (shape, k) in enumerate(tick):
         spec = cluster.service_spec(f"s{i:02d}-{shape}",
                                     CONFIG["shapes"][shape], k)
         svc = Service(id=f"svc{i:02d}", spec=spec,
@@ -95,15 +95,21 @@ def _services():
 
 @functools.lru_cache(maxsize=None)
 def outcome(seed: int, mode: str) -> dict:
-    """One tick of ``TICK`` on the cluster ``seed`` deals, routed as
-    ``mode`` says; what it placed, counted and traced (driven once a
-    seed and mode, read by every test)."""
+    """One tick of ``TICK``, driven once a seed and mode and read by
+    every test."""
+    return drive(seed, mode, TICK)
+
+
+def drive(seed: int, mode: str, tick, before=None) -> dict:
+    """One tick of ``tick`` on the cluster ``seed`` deals, routed as
+    ``mode`` says; what it placed, counted and traced.  ``before`` is
+    handed the planner ahead of the tick."""
     # no dispatcher runs here, so no node is agent-served: the comparison
     # asks RUNNING of no task
     nodes = [dict(n, agent=False)
              for n in cluster.plain_nodes(CONFIG["cluster"], seed)]
     store = MemoryStore()
-    made = _services()
+    made = _services(tick)
 
     def fill(tx):
         for n in cluster.store_nodes(nodes):
@@ -123,6 +129,8 @@ def outcome(seed: int, mode: str) -> dict:
         planner.enable_small_group_routing = False
     sched = Scheduler(store, batch_planner=planner)
     store.view(sched._setup_tasks_list)
+    if before is not None:
+        before(planner)
     walked = [len(g) for g in sched.unassigned_groups.values()]
     tracer.reset()
     tracer.enable()
@@ -276,3 +284,46 @@ def test_flat_shapes_land_on_the_same_nodes_whatever_the_route(seed):
         == sum(k for shape, k in TICK if shape in FLAT)
     assert mixed == host
     assert mixed == device
+
+
+@pytest.mark.parametrize("between", ["topology", "spread"])
+def test_a_host_routed_group_between_two_topology_groups_keeps_the_tree_true(
+        between):
+    """Inside one tick a host-routed group drops the cached columns
+    (``_to_host``) and mutates NodeInfos behind marks; the topology
+    group after it is handed the resident tree, which has absorbed
+    those rows and was not walked again, and its every array is what
+    the walk over the same mirror gives there and then."""
+    from swarmkit_tpu.ops import fusedbatch
+    seen = []
+
+    def spy(planner):
+        build = planner._build_device_inputs
+
+        def spied(sched, t, k, flat=False):
+            built = build(sched, t, k, flat=flat)
+            prefs = [p.spread.spread_descriptor
+                     for p in t.spec.placement.preferences]
+            if len(prefs) > 1:
+                assert planner._resident_for(planner._cache) is not None
+                seen.append(((built[7].leaf, built[9], built[10]),
+                             fusedbatch.spread_tree(built[0], built[2],
+                                                    prefs)))
+            return built
+        planner._build_device_inputs = spied
+    run = drive(SEEDS[0], "mixed",
+                [("topology", 60), (between, 6), ("topology", 33)], spy)
+    assert run["decided"] == 99
+    stats = run["stats"]
+    assert stats["groups_small_to_host"] == 1 == stats["route_switches"]
+    assert stats["groups_planned"] == 2 == len(seen)
+    assert (stats["tree_cols_builds"], stats["tree_cols_hits"],
+            stats["tree_cols_invalidations"]) == (1, 1, 0)
+    for (leaf, L, (upper, leaf_parent)), \
+            (w_leaf, w_L, (w_upper, w_leaf_parent)) in seen:
+        assert L == w_L == 256 and len(upper) == len(w_upper) == 1
+        for got, want in ((leaf, w_leaf), (leaf_parent, w_leaf_parent),
+                          (upper[0][0], w_upper[0][0]),
+                          (upper[0][1], w_upper[0][1])):
+            assert got.dtype == want.dtype
+            assert got.tolist() == want.tolist()
