@@ -39,24 +39,6 @@ timeout -k 10 600 env JAX_PLATFORMS=cpu \
     || { cat /tmp/_chaos_fast.json; exit 1; }
 
 echo
-echo "== obs critical path (fast bench + trace_report --critical-path) =="
-# tiny end-to-end bench (headline + e2e only) so the artifact embeds a
-# journey attribution, then the critical-path report must parse it:
-# non-empty cohort, per-plane rows, fractions summing to ~100%.  A
-# malformed or empty attribution fails CI — the observability plane
-# regressed even if every test still passes.
-timeout -k 10 600 env JAX_PLATFORMS=cpu \
-    BENCH_NODES=64 BENCH_TASKS=4096 BENCH_TRIALS=1 \
-    BENCH_SKIP_HOST=1 BENCH_SKIP_CONFIGS=1 BENCH_SKIP_OBS=1 \
-    BENCH_E2E_REPLICAS=64 BENCH_HISTORY= \
-    BENCH_TRACE_OUT=/tmp/_ci_bench_trace.json \
-    BENCH_FLIGHTREC_OUT=/tmp/_ci_bench_flightrec.json \
-    python bench.py > /tmp/_ci_bench.json 2>/tmp/_ci_bench.err \
-    || { cat /tmp/_ci_bench.err; exit 1; }
-python scripts/trace_report.py --critical-path /tmp/_ci_bench.json \
-    || exit 1
-
-echo
 echo "== tier-1 tests (ROADMAP.md) =="
 rm -f /tmp/_t1.log
 timeout -k 10 870 env JAX_PLATFORMS=cpu python -m pytest tests/ -q \

@@ -67,7 +67,7 @@ class ModuleInfo:
         parts = raw[:-1] if raw and raw[-1] == "__init__" else raw
         self.module = ".".join(parts)
         # first package segment under swarmkit_tpu/ ("" for top-level
-        # modules like swarmd.py, and for scripts/ / bench.py); computed
+        # modules like swarmd.py, and for scripts/); computed
         # from the PATH so a package's own __init__ belongs to it
         if raw[0] == "swarmkit_tpu" and len(raw) > 2:
             self.package = raw[1]

@@ -22,8 +22,8 @@ bypasses that silently break seed-reproducibility:
 
 Whitelisted modules are the seams themselves, the virtual clock, the
 real-subprocess executor (wall-clock health timers are its point),
-crypto (``security/``), and host-side tooling (``scripts/``,
-``bench.py``) that measures real time on purpose.
+crypto (``security/``), and host-side tooling (``scripts/``) that
+measures real time on purpose.
 """
 
 from __future__ import annotations
@@ -43,7 +43,6 @@ ALLOWED_PATHS = (
     "swarmkit_tpu/security/",          # cert validity / key material are
                                        # real-world crypto by definition
     "scripts/",
-    "bench.py",
 )
 
 _BANNED_CALLS = {

@@ -32,8 +32,9 @@ The device-telemetry ledger (obs/devicetelemetry.py, ISSUE 18) adds the
 UNACCOUNTED TRANSFER shape in the host drivers: every H2D staged with
 ``jax.device_put`` and every ``.block_until_ready()`` fetch sync in a
 host function of these modules must flow through the device ledger — a
-transfer the ledger never sees is a byte stream the bench regression
-gates cannot gate on.  A host function touching those seams passes only
+transfer the ledger never sees is a byte stream no reading of
+``/debug/device`` or chip_smoke.py can account for.  A host function
+touching those seams passes only
 when its body also carries an accounting call (``note_h2d`` /
 ``note_d2h`` / ``note_bytes_avoided``, or any dotted call through
 ``devicetelemetry``).

@@ -15,8 +15,7 @@ path, the control plane, and the simulator separately testable):
   production code only through the injected seams — it never imports
   the real I/O edge (``net``, ``security``);
 * nothing in production imports ``sim`` — the simulator depends on the
-  tree, never the reverse (``scripts/`` and ``bench.py`` are drivers
-  and exempt).
+  tree, never the reverse (``scripts/`` holds drivers and is exempt).
 
 The matrix is enforced on every ``import``/``from-import`` (including
 function-local ones), with relative imports resolved against the
@@ -51,7 +50,7 @@ FORBIDDEN: Dict[str, Set[str]] = {
 }
 
 #: only the simulator (and external drivers) may import sim
-SIM_IMPORTERS_EXEMPT = ("scripts/", "bench.py", "tests/")
+SIM_IMPORTERS_EXEMPT = ("scripts/", "tests/")
 
 
 def _resolve_relative(mod: ModuleInfo, node: ast.ImportFrom) -> Optional[str]:

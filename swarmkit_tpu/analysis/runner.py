@@ -21,7 +21,7 @@ from .core import ALL_RULES, Checker, Finding, ModuleInfo, checker_names, \
     make_checkers
 
 #: what `scripts/swarmlint.py` (and the tier-1 test) lints by default
-DEFAULT_ROOTS = ("swarmkit_tpu", "scripts", "bench.py")
+DEFAULT_ROOTS = ("swarmkit_tpu", "scripts")
 DEFAULT_BASELINE = "swarmlint_baseline.json"
 
 _SKIP_DIRS = {"__pycache__", ".git", "native", "build"}
@@ -58,7 +58,7 @@ def iter_source_files(repo_root: str,
                 f"swarmlint root {root!r} does not exist under "
                 f"{repo_root}")
         if os.path.isfile(abs_root):
-            # normalize ('./bench.py', absolute paths) to the canonical
+            # normalize ('./scripts/x.py', absolute paths) to the canonical
             # repo-relative form — rule whitelists and baseline entries
             # match on it
             out.append(os.path.relpath(abs_root, repo_root)

@@ -17,8 +17,9 @@ follower apply / watch fan-out) has its own escape hatch on top:
 same breaker discipline as the device planner.  ``get_commit()`` is the
 accessor those call sites use; when the native module is unavailable
 while the commit plane is *not* explicitly disabled, each call counts a
-``swarm_native_commit_fallbacks`` tick so a bench window can prove the
-native path actually ran (scripts/bench_compare.py gates on it).
+``swarm_native_commit_fallbacks`` tick so a measured window can prove
+the native path actually ran (benchmark/retreat.py and chip_smoke.py
+count any growth as a retreat).
 """
 
 from __future__ import annotations
@@ -106,8 +107,8 @@ def get_commit():
     """The native module for the columnar commit plane (block decode,
     follower apply, watch fan-out), or None when disabled
     (``SWARM_NATIVE_COMMIT=0``) or unavailable.  An unavailable-but-
-    requested native plane counts a fallback tick per call — the bench
-    gate's evidence that a timed window really ran native."""
+    requested native plane counts a fallback tick per call — the
+    evidence that a measured window really ran native."""
     if not commit_enabled():
         return None
     mod = get()

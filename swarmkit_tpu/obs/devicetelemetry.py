@@ -20,8 +20,9 @@ vs transfers vs compute, kept Dapper-cheap):
   the streaming win is a number, not an inference.
 * **compile-cache ledger** — a per-process registry of every jit
   signature ever compiled (bucket, shapes hash, retro compile time,
-  hit/miss counts), serialized into bench artifacts and flight-recorder
-  dumps so "compiles 0 in the timed window" is auditable per-signature.
+  hit/miss counts), serialized into flight-recorder dumps and read by
+  the benchmark around its window, so "compiles 0 in the timed window"
+  is auditable per-signature.
 * **memory watermarks** — live-buffer byte estimates per resident tier
   (host mirror vs device copies), plus a donation-balance registry that
   cross-checks the swarmlint donation rule at *runtime*: buffers
@@ -245,8 +246,9 @@ class DeviceTelemetry:
                 row["hits"] += 1
 
     def compile_cache_snapshot(self) -> Dict[str, Dict[str, int]]:
-        """Sorted copy of the per-signature ledger (bench diffs the
-        before/after of the timed window against this)."""
+        """Sorted copy of the per-signature ledger (benchmark/harness.py
+        diffs the before/after of its window against this:
+        ``window_compiles``)."""
         with self._mu:
             return {b: dict(r) for b, r in sorted(self._cache.items())}
 
@@ -315,7 +317,7 @@ class DeviceTelemetry:
 
     def snapshot(self) -> Dict[str, object]:
         """One deterministic document: sorted keys, aggregate ints only
-        — the bench-artifact / flightrec-dump / ``/debug/device``
+        — the flightrec-dump / ``/debug/device`` / chip_smoke.py
         surface.  Renders on a fresh process (all tables empty)."""
         with self._mu:
             kernel = {f"{b}|{r}": dict(row) for (b, r), row
@@ -417,7 +419,8 @@ _state = DeviceTelemetry()
 
 
 def set_enabled(on: bool) -> None:
-    """Toggle the whole ledger (bench's obs-overhead off-half)."""
+    """Toggle the whole ledger (tests/test_devicetelemetry.py: a
+    disabled ledger records nothing)."""
     _state.enabled = bool(on)
 
 
@@ -496,7 +499,7 @@ def restore_state(state) -> None:
 
 
 def reset() -> None:
-    """Start fresh (tests, bench epoch, sim scenario entry).  The
+    """Start fresh (tests, sim scenario entry).  The
     ledger is REBOUND, not cleared in place, so a ``save_state``
     capture survives."""
     global _state

@@ -1,8 +1,8 @@
 """Black-box flight recorder: bounded ring buffers of recent control-
 plane activity, dumpable as one post-mortem JSON.
 
-Motivation (round-5 verdict): identical code swung 17x between bench
-artifacts and sim invariant failures reported a verdict with no
+Motivation (round-5 verdict): identical code swung 17x between
+benchmark runs and sim invariant failures reported a verdict with no
 surrounding state — the noise was *inferred*, never *observed*.  The
 recorder keeps the last-N of everything cheap to capture continuously:
 
@@ -24,9 +24,8 @@ byte for byte, which is what makes a post-mortem from a failing seed
 *evidence* rather than anecdote (asserted in tests/test_flightrec.py).
 
 Dump triggers: ``/debug/flightrec`` on the DebugServer (on demand),
-``sim.scenario.run_scenario`` (automatically on invariant violation or
-crashed-scenario exit; path + sha land in the report), and ``bench.py``
-(when a trial trips the variance guard).
+and ``sim.scenario.run_scenario`` (automatically on invariant violation
+or crashed-scenario exit; path + sha land in the report).
 """
 
 from __future__ import annotations

@@ -15,8 +15,8 @@ load-balancer/probe consumers need no JSON parsing.
 Checks with no data (a timer never observed, a counter never
 incremented) report ``pass`` with ``value: null`` — a fresh manager is
 healthy, not unknown-unhealthy.  Thresholds are constructor arguments;
-the defaults are sized for the production-shape bench (100k-task ticks
-well under a second of p99 budget).
+the defaults are sized for production-shape ticks (100k tasks well
+under a second of p99 budget).
 """
 
 from __future__ import annotations

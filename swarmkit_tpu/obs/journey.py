@@ -33,8 +33,7 @@ normalizes — "62% scheduler, 21% dispatcher, …".  The
 ``planned -> committed`` edge is zero-width today (both ride the same
 replicated stamp; the version token still records the commit) so the
 commit plane's share surfaces through the plane-occupancy windows
-(obs/planes.py) that ``scripts/trace_report.py --critical-path``
-prints alongside.
+(obs/planes.py) that ``/debug/planes`` serves alongside.
 """
 
 from __future__ import annotations
@@ -240,7 +239,7 @@ class JourneyLedger:
         # scheduler plane's edge gains a NESTED breakdown (dispatch vs
         # d2h vs compile from the device-telemetry ledger) — nested,
         # not a sibling plane row, so per-plane fracs still sum to ~1.0
-        # (the trace_report --critical-path invariant).
+        # (tests/test_planes_journeys.py holds the sum).
         sched_row = planes.get("scheduler")
         if sched_row is not None:
             from . import devicetelemetry as _devtel
@@ -304,6 +303,6 @@ class JourneyLedger:
              self.sample_rate, self.cap) = state
 
 
-# the process-wide ledger: the Manager, the sim runner, and bench all
-# tap the same instance (flightrec.journey_sink feeds it store events)
+# the process-wide ledger: the Manager and the sim runner tap the same
+# instance (flightrec.journey_sink feeds it store events)
 journeys = JourneyLedger()

@@ -9,7 +9,7 @@ starting→running.  Each observed edge feeds a labeled registry timer
     swarm_task_lifecycle{from="pending",to="assigned"}
 
 so ``/metrics`` exports per-edge p50/p90/p99, and ``summary()`` gives the
-same numbers programmatically (bench/tests).
+same numbers programmatically (tests).
 
 Latencies are computed from the *stamped* status timestamps (and
 ``meta.created_at`` for the creation edge), not from observation time —

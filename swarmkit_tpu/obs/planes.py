@@ -22,9 +22,8 @@ Busy time is accumulated at the call sites (``note_busy`` / the
 (``set_depth`` / ``set_oldest_age``) or pulled through a registered
 ``probe`` at roll time — the probe form keeps hot paths untouched for
 signals that are just an attribute read away (raft inbox qsize, apply
-lag).  ``roll_all()`` is driven by the sampler tick (production) and by
-the sim engine / bench explicitly, so gauge freshness follows the same
-cadence as every other sampled signal.
+lag).  ``roll_all()`` is driven by the sampler tick, so gauge freshness
+follows the same cadence as every other sampled signal.
 
 Time flows through ``models.types.now()`` — under the simulator's
 VirtualClock occupancy windows are a pure function of the seed.  All
@@ -199,7 +198,7 @@ def plane(name: str) -> PlaneStats:
 
 
 def roll_all() -> Dict[str, Dict[str, float]]:
-    """Roll every registered plane (sampler tick / bench window edge);
+    """Roll every registered plane (the sampler's tick);
     returns {plane: rolled snapshot} in sorted order."""
     with _lock:
         items = sorted(_planes.items())
@@ -207,8 +206,8 @@ def roll_all() -> Dict[str, Dict[str, float]]:
 
 
 def report_all() -> Dict[str, Dict[str, float]]:
-    """Deterministically ordered report for ``/debug/planes`` and the
-    bench artifact.  Safe on a fresh process: an empty taxonomy reports
+    """Deterministically ordered report for ``/debug/planes`` and
+    ``/debug/device``.  Safe on a fresh process: an empty taxonomy reports
     an empty dict, never raises."""
     with _lock:
         items = sorted(_planes.items())

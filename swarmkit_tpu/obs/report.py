@@ -1,9 +1,9 @@
 """Trace analysis: phase tables and schema validation.
 
-Consumed three ways: ``scripts/trace_report.py`` (CLI), ``bench.py``
-(embeds a per-config phase table in the BENCH artifact, derived from the
-same trace JSON it writes), and the tier-1 smoke test (schema-validates
-an emitted trace).
+Consumed three ways: ``scripts/trace_report.py`` (CLI),
+``scripts/servedpath_trace.py`` (the served path's traced window), and
+the tier-1 tests (``tests/test_obs.py``, ``tests/test_obs_servedpath.py``:
+they schema-validate an emitted trace and read its phase table).
 """
 
 from __future__ import annotations
@@ -29,16 +29,6 @@ LOOP_PHASES = ("sched.idle", "sched.debounce", "sched.events")
 def x_events(doc: Dict[str, Any]) -> List[Dict[str, Any]]:
     return [e for e in doc.get("traceEvents", ())
             if e.get("ph") == "X"]
-
-
-def config_windows(doc: Dict[str, Any]
-                   ) -> List[Tuple[str, Tuple[int, int]]]:
-    """(cfg label, (ts_lo, ts_hi)) per ``bench.config`` marker span —
-    the single definition both bench.py and scripts/trace_report.py use
-    to attribute phases, so artifact tables and CLI reports can never
-    disagree on the same trace file."""
-    return [(e["args"].get("cfg", "?"), (e["ts"], e["ts"] + e["dur"]))
-            for e in x_events(doc) if e["name"] == "bench.config"]
 
 
 def _merge(intervals: List[Tuple[int, int]]) -> List[Tuple[int, int]]:
@@ -84,8 +74,8 @@ def phase_table(doc: Dict[str, Any],
     """Summarize a Chrome trace into a per-phase table.
 
     ``window``: optional (ts_lo, ts_hi) in trace microseconds — restricts
-    the table to spans starting inside it (bench uses the enclosing
-    ``bench.config`` span to attribute phases per config).
+    the table to spans starting inside it
+    (``tests/test_obs_servedpath.py`` takes one span's own row that way).
     """
     phases: Dict[str, Dict[str, float]] = {}
     plan_iv: List[Tuple[int, int]] = []
@@ -235,78 +225,6 @@ def format_diff(diff: Dict[str, Any]) -> str:
     lines.append("")
     for key, (va, vb) in diff["summary"].items():
         lines.append(f"{key:<22}: {va:.6f} -> {vb:.6f}")
-    return "\n".join(lines)
-
-
-def device_table(art: Dict[str, Any]) -> Optional[Dict[str, Any]]:
-    """Join a bench artifact's device-telemetry ledger with the device
-    plane's occupancy window: kernel rows (bucket/route, dispatch vs
-    D2H vs compile time) against what the plane as a whole reported.
-    Returns None when the artifact carries no ``device_telemetry``
-    (pre-PR-18 artifact, or telemetry off) — the CLI exits non-zero.
-    Shared by ``scripts/trace_report.py --device`` and tests."""
-    tel = art.get("device_telemetry")
-    if not isinstance(tel, dict):
-        return None
-    plane = (art.get("planes") or {}).get("device") or {}
-    rows = []
-    for key, row in sorted((tel.get("kernel") or {}).items()):
-        bucket, _, route = key.partition("|")
-        rows.append({
-            "bucket": bucket, "route": route,
-            "dispatches": row.get("dispatches", 0),
-            "groups": row.get("groups", 0),
-            "task_rows": row.get("task_rows", 0),
-            "node_rows": row.get("node_rows", 0),
-            "dispatch_s": round(row.get("dispatch_ns", 0) / 1e9, 6),
-            "d2h_s": round(row.get("d2h_ns", 0) / 1e9, 6),
-            "compile_s": round(
-                row.get("retro_compile_ns", 0) / 1e9, 6),
-        })
-    return {
-        "device_plane": plane,
-        "kernel": rows,
-        "transfers": tel.get("transfers") or {},
-        "bytes_avoided": tel.get("bytes_avoided", 0),
-        "compile_cache": tel.get("compile_cache") or {},
-        "memory": tel.get("memory") or {},
-        "donation": tel.get("donation") or {},
-    }
-
-
-def format_device_table(table: Dict[str, Any]) -> str:
-    plane = table["device_plane"]
-    lines = [
-        f"device plane: occupancy={plane.get('occupancy', 0.0)} "
-        f"queue_depth={plane.get('queue_depth', 0.0)} "
-        f"oldest_age_s={plane.get('oldest_age_s', 0.0)}",
-        "",
-        f"{'bucket':<40} {'route':<10} {'disp':>6} "
-        f"{'dispatch_s':>11} {'d2h_s':>9} {'compile_s':>10}",
-    ]
-    for r in table["kernel"]:
-        lines.append(
-            f"{r['bucket']:<40} {r['route']:<10} {r['dispatches']:>6} "
-            f"{r['dispatch_s']:>11.6f} {r['d2h_s']:>9.6f} "
-            f"{r['compile_s']:>10.6f}")
-    lines.append("")
-    for direction in sorted(table["transfers"]):
-        for reason, row in sorted(table["transfers"][direction].items()):
-            lines.append(f"{direction} {reason:<16}: "
-                         f"{row['bytes']:>14} B  x{row['count']}")
-    lines.append(f"bytes avoided        : {table['bytes_avoided']:>14} B")
-    cache = table["compile_cache"]
-    misses = sum(r.get("misses", 0) for r in cache.values())
-    hits = sum(r.get("hits", 0) for r in cache.values())
-    lines.append(f"compile cache        : {len(cache)} signatures, "
-                 f"{misses} misses, {hits} hits")
-    don = table["donation"]
-    if don:
-        lines.append(
-            f"donation balance     : donated={don.get('donated', 0)} "
-            f"retired={don.get('retired', 0)} "
-            f"outstanding={don.get('outstanding', 0)} "
-            f"violations={don.get('violations', 0)}")
     return "\n".join(lines)
 
 

@@ -6,10 +6,11 @@ the whole group on device and return True, or return False to fall back to
 the host (oracle) path.
 
 Falls back for features the device path does not model yet (documented
-parity waivers): CSI volume mounts, node.ip constraints, named (non-
-discrete) generic resources in *node* inventories, and spread-preference
-trees deeper than 4 levels.  Multi-level spread (up to 4 levels) runs on
-device via the kernel's hierarchical stage-A water-fill.
+parity waivers, ``_supported``): CSI volume mounts, named (non-discrete)
+generic resources, and spread-preference trees deeper than 4 levels.
+Multi-level spread (up to 4 levels) runs on device via the kernel's
+hierarchical stage-A water-fill; node.ip constraints ride the hash/prefix
+columns (``constraint.ip_column_spec``).
 
 Small groups on small clusters route to the host path: a device launch
 costs a fixed round-trip (measured once per process, see
@@ -101,7 +102,7 @@ def _observe_compile(fn, bucket: str, cache_before: Optional[int],
     """Count an XLA cache miss when the jit cache grew across one call:
     a ``swarm_planner_compiles{bucket=...}`` counter tick, a compile
     timer observation, and a retroactive ``plan.compile`` span — the
-    explanation trail for ``shape_cost_x``/bench variance swings.
+    explanation trail for a slow tick in a trace.
 
     Doubles as THE compile-cache ledger feed: every dispatch lands in
     the per-signature hit/miss registry (obs/devicetelemetry.py), so
@@ -367,8 +368,8 @@ class TPUPlanner:
         self._launch_overhead = None
         self.host_cost_per_node = None
         self.host_cost_per_task = 50e-6
-        # set False to force every supported group onto the device (bench
-        # warm-ups, differential tests)
+        # set False to force every supported group onto the device
+        # (chip_smoke.py, differential tests)
         self.enable_small_group_routing = True
         # per-tick cache of group-independent node columns; built on
         # begin_tick, updated incrementally by the apply phase, invalidated
@@ -417,7 +418,7 @@ class TPUPlanner:
     # routing-counter keys -> the route label exported on
     # swarm_planner_groups{route=...}; every increment goes through
     # _count so the stats dict and the metrics registry can never
-    # disagree (bench reads the registry)
+    # disagree (benchmark/retreat.py reads the dict, /metrics the registry)
     _ROUTE = {"groups_planned": "device",
               "groups_fused": "fused",
               "groups_fallback": "fallback",
@@ -447,8 +448,10 @@ class TPUPlanner:
         earlier group leaves a near-zero d2h wait, which would read as
         "no overlap" exactly when overlap worked best.  The in-flight
         window is what the commit spans genuinely ran inside of —
-        obs/report.py counts it toward plan_hidden_frac.  Zero-duration
-        under a virtual clock, like plan.compile (seed-pure sim traces).
+        obs/report.py counts it toward plan_hidden_frac, which
+        scripts/trace_report.py prints under the phase table.
+        Zero-duration under a virtual clock, like plan.compile
+        (seed-pure sim traces).
         """
         from ..models.types import time_source_installed
         tracer.record_complete("plan.inflight", "plan",
@@ -598,7 +601,9 @@ class TPUPlanner:
         return None
 
     def streaming_snapshot(self):
-        """Bench/obs surface: the ``streaming_*`` artifact fields."""
+        """The resident tier's counters (``ResidentState.snapshot``), or
+        the all-off shape; benchmark/retreat.py and chip_smoke.py read
+        it to tell a run with the tier off from one with it on."""
         st = self._streaming
         if st is None or not self.streaming_enabled:
             return {"enabled": False, "dirty_frac": None, "resyncs": 0,

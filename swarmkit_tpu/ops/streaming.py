@@ -834,11 +834,13 @@ class ResidentState:
         _devtel.check_live([id(a) for a in self.dev])
         return self.dev
 
-    # --------------------------------------------------------------- bench
+    # ------------------------------------------------------------ counters
 
     def snapshot(self) -> Dict[str, object]:
-        """Artifact-shaped stats: the ``streaming_*`` fields bench and
-        bench_compare gate on."""
+        """The tier's counters as one dict: benchmark/retreat.py (a
+        run with no incremental tick is a retreat), benchmark/harness.py
+        and chip_smoke.py read it through
+        ``TPUPlanner.streaming_snapshot``."""
         return {
             "enabled": True,
             "dirty_frac": round(self.stats["dirty_frac"], 4),

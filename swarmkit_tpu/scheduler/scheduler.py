@@ -369,7 +369,7 @@ class Scheduler:
         # plane() is resolved per call — planes.reset() rebinds the
         # table and a cached PlaneStats would go stale.  The probe holds
         # a WEAKREF: it must never pin a dead scheduler's task graph
-        # (bench builds one per trial).  Co-resident schedulers (HA
+        # (tests build many in one process).  Co-resident schedulers (HA
         # tests): last constructed owns the probe.
         import weakref
         _ref = weakref.ref(self)

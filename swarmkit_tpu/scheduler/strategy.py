@@ -131,8 +131,8 @@ def resolve(name: str) -> Optional[StrategyInfo]:
 
 def count_fallback(name: str) -> None:
     """A non-spread strategy group was served by the spread path (the
-    strategy could not be honored — unknown name).  The cfg11 bench
-    gate pins this at 0 for spread/binpack workloads."""
+    strategy could not be honored — unknown name).
+    tests/test_strategy.py holds it at 0 for spread/binpack groups."""
     _metrics.counter(f'swarm_strategy_fallbacks{{strategy="{name}"}}')
 
 

@@ -15,7 +15,7 @@ from ..utils.metrics import registry as _metrics
 
 # cached Timer reference (Registry.reset() resets it in place): total
 # wall time spent synthesizing per-task events out of coalesced blocks —
-# the watch fan-out cost the bench reports as ``fanout_s``
+# the watch fan-out cost (``swarm_watch_fanout_latency`` on /metrics)
 _FANOUT_TIMER = _metrics.timer("swarm_watch_fanout_latency")
 
 

@@ -66,7 +66,7 @@ class Timer:
         return {q: buf[max(0, math.ceil(q * n) - 1)] for q in _QUANTILES}
 
     def reset(self) -> None:
-        """Forget every observation (per-bench-config isolation)."""
+        """Forget every observation (per-test / per-scenario isolation)."""
         with self._lock:
             self._buf = []
             self._i = 0
@@ -108,8 +108,8 @@ class Registry:
             return self.gauges.get(name, default)
 
     def counters_snapshot(self, prefix: str = "") -> Dict[str, float]:
-        """Copy of the counter map (optionally prefix-filtered); bench
-        diffs two snapshots to attribute counts to one timed region."""
+        """Copy of the counter map (optionally prefix-filtered); the
+        benchmark diffs two snapshots to attribute counts to its window."""
         with self._lock:
             return {k: v for k, v in self.counters.items()
                     if k.startswith(prefix)}
@@ -134,7 +134,7 @@ class Registry:
     def reset(self) -> None:
         """Zero all counters/gauges and reset timers IN PLACE — components
         hold Timer references from ``timer(name)``, so the objects must
-        survive a reset (per-bench-config isolation)."""
+        survive a reset (per-test / per-scenario isolation)."""
         with self._lock:
             self.counters.clear()
             self.gauges.clear()

@@ -1,9 +1,4 @@
-"""Seeded distribution samplers shared by the sim and the bench.
-
-One definition so the deterministic scenarios and the churn bench draw
-from the same distribution — a numerical tweak applied to one can never
-silently diverge the other.
-"""
+"""Seeded distribution samplers for the sim's deterministic scenarios."""
 
 from __future__ import annotations
 

@@ -48,8 +48,8 @@ def _restore_autoscale_health_gauges():
     """The flap/out-of-bounds sensitivity tests deliberately drive the
     process-global registry's autoscale gauges into warn/fail states;
     park them back at 0 so every later health assertion in the process
-    (e.g. the bench smoke's all-pass verdict) judges its own run — the
-    swarm_stale_reads discipline from the follower-reads tests."""
+    (e.g. test_obs.py's all-pass verdict on clean books) judges its own
+    run — the swarm_stale_reads discipline from the follower-reads tests."""
     yield
     from swarmkit_tpu.utils.metrics import registry
     for prefix in ('swarm_autoscale_flapping{service="',
@@ -368,6 +368,21 @@ def test_quota_device_path_byte_identical_to_host():
     # the multi-service pending queue fused (quota column in the fused
     # program, not just the per-group one)
     assert planner.stats.get("groups_fused", 0) >= 2, planner.stats
+
+
+def test_quota_clamped_burst_on_the_device_counts_clamps_and_compiles_once():
+    """A clamped tenant's burst through the device planner: admission
+    control fires (the host path's six clamps), no group leaves the
+    device for it, and the same tick again, warm, compiles nothing."""
+    from swarmkit_tpu.ops import TPUPlanner
+    from test_scheduler import cold_then_warm
+
+    def tick():
+        planner = TPUPlanner()
+        _, sched, _ = _run_quota_tick(planner)
+        assert sched.stats["quota_clamps"] == 6
+        assert not planner.stats.get("groups_fallback", 0), planner.stats
+    cold_then_warm(tick)
 
 
 def test_quota_differential_fuzz_random_tenants():

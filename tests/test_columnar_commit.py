@@ -142,8 +142,9 @@ def test_entry_codec_fallbacks():
 
 
 def test_native_commit_fallback_counter(monkeypatch):
-    """Native requested but unavailable counts fallback ticks (bench
-    gate evidence); the explicit escape hatch does not."""
+    """Native requested but unavailable counts fallback ticks (what
+    benchmark/retreat.py reads as a retreat); the explicit escape hatch
+    does not."""
     from swarmkit_tpu.utils.metrics import registry
     monkeypatch.setenv("SWARMKIT_TPU_NO_NATIVE", "1")
     base = registry.get_counter("swarm_native_commit_fallbacks")
@@ -154,6 +155,30 @@ def test_native_commit_fallback_counter(monkeypatch):
     assert native.get_commit() is None
     assert registry.get_counter("swarm_native_commit_fallbacks") \
         == base + 1   # hatch pulled: intentional, not a fallback
+
+
+def test_native_plane_serves_a_block_workload_with_no_fallback(
+        monkeypatch):
+    """With the plane built and not switched off, the commit path asks
+    for it, gets it every time, and counts no fallback: block commits,
+    a delete burst and the watch fan-out of both ran native."""
+    _require_native()
+    from swarmkit_tpu.utils.metrics import registry
+    monkeypatch.delenv("SWARM_NATIVE_COMMIT", raising=False)
+    served = []
+    get_commit = native.get_commit
+
+    def recording():
+        served.append(get_commit())
+        return served[-1]
+    monkeypatch.setattr(native, "get_commit", recording)
+    base = registry.get_counter("swarm_native_commit_fallbacks")
+    store = MemoryStore()
+    sub = store.queue.subscribe()
+    _drive_workload(store)
+    assert len(sub.drain()) > 37
+    assert served and all(hp is not None for hp in served)
+    assert registry.get_counter("swarm_native_commit_fallbacks") == base
 
 
 # ---------------------------------------------------------------------------
@@ -444,50 +469,6 @@ def test_follower_apply_diverged_falls_back(frozen_clock):
     events = [e for e in sub.drain() if isinstance(e, Event)]
     assert [e.version for e in events] == [base + 1, base + 3]
     assert store.version == base + 3
-
-
-# ---------------------------------------------------------------------------
-# bench gates
-# ---------------------------------------------------------------------------
-
-def test_bench_compare_commit_plane_gates(tmp_path):
-    """bench_compare exits 1 when cfg6 commit_phase_s regresses > 20%
-    or when the native commit plane fell back to Python in the timed
-    window; the explicit escape hatch (enabled=False) is exempt."""
-    sys.path.insert(0, os.path.join(REPO, "scripts"))
-    import bench_compare as bc
-
-    def doc(commit, nc):
-        return {"value": 250000, "configs": {
-            "6_live_manager_2x100k_x_10k": {
-                "decisions_per_sec": 100000, "shape_cost_x": 1.2,
-                "commit_phase_s": commit, "native_commit": nc,
-                "compiles": {}}}}
-
-    def run(old, new, tag):
-        a = tmp_path / f"old-{tag}.json"
-        b = tmp_path / f"new-{tag}.json"
-        a.write_text(json.dumps(old))
-        b.write_text(json.dumps(new))
-        import contextlib
-        import io
-        buf = io.StringIO()
-        with contextlib.redirect_stdout(buf), \
-                contextlib.redirect_stderr(buf):
-            return bc.main([str(a), str(b)])
-
-    ok = {"enabled": True, "active": True, "fallbacks": 0}
-    assert run(doc(1.0, ok), doc(1.1, ok), "within") == 0
-    assert run(doc(1.0, ok), doc(1.3, ok), "regressed") == 1
-    assert run(doc(1.0, ok),
-               doc(1.0, {"enabled": True, "active": True,
-                         "fallbacks": 3}), "fellback") == 1
-    assert run(doc(1.0, ok),
-               doc(1.0, {"enabled": True, "active": False,
-                         "fallbacks": 0}), "inactive") == 1
-    assert run(doc(1.0, ok),
-               doc(1.0, {"enabled": False, "active": False,
-                         "fallbacks": 0}), "hatch") == 0
 
 
 # ---------------------------------------------------------------------------

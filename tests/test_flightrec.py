@@ -282,24 +282,23 @@ def test_debug_server_index_health_and_flightrec():
 def test_compile_counter_zero_on_second_same_bucket_call():
     """A planner call through a FRESH jit records exactly the compiles
     the XLA cache reports; a second call on the same static shape bucket
-    records zero — so bench's per-bucket counts separate "compiled in
-    the timed region" from "ran warm", which timing alone cannot."""
+    records zero — so the per-bucket counts separate "compiled in the
+    measured window" from "ran warm", which timing alone cannot."""
     import jax
 
-    from bench import build_cluster, one_tick
     from swarmkit_tpu.ops import TPUPlanner
     from swarmkit_tpu.ops.kernel import plan_group
     from swarmkit_tpu.utils.metrics import registry
+    from test_scheduler import tick_one_service
 
     @functools.partial(jax.jit, static_argnames=("L",))
     def fresh_plan_fn(nodes, group, L, hier=()):
         return plan_group(nodes, group, L, hier=hier)
 
     def run_once():
-        store, svc, nodes, tasks = build_cluster(64, 256)
         planner = TPUPlanner(plan_fn=fresh_plan_fn)
         planner.enable_small_group_routing = False
-        one_tick(store, planner)
+        tick_one_service(planner, n_nodes=64, n_tasks=256)
 
     def compile_counts():
         return registry.counters_snapshot("swarm_planner_compiles")

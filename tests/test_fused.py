@@ -453,56 +453,6 @@ def test_fused_differential_detects_divergence(monkeypatch):
                for v in r.violations), r.violations
 
 
-def test_bench_compare_shape_and_compile_gates(tmp_path):
-    """bench_compare exits 1 when the NEW run's cfg6/cfg7 shape_cost_x
-    exceeds the bar or when timed-region compile counts grew; clean
-    runs pass."""
-    import json
-    import os as _os
-    import sys as _sys
-    _sys.path.insert(0, _os.path.join(_os.path.dirname(__file__), "..",
-                                      "scripts"))
-    try:
-        import bench_compare
-    finally:
-        _sys.path.pop(0)
-
-    def record(shape6=1.2, shape7=1.3, compiles=0, headline_compiles=0):
-        return {"t": 1.0, "value": 250000.0, "unit": "d/s",
-                "metric": "m", "health": "pass",
-                "planner_compiles": headline_compiles,
-                "configs": {
-                    "6_live_manager_2x100k_x_10k": {
-                        "decisions_per_sec": 170000.0,
-                        "shape_cost_x": shape6, "compiles": compiles},
-                    "7_many_service_10x": {
-                        "decisions_per_sec": 170000.0,
-                        "shape_cost_x": shape7, "compiles": 0}},
-                "pipeline_depth": 2, "plan_hidden_frac": 0.5,
-                "plan_commit_overlap_s": 0.05,
-                "plan_overlap_source": "cfg6"}
-
-    hist = tmp_path / "hist.jsonl"
-
-    def run(old, new):
-        with open(hist, "w") as f:
-            f.write(json.dumps(old) + "\n")
-            f.write(json.dumps(new) + "\n")
-        return bench_compare.main(["--history", str(hist)])
-
-    assert run(record(), record()) == 0
-    # shape bar is judged on the NEW run alone, per live config
-    assert run(record(), record(shape6=1.9)) == 1
-    assert run(record(), record(shape7=2.4)) == 1
-    # an old run that also missed the bar must not disarm the gate
-    assert run(record(shape6=3.0), record(shape6=1.9)) == 1
-    # compile growth in a shared config or the headline fails
-    assert run(record(), record(compiles=2)) == 1
-    assert run(record(), record(headline_compiles=1)) == 1
-    # equal nonzero compile counts are flat, not growth
-    assert run(record(compiles=1), record(compiles=1)) == 0
-
-
 def test_mesh_env_knob(monkeypatch):
     """SWARM_PLANNER_MESH builds the mesh at planner construction; a
     count beyond the available devices is a loud error."""

@@ -368,8 +368,8 @@ def _mk_store(n_nodes, services, node_cpu=4 * 10 ** 9, cluster=None):
     return store
 
 
-def _tick(store):
-    sched = Scheduler(store)
+def _tick(store, planner=None):
+    sched = Scheduler(store, batch_planner=planner)
     store.view(sched._setup_tasks_list)
     sched.tick()
     return sched
@@ -459,6 +459,45 @@ def test_cross_service_gang_is_one_atomic_unit():
     _tick(store2)
     assert all(t.node_id and t.status.state == TaskState.ASSIGNED
                for t in store2.view(lambda tx: tx.find(Task)))
+
+
+def test_gangs_and_a_gated_stage_through_the_device_planner():
+    """Gang admission with the device planner behind it: every gang is
+    judged by ``gang_fit`` on the device, per group and fused (none by
+    the host oracle), admitted whole with no deferral, the downstream
+    stage is held by the DAG gate, and the same tick again, warm,
+    compiles nothing."""
+    from swarmkit_tpu.ops import TPUPlanner
+    from test_scheduler import cold_then_warm
+    svcs = [("svc-a", 0, 6, 0, 6, "", (), ""),
+            ("svc-b", 0, 6, 0, 6, "", (), ""),
+            ("svc-c", 0, 4, 0, 4, "", (), ""),
+            ("svc-h1", 0, 3, 0, 6, "ring", (), ""),
+            ("svc-h2", 0, 3, 0, 6, "ring", (), ""),
+            ("stage-b", 0, 2, 0, 0, "", ("svc-a",), "")]
+    def tick():
+        planner = TPUPlanner()
+        planner.enable_small_group_routing = False
+        store = _mk_store(12, svcs)
+        sched = _tick(store, planner)
+        tasks = store.view(lambda tx: tx.find(Task))
+        gang = [t for t in tasks if t.service_id != "stage-b"]
+        assert all(t.node_id and t.status.state == TaskState.ASSIGNED
+                   for t in gang)
+        assert sched.gang.stats["gangs_admitted"] == 4
+        assert sched.gang.stats["gangs_deferred"] == 0
+        assert not sched.gang.blocked
+        st = planner.stats
+        # three single-service gangs, one verdict each; the two member
+        # groups of the cross-service unit in one fused call
+        assert st.get("gang_fit_device", 0) == 3, st
+        assert st.get("gang_fit_fused", 0) == 2, st
+        assert not st.get("gang_fit_host", 0), st
+        assert not st.get("gang_device_error", 0), st
+        assert {t.status.err for t in tasks
+                if t.service_id == "stage-b"} == \
+            {"awaiting upstream pipeline stage"}
+    cold_then_warm(tick)
 
 
 def test_incomplete_gang_waits_for_materialization():

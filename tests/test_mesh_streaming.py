@@ -555,54 +555,3 @@ def test_mesh_steady_state_churn_sim(monkeypatch):
     r = run_scenario("steady-state-churn", seed=7)
     assert r.ok, r.violations
 
-
-# ------------------------------------------------- bench_compare gate
-
-def test_bench_compare_mesh_resident_transfer_gate(tmp_path):
-    """bench_compare's mesh-resident-transfer gate: a cfg10 run under a
-    planner mesh must keep resident H2D/tick within the dirty-scatter
-    budget and route zero strategy groups to the host oracle; judged on
-    the NEW run alone, and skipped entirely for single-device runs."""
-    import json as _json
-    import os as _os
-    import sys as _sys
-    _sys.path.insert(0, _os.path.join(_os.path.dirname(__file__), "..",
-                                      "scripts"))
-    try:
-        import bench_compare
-    finally:
-        _sys.path.pop(0)
-
-    def record(mesh=2, resident_h2d=512.0, host_groups=0):
-        return {"t": 1.0, "value": 250000.0, "unit": "d/s",
-                "metric": "m", "health": "pass", "planner_compiles": 0,
-                "configs": {"10_steady_state_churn": {
-                    "decisions_per_sec": 50000.0, "compiles": 0,
-                    "streaming": {"enabled": True,
-                                  "incremental_ticks": 5,
-                                  "dirty_frac": 0.01,
-                                  "resyncs": 1, "fallbacks": 0},
-                    "pending_assigned_p99_s": 0.02,
-                    "h2d_bytes_per_tick": 1000.0,
-                    "planner_mesh": mesh,
-                    "resident_h2d_bytes_per_tick": resident_h2d,
-                    "strategy_host_groups": host_groups}}}
-
-    hist = tmp_path / "hist.jsonl"
-
-    def run(old, new):
-        with open(hist, "w") as f:
-            f.write(_json.dumps(old) + "\n")
-            f.write(_json.dumps(new) + "\n")
-        return bench_compare.main(["--history", str(hist)])
-
-    assert run(record(), record()) == 0
-    # a column re-upload per tick blows the dirty-scatter budget
-    assert run(record(), record(resident_h2d=5.0e8)) == 1
-    # any strategy group on the host oracle under a mesh fails
-    assert run(record(), record(host_groups=3)) == 1
-    # the gate is the MESH contract: single-device runs skip it
-    assert run(record(), record(mesh=1, resident_h2d=5.0e8)) == 0
-    # an old run that also blew the budget must not disarm the gate
-    assert run(record(resident_h2d=5.0e8),
-               record(resident_h2d=5.0e8)) == 1

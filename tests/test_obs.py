@@ -1,5 +1,5 @@
 """Observability layer: tracer, lifecycle tracker, metrics registry,
-trace reports, and the tiny-bench smoke test.
+trace reports, and the traced device tick.
 
 Covers the obs PR's acceptance surface:
 * exposition-format golden test for the registry (labeled + plain);
@@ -8,14 +8,15 @@ Covers the obs PR's acceptance surface:
 * virtual-clock determinism: same sim seed ⇒ byte-identical trace;
 * lifecycle latency through the real task FSM edge sequence;
 * Collector labeled gauges surviving EventSnapshotRestore recounts;
-* bench smoke: a tiny config emits a schema-valid Chrome trace whose
-  phases appear in the artifact's phase table.
+* traced device tick: a tick through the device planner emits a
+  schema-valid Chrome trace whose phases appear in its phase table.
 """
 
-import importlib
 import json
 import os
 import sys
+
+import pytest
 
 from swarmkit_tpu.models import (
     Annotations, Node, NodeDescription, NodeSpec, NodeState, NodeStatus,
@@ -29,6 +30,8 @@ from swarmkit_tpu.sim.clock import VirtualClock
 from swarmkit_tpu.state.events import Event, EventSnapshotRestore
 from swarmkit_tpu.state.store import MemoryStore
 from swarmkit_tpu.utils.metrics import Registry, Timer
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 # ------------------------------------------------------------------ registry
@@ -146,24 +149,62 @@ def test_live_snapshot_and_reset_mid_span():
     assert validate_chrome_trace(tr.to_chrome()) == []
 
 
+def _ev(name, ts, dur, sid, parent=0, **args):
+    return {"name": name, "cat": "p", "ph": "X", "ts": ts,
+            "dur": dur, "pid": 1, "tid": 1,
+            "args": dict(args, span_id=sid, parent_id=parent)}
+
+
 def test_phase_overlap_merges_concurrent_spans():
     """Concurrent spans of the same phase (the pipelining PR will emit
     them from worker threads) must not double-count: the hidden fraction
     is bounded by 1.0."""
-    def ev(name, ts, dur, sid):
-        return {"name": name, "cat": "p", "ph": "X", "ts": ts,
-                "dur": dur, "pid": 1, "tid": 1,
-                "args": {"span_id": sid, "parent_id": 0}}
-
     doc = {"traceEvents": [
-        ev("plan.dispatch", 0, 100, 1),     # two overlapping plan spans
-        ev("plan.dispatch", 0, 100, 2),
-        ev("sched.commit", 0, 100, 3),
+        _ev("plan.dispatch", 0, 100, 1),     # two overlapping plan spans
+        _ev("plan.dispatch", 0, 100, 2),
+        _ev("sched.commit", 0, 100, 3),
     ]}
     table = phase_table(doc)
     assert table["plan_wall_s"] == 100 / 1e6
     assert table["plan_commit_overlap_s"] == 100 / 1e6
     assert table["plan_hidden_frac"] == 1.0
+
+
+@pytest.mark.parametrize("argv,rc,said", [
+    ([], 0, ["plan.dispatch", "sched.commit", "plan hidden: 50.0%",
+             "scheduler"]),
+    (["--validate"], 0, ["ok (3 spans)"]),
+    (["--json"], 0, ['"plan_hidden_frac": 0.5', '"thread_cpu_s"']),
+    (["--service", "svc1"], 0, ["sched.commit", "commit.apply"]),
+    (["--service", "nobody"], 1, []),
+    (["--diff"], 0, ["sched.commit", "+100.0%",
+                     "plan_hidden_frac"]),
+], ids=["table", "validate", "json", "service", "service-unknown", "diff"])
+def test_trace_report_cli(tmp_path, capsys, argv, rc, said):
+    """``scripts/trace_report.py`` on a trace file: the phase table with
+    the loop's thread CPU under it, the schema check, the JSON form,
+    one service's spans (nested ones included), and the diff of two
+    traces."""
+    sys.path.insert(0, os.path.join(REPO, "scripts"))
+    try:
+        import trace_report
+    finally:
+        sys.path.pop(0)
+    doc = {"traceEvents": [
+        _ev("plan.dispatch", 0, 100, 1),
+        _ev("sched.commit", 50, 100, 2, service="svc1"),
+        _ev("commit.apply", 60, 10, 3, parent=2),
+    ], "otherData": {"thread_cpu_s": {"scheduler": 0.25}}}
+    paths = [tmp_path / "a.json"]
+    paths[0].write_text(json.dumps(doc))
+    if "--diff" in argv:
+        doc["traceEvents"].append(_ev("sched.commit", 200, 100, 4))
+        paths.append(tmp_path / "b.json")
+        paths[1].write_text(json.dumps(doc))
+    assert trace_report.main([str(p) for p in paths] + argv) == rc
+    out = capsys.readouterr().out
+    for text in said:
+        assert text in out, out
 
 
 def test_sim_trace_determinism_and_content():
@@ -302,99 +343,58 @@ def test_collector_labeled_gauges_survive_restore():
     assert global_reg.gauges['swarm_manager_tasks{state="running"}'] == 1
 
 
-# --------------------------------------------------------------- bench smoke
+# ------------------------------------------------------- traced device tick
 
-def test_bench_tiny_config_emits_valid_trace(tmp_path, monkeypatch,
-                                             capsys):
-    """Tier-1 smoke: a tiny bench run writes a schema-valid Chrome trace
-    and the artifact's phase table reflects the trace's per-phase spans."""
-    repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    trace_out = str(tmp_path / "trace.json")
-    history_out = str(tmp_path / "history.jsonl")
-    monkeypatch.setenv("BENCH_HISTORY", history_out)
-    monkeypatch.setenv("BENCH_FLIGHTREC_OUT",
-                       str(tmp_path / "flightrec.json"))
-    monkeypatch.setenv("BENCH_NODES", "64")
-    # large enough that the adaptive router always amortizes a device
-    # round-trip (4096 tasks ≈ 200ms of host-path cost vs a launch
-    # overhead of ~10ms even on a loaded CI box) — 512 was marginal and
-    # flaked onto the host path under pytest load
-    monkeypatch.setenv("BENCH_TASKS", "4096")
-    monkeypatch.setenv("BENCH_TRIALS", "1")
-    monkeypatch.setenv("BENCH_SKIP_HOST", "1")
-    monkeypatch.setenv("BENCH_SKIP_CONFIGS", "1")
-    monkeypatch.setenv("BENCH_SKIP_E2E", "1")
-    monkeypatch.setenv("BENCH_TRACE_OUT", trace_out)
-    monkeypatch.syspath_prepend(repo_root)
-    import bench
-    bench = importlib.reload(bench)   # re-read env-derived constants
-    # a standalone bench process starts with nothing on the books; in
-    # suite order whichever modules shared this xdist worker left their
-    # rollouts, overload gauges and plane windows in the process-wide
-    # registry and plane table, and the all-pass health verdict below
-    # would judge them as this run's.  The run is given clean books
-    # (reset in place: components hold their Timer references), less
-    # the compile counters: they are how the artifact names a bucket
-    # that an earlier module of this process already compiled.
-    from swarmkit_tpu.obs import planes
+def _device_tick():
+    """A tick of one service through a ``TPUPlanner``.  4,096 tasks:
+    large enough that the break-even router always prices the device
+    route cheaper, whatever the host's load."""
+    from swarmkit_tpu.ops import TPUPlanner
+    from test_scheduler import tick_one_service
+
+    planner = TPUPlanner()
+    tick_one_service(planner, n_nodes=64, n_tasks=4096)
+    assert planner.stats["tasks_planned"] == 4096, planner.stats
+
+
+def test_traced_device_tick_emits_valid_trace_on_clean_books():
+    """A tracer-on tick through the device planner writes a schema-valid
+    Chrome trace whose phase table is backed by its spans; warm, it
+    compiles nothing; and the health plane passes every check on the
+    books it leaves."""
+    from swarmkit_tpu.obs import HealthEvaluator, planes, tracer
     from swarmkit_tpu.utils.metrics import registry
-    compiled = {k: v for k, v in dict(registry.counters).items()
-                if k.startswith('swarm_planner_compiles{')}
+
+    # in suite order whichever modules shared this xdist worker left
+    # their rollouts, overload gauges and plane windows in the
+    # process-wide registry and plane table, and the all-pass verdict
+    # below would judge them as this run's.  Clean books (reset in
+    # place: components hold their Timer references).
     registry.reset()
     planes.reset()
-    for key, n in compiled.items():
-        registry.counter(key, n)
+
+    _device_tick()                      # warm: compiles its bucket
+    warm = registry.counters_snapshot("swarm_planner_compiles")
+    tracer.reset()
+    tracer.enable()
     try:
-        bench.main()
+        _device_tick()
+        doc = tracer.to_chrome()
     finally:
-        # leave the module with default constants for any later importer
-        for k in ("BENCH_NODES", "BENCH_TASKS", "BENCH_TRIALS",
-                  "BENCH_SKIP_HOST", "BENCH_SKIP_CONFIGS",
-                  "BENCH_SKIP_E2E", "BENCH_TRACE_OUT", "BENCH_HISTORY",
-                  "BENCH_FLIGHTREC_OUT"):
-            monkeypatch.delenv(k, raising=False)
-        importlib.reload(bench)
+        tracer.disable()
+        tracer.reset()
+    assert registry.counters_snapshot("swarm_planner_compiles") == warm
 
-    artifact = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert artifact["trace_file"] == trace_out
-    with open(trace_out) as f:
-        doc = json.load(f)
     assert validate_chrome_trace(doc) == []
-
     trace_names = {e["name"] for e in x_events(doc)}
     assert {"sched.tick", "plan.dispatch", "plan.d2h",
             "sched.commit"} <= trace_names
-
-    table = artifact["phase_table"]["headline"]
-    # every phase row is backed by spans in the emitted trace, and the
-    # device-plan phases made it into the table
+    table = phase_table(doc)
     assert set(table["phases"]) <= trace_names
-    assert "plan.dispatch" in table["phases"]
-    assert "sched.commit" in table["phases"]
+    assert {"plan.dispatch", "sched.commit"} <= set(table["phases"])
     assert table["plan_wall_s"] > 0
-    # fresh table from the same file agrees with the embedded one
-    recomputed = phase_table(doc, window=None)
-    assert set(table["phases"]) <= set(recomputed["phases"])
-    # overhead was measured (enabled vs disabled in the same run)
-    assert "overhead_pct" in artifact["obs"]
-    assert artifact["obs"]["enabled_decisions_per_sec"] > 0
-    assert artifact["obs"]["disabled_decisions_per_sec"] > 0
 
-    # compile observability: the artifact names every jit bucket the
-    # headline touched, and — warm-up done — none recompiled inside the
-    # timed region (a nonzero count here IS the r4/r5 variance story)
-    compiles = artifact["planner_compiles"]
-    assert isinstance(compiles, dict) and compiles
-    assert all(v == 0 for v in compiles.values()), compiles
-
-    # health plane: a clean tiny-bench run reports every check passing
-    assert artifact["health"]["status"] == "pass"
-    assert artifact["health"]["checks"]
-    assert all(s == "pass" for s in artifact["health"]["checks"].values())
-
-    # the run appended one history record bench_compare.py can diff
-    with open(history_out) as f:
-        records = [json.loads(line) for line in f if line.strip()]
-    assert len(records) == 1
-    assert records[0]["value"] == artifact["value"]
-    assert records[0]["health"] == "pass"
+    health = HealthEvaluator()
+    checks = health.evaluate()
+    assert checks and health.status() == "pass"
+    assert all(state == "pass" for state in checks.values()), checks

@@ -416,24 +416,43 @@ def test_multi_kind_generic_demand_still_waived():
     assert not hp.preemptable_group(named)
 
 
+def _three_band_tick(planner):
+    """A cluster full of priority-0 work, then a priority-3 and a
+    priority-10 arrival, in one tick."""
+    store = _mk_store(3, [("lo", 0, 0, 6), ("mid", 3, 1, 0),
+                          ("hi", 10, 2, 0)])
+    sched = Scheduler(store, batch_planner=planner)
+    if planner is not None:
+        planner.enable_small_group_routing = False
+    store.view(sched._setup_tasks_list)
+    sched.tick()
+    return sched, sorted(
+        (t.id, t.node_id, int(t.status.state), int(t.desired_state))
+        for t in store.view(lambda tx: tx.find(Task)))
+
+
 def test_device_and_host_schedulers_place_identically():
     from swarmkit_tpu.ops import TPUPlanner
+    assert _three_band_tick(None)[1] == _three_band_tick(TPUPlanner())[1]
 
-    def run(planner):
-        store = _mk_store(3, [("lo", 0, 0, 6), ("mid", 3, 1, 0),
-                              ("hi", 10, 2, 0)])
-        sched = Scheduler(store, batch_planner=planner)
-        if planner is not None:
-            planner.enable_small_group_routing = False
-        store.view(sched._setup_tasks_list)
-        sched.tick()
-        return sorted(
-            (t.id, t.node_id, int(t.status.state), int(t.desired_state))
-            for t in store.view(lambda tx: tx.find(Task)))
 
-    host = run(None)
-    device = run(TPUPlanner())
-    assert host == device
+def test_three_bands_preempt_on_the_device_and_compile_once():
+    """Three bands on a full cluster through the device planner: the
+    preemption pass fires (one victim per arrival), victim selection
+    stays on the device, and the same tick again, warm, compiles
+    nothing."""
+    from swarmkit_tpu.ops import TPUPlanner
+    from test_scheduler import cold_then_warm
+
+    def tick():
+        planner = TPUPlanner()
+        sched, rows = _three_band_tick(planner)
+        assert sched.stats["preemptions"] == 3
+        assert sum(1 for tid, node, _s, _d in rows
+                   if node and tid.startswith(("mid-p", "hi-p"))) == 3
+        assert not planner.stats.get("preempt_device_error", 0)
+        assert not planner.stats.get("preempt_breaker_to_host", 0)
+    cold_then_warm(tick)
 
 
 def test_breaker_open_routes_selection_to_host():
@@ -453,7 +472,7 @@ def test_preemption_storm_green_and_deterministic():
     # first run warms the victim-kernel jit signatures: its obs trace
     # carries the one-off plan.compile events (zero-duration under the
     # virtual clock, but present), so byte-identity is judged on the
-    # warm pair — same discipline as the bench's warm-up windows
+    # warm pair — same discipline as the benchmark's warm-up
     warm = run_scenario("preemption-storm", seed=0)
     assert warm.ok, warm.violations
     r1 = run_scenario("preemption-storm", seed=0)

@@ -88,6 +88,35 @@ def make_service_with_tasks(n_tasks, reservations=None, constraints=None,
     return svc, tasks
 
 
+def tick_one_service(planner, n_nodes=64, n_tasks=256):
+    """One synchronous ``Scheduler.tick()`` of one ``n_tasks`` service
+    through ``planner`` on ``n_nodes`` fresh 64-CPU nodes; returns the
+    scheduler.  Shared by the tests that need a device tick and no more
+    (``test_obs.py``, ``test_flightrec.py``)."""
+    store = MemoryStore()
+    nodes = [make_ready_node(f"node-{i:03d}", cpus=64, mem=256 << 30)
+             for i in range(n_nodes)]
+    svc, tasks = make_service_with_tasks(
+        n_tasks, reservations=Resources(nano_cpus=10 ** 8,
+                                        memory_bytes=64 << 20))
+    store.update(lambda tx: [tx.create(obj)
+                             for obj in (*nodes, svc, *tasks)])
+    sched = Scheduler(store, batch_planner=planner)
+    store.view(sched._setup_tasks_list)
+    assert sched.tick() == n_tasks
+    return sched
+
+
+def cold_then_warm(tick):
+    """Run ``tick()`` twice: whatever it compiles is the first run's to
+    compile, and the second, warm, adds no ``swarm_planner_compiles``."""
+    from swarmkit_tpu.utils.metrics import registry
+    tick()
+    before = registry.counters_snapshot("swarm_planner_compiles")
+    tick()
+    assert registry.counters_snapshot("swarm_planner_compiles") == before
+
+
 @pytest.fixture
 def cluster():
     store = MemoryStore()

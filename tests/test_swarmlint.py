@@ -386,12 +386,15 @@ def test_write_baseline_preserves_out_of_scope_entries(tmp_path):
 
 
 def test_file_roots_are_normalized():
-    """'./bench.py' and 'bench.py' must lint identically — whitelists
-    and baseline entries match on the canonical repo-relative path."""
+    """'./scripts/chaos_sweep.py' and 'scripts/chaos_sweep.py' must lint
+    identically — whitelists (here ``scripts/``: the file imports the
+    simulator) and baseline entries match on the canonical repo-relative
+    path."""
     from swarmkit_tpu.analysis import iter_source_files
 
-    assert iter_source_files(REPO, ("./bench.py",)) == ["bench.py"]
-    r = lint_tree(REPO, roots=("./bench.py",), baseline_path=None)
+    root = "./scripts/chaos_sweep.py"
+    assert iter_source_files(REPO, (root,)) == ["scripts/chaos_sweep.py"]
+    r = lint_tree(REPO, roots=(root,), baseline_path=None)
     assert r.ok, [f.render() for f in r.new]
 
 
