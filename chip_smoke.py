@@ -392,9 +392,160 @@ def _timed_twice(row, fn):
     first = time.perf_counter() - t0
     t0 = time.perf_counter()
     out = jax.block_until_ready(fn())
-    row["run_s"] = round(time.perf_counter() - t0, 4)
+    row["run_s"] = round(time.perf_counter() - t0, 5)
     row["compile_s"] = round(max(first - row["run_s"], 0.0), 3)
     return out
+
+
+#: (node bucket, nodes in it) of the two cells: the plan programs are
+#: timed at both, on a service seen for the first time
+TIMED_BUCKETS = ((1024, 1000), (16384, 10000))
+#: group sizes from the cells' mix (60 % of its services have 1-10
+#: replicas); the last fused slot is padding, as a short run's is
+TIMED_K = {"flat": 10, "hier": 100, "binpack": 30,
+           "fused": (10, 100, 3, 0)}
+
+
+def _timed_inputs(nb: int, n: int, seed: int):
+    """Synthetic columns of a fresh service on ``n`` nodes of the
+    ``nb`` bucket: no task of its own anywhere, 0-8 tasks of others a
+    node, 64 CPU / 256 GiB free, 4 zones of 25 racks dealt round-robin.
+    Returns (NodeInputs for a flat group, group_of(k), zone i32[nb],
+    rack i32[nb])."""
+    from swarmkit_tpu.ops.kernel import GroupInputs, NodeInputs
+    rng = np.random.default_rng([seed, nb])
+    i32 = np.int32
+    valid = np.arange(nb) < n
+    zone = np.where(valid, np.arange(nb) % ZONES, 0).astype(i32)
+    rack = np.where(valid, zone * RACKS_PER_ZONE
+                    + (np.arange(nb) // ZONES) % RACKS_PER_ZONE,
+                    0).astype(i32)
+    nodes = NodeInputs(
+        valid=valid, ready=valid, res_ok=valid,
+        res_cap=np.where(valid, 640, 0).astype(i32),
+        svc_tasks=np.zeros(nb, i32),
+        total_tasks=np.where(valid, rng.integers(0, 9, nb), 0).astype(i32),
+        failures=np.zeros(nb, i32), leaf=np.zeros(nb, i32),
+        os_hash=np.zeros((2, nb), i32), arch_hash=np.zeros((2, nb), i32),
+        port_conflict=np.zeros(nb, bool), extra_mask=np.ones(nb, bool))
+
+    def group_of(k):
+        return GroupInputs(
+            k=i32(k), con_hash=np.zeros((1, 2, nb), i32),
+            con_op=np.full(1, 2, i32), con_exp=np.zeros((1, 2), i32),
+            plat=np.full((1, 4), -1, i32), maxrep=i32(0),
+            port_limited=np.bool_(False))
+    return nodes, group_of, zone, rack
+
+
+def _tie_key(nodes):
+    from swarmkit_tpu.scheduler import strategy as strategy_mod
+    return ((nodes.total_tasks.astype(np.int64) << strategy_mod.IDX_BITS)
+            | np.arange(len(nodes.valid)))
+
+
+def plan_program_times(smoke, buckets=TIMED_BUCKETS, seed: int = 0) -> None:
+    """Each plan program called directly, twice, on a fresh service at
+    each of ``buckets``: ``run_s`` on its PASS line is the program's run
+    time with the result fetched, what ``plan.d2h`` waits for.  Held to
+    the host mirrors (flat, binpack), to the tree's balance (hier) and
+    to the per-group programs applied in order (fused)."""
+    from swarmkit_tpu.ops import fusedbatch
+    from swarmkit_tpu.ops.kernel import (
+        FusedCarry, FusedGroups, FusedShared, FusedStrategy, StrategyInputs,
+        plan_fused_jit, plan_group_jit, plan_strategy_jit,
+    )
+    from swarmkit_tpu.scheduler import strategy as strategy_mod
+    i32, i64 = np.int32, np.int64
+    w1, b1, w2, b2 = (np.asarray(a, i32)
+                      for a in strategy_mod.learned_params())
+    for nb, n in buckets:
+        nodes, group_of, zone, rack = _timed_inputs(nb, n, seed)
+        zeros = np.zeros(nb, i32)
+
+        with smoke.program(f"plan_group_jit/flat@nb{nb}") as row:
+            k = TIMED_K["flat"]
+            x, _fc, spill = _timed_twice(
+                row, lambda: plan_group_jit(nodes, group_of(k), 1, ()))
+            smoke.check(not spill and (np.asarray(x) == (
+                strategy_mod.waterfill_host(
+                    zeros, np.minimum(nodes.res_cap, k), _tie_key(nodes),
+                    k))).all(),
+                "placements differ from waterfill_host")
+
+        with smoke.program(f"plan_group_jit/hier@nb{nb}") as row:
+            k = TIMED_K["hier"]
+            hier = (((zone, np.zeros(16, i32)),),
+                    (np.arange(256) // RACKS_PER_ZONE
+                     % 16).astype(i32))
+            x, _fc, _spill = _timed_twice(
+                row, lambda: plan_group_jit(
+                    nodes._replace(leaf=rack), group_of(k), 256, hier))
+            x = np.asarray(x)
+            racks = np.bincount(rack, x, ZONES * RACKS_PER_ZONE)
+            smoke.check(
+                x.sum() == k and within_one(racks)
+                and within_one(racks.reshape(ZONES, -1).sum(1))
+                and x.max() <= 1, f"tree unbalanced: racks {racks}")
+
+        sin = StrategyInputs(hr_cpu=zeros, hr_mem=zeros, hr_gen=zeros,
+                             weights=np.zeros(4, i32), w1=w1, b1=b1,
+                             w2=w2, b2=b2)
+        with smoke.program(f"plan_strategy_jit/binpack@nb{nb}") as row:
+            k = TIMED_K["binpack"]
+            x, _fc, _spill = _timed_twice(
+                row, lambda: plan_strategy_jit(
+                    nodes, group_of(k), sin, strategy_mod.STRAT_BINPACK))
+            smoke.check((np.asarray(x) == strategy_mod.plan_binpack_host(
+                k, np.minimum(nodes.res_cap, k), nodes.res_cap,
+                zeros)).all(),
+                "placements differ from plan_binpack_host")
+
+        # the fused scan: spread, spread, binpack and a padded slot; no
+        # reservations, so each step sees the last one's totals and no
+        # other change, and the per-group programs say what it must give
+        ks = np.asarray(TIMED_K["fused"], i32)
+        sids = np.asarray([0, 0, strategy_mod.STRAT_BINPACK, 0], i32)
+        want, total = [], nodes.total_tasks
+        for k, sid in zip(ks, sids):
+            seen = nodes._replace(total_tasks=total,
+                                  res_cap=np.where(nodes.valid,
+                                                   strategy_mod.K_CLAMP,
+                                                   0).astype(i32))
+            x = (plan_strategy_jit(seen, group_of(k), sin, int(sid))
+                 if sid else plan_group_jit(seen, group_of(k), 1, ()))[0]
+            want.append(np.asarray(x))
+            total = total + want[-1]
+        for g in (2, 4):
+            with smoke.program(f"plan_fused_jit/g{g}@nb{nb}") as row, \
+                    fusedbatch.x64():
+                shared = FusedShared(
+                    valid=nodes.valid, ready=nodes.ready,
+                    os_hash=nodes.os_hash, arch_hash=nodes.arch_hash,
+                    svc0=np.zeros((g, nb), i32))
+                groups = FusedGroups(
+                    k=ks[:g], slot=np.arange(g, dtype=i32),
+                    maxrep=np.zeros(g, i32), cpu_d=np.zeros(g, i64),
+                    mem_d=np.zeros(g, i64),
+                    con_hash=np.zeros((g, 1, 2, nb), i32),
+                    con_op=np.full((g, 1), 2, i32),
+                    con_exp=np.zeros((g, 1, 2), i32),
+                    plat=np.full((g, 1, 4), -1, i32),
+                    failures=np.zeros((g, nb), i32),
+                    leaf=np.zeros((g, nb), i32),
+                    extra_mask=np.ones((g, nb), bool))
+                carry = FusedCarry(
+                    total=nodes.total_tasks,
+                    cpu=np.where(nodes.valid, NODE_CPU, 0).astype(i64),
+                    mem=np.where(nodes.valid, NODE_MEM, 0).astype(i64),
+                    svc_acc=np.zeros((g, nb), i32))
+                strat = FusedStrategy(sid=sids[:g],
+                                      weights=np.zeros((g, 4), i32),
+                                      w1=w1, b1=b1, w2=w2, b2=b2)
+                xs = _timed_twice(row, lambda: plan_fused_jit(
+                    shared, groups, carry, 1, strat))[0]
+                smoke.check((np.asarray(xs) == np.stack(want[:g])).all(),
+                            "differs from the per-group programs in order")
 
 
 def _gang_programs(smoke, nodes, k):
@@ -838,6 +989,7 @@ def run(device: dict, n_nodes: int = N_NODES, n_agents: int = N_AGENTS,
             print(f"launch_overhead_s={overhead}", flush=True)
         with smoke.phase("programs"):
             programs_phase(smoke, n_nodes, max(replicas // 5, 1), seed)
+            plan_program_times(smoke, seed=seed)
         with smoke.phase("served"):
             served = served_phase(smoke, n_nodes, n_agents, replicas,
                                   seed, timeout)

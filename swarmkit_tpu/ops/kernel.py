@@ -17,18 +17,61 @@ scheduleNTasksOnSubtree, :844 scheduleNTasksOnNodes, nodeset.go:50 tree):
   reproduces the reference's "level per-service counts first, then total
   counts, capacity-bounded" semantics without any sequential loop.
 
-Everything is fixed-shape, fixed-iteration-count (binary searches with a
-static iteration budget), 32-bit, and built exclusively from ops that XLA
-maps well to TPU (segment-sums, elementwise selects).  The identical code
-runs under plain `jit` (single chip) and under `shard_map` with the node
-axis sharded over a mesh — the only difference is the `reduce` callback,
-which becomes a `psum` over the node-axis (see parallel/sharded.py).
+Everything is fixed-shape, 32-bit, and built exclusively from ops that XLA
+maps well to TPU (reductions, elementwise selects).  The searches are
+bisections in ``lax.while_loop``s whose brackets come from the inputs and
+which leave when every segment has converged (see "The searches" below):
+the trip count is a function of the data, at most SEARCH_STEPS_MAX, and
+the result does not depend on it.  The identical code runs under plain
+`jit` (single chip) and under `shard_map` with the node axis sharded over
+a mesh — the only difference is the `reduce` callback, which becomes a
+`psum` over the node-axis (see parallel/sharded.py).
 
 Numeric ranges (32-bit budget):
   per-service counts clamped to 2^20; failure down-weight factor 2^22
-  (dominates any real count); water-level search over [0, 2^30); node index
+  (dominates any real count); water levels lie in [0, 2^30]; node index
   packed in 20 bits -> supports up to 2^20 (~1M) nodes per shard; group size
   k clamped to 2^22 (the planner falls back to the host path above that).
+
+The searches (`_bisect`, `waterfill_search`, `packfill_search`).  A search
+starts from the bracket its inputs give, per segment, and stops at
+``lo == hi``.  The reference is the plain form: 34 steps over [0, 2^30]
+(levels) or [-1, 2^30] (keys), whatever the inputs, kept verbatim in
+tests/test_waterfill_search.py.  The placements are bit-identical to it,
+because the bracket always holds what that form finds or something that
+places the same:
+  (a) feasible segment (sum of cap >= k).  At λ = max e + k every row
+      with room fills min(k + (max e - e_i), cap_i) >= min(k, cap_i), so
+      the fill reaches k and the minimal λ lies in [min e, max e + k]
+      (min, max over the rows with room: the others fill nothing).
+      Below min e + 1 nothing fills, so for k >= 1 the lower end is safe.
+  (b) infeasible segment (sum of cap < k).  The 34-step form ends at
+      λ = 2^30 with x_base = cap and no marginal row, so x = cap.  The
+      bracket's top gives λ = max e + k: λ - 1 - e_i >= k - 1 >= cap_i
+      (every cap_i <= sum of cap < k), so x_base = cap again, no row is
+      marginal and no grant is made.
+  (c) k = 0 (a fused run's padded slot) or no row with room.  The bracket
+      is closed at λ = 0, where the 34-step form ends too when k = 0;
+      with no room x = 0 at any λ.  No step is taken.
+  (d) the tie threshold.  r > 0 implies at least r marginal rows (the
+      fill grows by one per marginal row from λ - 1 to λ and reaches k
+      there), so the r-th smallest of their keys is the threshold and
+      [min, max] of the marginal rows' keys holds it; with r = 0 or no
+      marginal row no grant is made whatever the threshold.  The
+      pack-fill's threshold is likewise the key of a row with room, or
+      one past the largest of them where the segment cannot hold k, which
+      places what 2^30 placed: every row below it takes its cap.
+  (e) int32.  For spread, max e + k < 2^28 + 2^20 + 2^22 < 2^30, also
+      under the fused path's `enable_x64` (e, cap, k and the brackets are
+      int32 throughout).  The weighted and learned scores have their own
+      clamps; the top is cut at 2^30 as before and formed as
+      min(max e, 2^30 - k) + k, which cannot wrap.
+A caller that hands in a ``reduce`` (the sharded twins) sees only its
+shard's rows, and a bracket from them would differ between shards: it
+keeps the static brackets, closed where k = 0 or r = 0 (both replicated),
+and gains the convergence exit alone; ``lo`` and ``hi`` follow from
+reduced sums, so the loop's predicate is the same on every shard.
+What a step costs follows the static L: see `MASK_FORM_MAX_L`.
 
 Resource accounting is **exact**: the host densifier compares int64
 nano-cpus/bytes and floor-divides in int64 (matching the reference's integer
@@ -41,7 +84,9 @@ computed in float32, which is safe *for comparisons against k <= K_CLAMP*:
 all addends are non-negative, so every partial sum <= the true total; totals
 < 2^24 are therefore exact at every step, and totals >= 2^24 keep enough
 relative accuracy (error ~ N*eps) to stay far above K_CLAMP = 2^22 — either
-way the `sum >= k` comparison is decided correctly.
+way the `sum >= k` comparison is decided correctly, in whatever order the
+addends are taken (a scatter-add, a plain reduction, a reduction over the
+membership mask).
 """
 
 from __future__ import annotations
@@ -66,8 +111,12 @@ LOAD_CLAMP = (1 << 24) - 1   # branch-load clamp: the f32 segment sums are
                              # exact below 2^24, so clamping there keeps
                              # stage-A branch ordering exact; branches with
                              # >16.7M tasks of one service are equi-preferred
-LEVEL_ITERS = 34         # binary search over [0, 2^30]; extra margin
-TIE_ITERS = 34           # binary search over packed 31-bit tie keys
+LEVEL_TOP = 1 << 30      # ceiling of the water-level search's bracket
+KEY_TOP = 1 << 30        # tie / pack keys are < 2^30: above every key
+SEARCH_STEPS_MAX = 31    # ceil(log2(2^30 + 2)): the widest bracket a
+                         # search can start from; most take far fewer
+_I32_MAX = (1 << 31) - 1
+_I32_MIN = -(1 << 31)
 IDX_BITS = 20
 TOTAL_CLAMP = (1 << 10) - 1   # total-tasks clamp: tie keys stay < 2^30 so
                               # the threshold search range fits in int32
@@ -117,11 +166,164 @@ class NodeInputs(NamedTuple):
     quota_ok: Optional[jnp.ndarray] = None   # bool[N] or None
 
 
+def _member(seg: jnp.ndarray, L: int) -> jnp.ndarray:
+    """bool[N, L]: row i lies in segment l.  Never stored: the compiler
+    fuses the compare into the reduction that reads it."""
+    return seg[:, None] == jnp.arange(L, dtype=seg.dtype)[None, :]
+
+
+# How a per-segment quantity is taken over the rows follows the static
+# L (measured on one TPU v5 lite, PERF.md §6 PR 33: a scatter-add or a
+# gather over N rows is a serial walk of them, 9-17 ns a row):
+#   L == 1             one segment holds every row: a plain reduction
+#                      and a scalar broadcast
+#   L <= MASK_FORM_MAX_L   reduce over the [N, L] membership mask: 7 ps
+#                      an entry, 9x faster than the scatter at L = 256
+#   above              N * L entries cost more than the walk: scatter
+#                      and gather
+MASK_FORM_MAX_L = 256
+
+
+def _seg_reduce(x: jnp.ndarray, seg: jnp.ndarray, L: int, *, over, scatter,
+                fill) -> jnp.ndarray:
+    """[L] reduction (``over``: jnp.sum / min / max) of x i32|f32[N] by
+    segment; ``fill`` is the reduction's identity, ``scatter`` its
+    jax.ops form."""
+    if L == 1:
+        return over(x).reshape(1)
+    if L <= MASK_FORM_MAX_L:
+        return over(jnp.where(_member(seg, L), x[:, None], fill), axis=0)
+    return scatter(x, seg, num_segments=L)
+
+
 def _seg_sum_f32(x: jnp.ndarray, seg: jnp.ndarray, L: int) -> jnp.ndarray:
     """int32 segment sum carried in f32 so totals up to N*k (~2^42) cannot
     wrap.  Safe for comparisons against bounds <= K_CLAMP — see module
-    docstring for the exactness argument."""
-    return jax.ops.segment_sum(x.astype(jnp.float32), seg, num_segments=L)
+    docstring for the exactness argument, which holds in any order of
+    summation."""
+    return _seg_reduce(x.astype(jnp.float32), seg, L, over=jnp.sum,
+                       scatter=jax.ops.segment_sum, fill=0.0)
+
+
+def _of_row(v_seg: jnp.ndarray, seg: jnp.ndarray, L: int) -> jnp.ndarray:
+    """Each row's entry of a per-segment vector ([N] from [L]); a row
+    whose segment id is no segment reads 0 in the mask form."""
+    if L == 1:
+        return v_seg[0]
+    if L <= MASK_FORM_MAX_L:
+        # one entry of each row of the mask is set: the sum is exact
+        return jnp.sum(jnp.where(_member(seg, L), v_seg[None, :], 0),
+                       axis=1, dtype=v_seg.dtype)
+    return v_seg[seg]
+
+
+def _seg_total(fn: Callable, v_seg: jnp.ndarray, rows: Tuple,
+               seg: jnp.ndarray, L: int) -> jnp.ndarray:
+    """What a search step needs, f32[L]: per segment, the sum over its
+    rows of ``fn(v, *rows)`` with v the segment's entry of ``v_seg`` —
+    ``_seg_sum_f32(fn(_of_row(v_seg), *rows))`` in one pass over the
+    mask, so a step neither gathers nor scatters.  ``fn`` is
+    elementwise and non-negative."""
+    if L == 1 or L > MASK_FORM_MAX_L:
+        return _seg_sum_f32(fn(_of_row(v_seg, seg, L), *rows), seg, L)
+    vals = fn(v_seg[None, :], *(r[:, None] for r in rows))
+    return jnp.sum(jnp.where(_member(seg, L), vals, 0).astype(jnp.float32),
+                   axis=0)
+
+
+def _key_bracket(live: jnp.ndarray, key: jnp.ndarray, seg: jnp.ndarray,
+                 L: int):
+    """Per segment (has, min, max) of ``key`` over the ``live`` rows;
+    min and max mean nothing where ``has`` is False."""
+    lo = _seg_reduce(jnp.where(live, key, _I32_MAX), seg, L, over=jnp.min,
+                     scatter=jax.ops.segment_min, fill=_I32_MAX)
+    hi = _seg_reduce(jnp.where(live, key, _I32_MIN), seg, L, over=jnp.max,
+                     scatter=jax.ops.segment_max, fill=_I32_MIN)
+    return lo <= hi, lo, hi
+
+
+def _bisect(reaches: Callable[[jnp.ndarray], jnp.ndarray],
+            lo: jnp.ndarray, hi: jnp.ndarray):
+    """Per segment, the least t in [lo, hi) with ``reaches(t)``
+    (monotone in t), or hi where no such t has it.  Returns (t i32[L],
+    steps i32 scalar).  The loop leaves when every segment has
+    ``lo == hi`` — ceil(log2(hi - lo + 1)) steps of the widest segment
+    — and a segment that got there does not move again."""
+    def step(state):
+        lo, hi, n = state
+        mid = lo + (hi - lo) // 2   # avoids int32 overflow of lo + hi
+        ge = reaches(mid)
+        open_ = lo < hi
+        return (jnp.where(open_ & ~ge, mid + 1, lo),
+                jnp.where(open_ & ge, mid, hi), n + 1)
+
+    _, hi, n = jax.lax.while_loop(
+        lambda state: jnp.any(state[0] < state[1]), step,
+        (lo, hi, jnp.zeros((), jnp.int32)))
+    return hi, n
+
+
+def waterfill_search(e: jnp.ndarray, cap: jnp.ndarray, tie: jnp.ndarray,
+                     k_seg: jnp.ndarray, seg: jnp.ndarray, L: int,
+                     reduce: Reduce = _identity):
+    """``seg_waterfill`` with its two trip counts: (x i32[N], level
+    steps, tie steps).  The counts are what the tests pin; the jitted
+    programs return x alone and the compiler drops the counters."""
+    e = e.astype(jnp.int32)
+    cap = cap.astype(jnp.int32)
+    k_seg = k_seg.astype(jnp.int32)
+    kf = k_seg.astype(jnp.float32)
+    from_data = reduce is _identity
+
+    def fill(lam, e_rows, cap_rows):
+        return jnp.clip(lam - e_rows, 0, cap_rows)
+
+    # level bracket (module docstring, "The searches"): [min e, max e +
+    # k] over the rows with room; closed at 0 where nothing is asked or
+    # nothing has room
+    want = k_seg > 0
+    if from_data:
+        has, e_min, e_max = _key_bracket(cap > 0, e, seg, L)
+        want = want & has
+        top = jnp.minimum(e_max, LEVEL_TOP - k_seg) + k_seg  # no wrap
+        hi = jnp.where(want, top, 0)
+        lo = jnp.where(want, jnp.clip(e_min, 0, hi), 0)
+    else:
+        hi = jnp.where(want, LEVEL_TOP, 0).astype(jnp.int32)
+        lo = jnp.zeros_like(hi)
+    # minimal λ with fill ≥ k (or the bracket's top if infeasible)
+    lam, level_steps = _bisect(
+        lambda mid: reduce(_seg_total(fill, mid, (e, cap), seg, L)) >= kf,
+        lo, hi)
+
+    lam_row = _of_row(lam, seg, L)
+    x_base = fill(lam_row - 1, e, cap)
+    f_base = reduce(_seg_sum_f32(x_base, seg, L))
+    # remainder is exact: whenever r > 0, f_base < k <= K_CLAMP < 2^24
+    r = jnp.maximum(kf - f_base, 0.0)
+
+    marginal = (e <= lam_row - 1) & (x_base < cap)
+
+    # threshold search: per segment, the r-th smallest tie key among
+    # marginals — one of their keys, so their range is the bracket
+    want = r > 0
+    if from_data:
+        has, t_min, t_max = _key_bracket(marginal, tie, seg, L)
+        want = want & has
+        tlo = jnp.where(want, t_min, 0)
+        thi = jnp.where(want, t_max, 0)
+    else:
+        tlo = jnp.where(want, -1, 0).astype(jnp.int32)
+        thi = jnp.where(want, KEY_TOP, 0).astype(jnp.int32)
+    thr, tie_steps = _bisect(
+        lambda mid: reduce(_seg_total(
+            lambda t, m, tie: (m & (tie <= t)).astype(jnp.int32),
+            mid, (marginal, tie), seg, L)) >= r,
+        tlo, thi)
+    grant = marginal & (tie <= _of_row(thr, seg, L)) \
+        & (_of_row(r, seg, L) > 0)
+
+    return x_base + grant.astype(jnp.int32), level_steps, tie_steps
 
 
 def seg_waterfill(e: jnp.ndarray, cap: jnp.ndarray, tie: jnp.ndarray,
@@ -133,53 +335,14 @@ def seg_waterfill(e: jnp.ndarray, cap: jnp.ndarray, tie: jnp.ndarray,
     grants the remainder one-by-one to marginal nodes in ``tie`` order.
 
     e:    i32[N] current level per element (lower = preferred)
-    cap:  i32[N] max units this element can take
-    tie:  i32[N] tie-break key, unique per element (lower = preferred)
+    cap:  i32[N] max units this element can take (>= 0)
+    tie:  i32[N] tie-break key in [0, 2^30), unique per element (lower =
+          preferred)
     k_seg:i32[L] units to place per segment (each <= K_CLAMP)
-    seg:  i32[N] segment id per element
+    seg:  i32[N] segment id per element (all 0 where L == 1)
     reduce: cross-shard sum for [L]-shaped partials (psum under shard_map)
     """
-    e = e.astype(jnp.int32)
-    cap = cap.astype(jnp.int32)
-    kf = k_seg.astype(jnp.float32)
-
-    def fill_at(lam_seg: jnp.ndarray) -> jnp.ndarray:
-        return jnp.clip(lam_seg[seg] - e, 0, cap)
-
-    def level_body(_, lohi):
-        lo, hi = lohi
-        mid = lo + (hi - lo) // 2   # avoids int32 overflow of lo + hi
-        f = reduce(_seg_sum_f32(fill_at(mid), seg, L))
-        ge = f >= kf
-        return jnp.where(ge, lo, mid + 1), jnp.where(ge, mid, hi)
-
-    lo = jnp.zeros((L,), jnp.int32)
-    hi = jnp.full((L,), 1 << 30, jnp.int32)
-    lo, hi = jax.lax.fori_loop(0, LEVEL_ITERS, level_body, (lo, hi))
-    lam = hi  # minimal λ with fill ≥ k (or 2^30 if capacity-infeasible)
-
-    x_base = fill_at(lam - 1)
-    f_base = reduce(_seg_sum_f32(x_base, seg, L))
-    # remainder is exact: whenever r > 0, f_base < k <= K_CLAMP < 2^24
-    r = jnp.maximum(kf - f_base, 0.0)
-
-    marginal = (e <= lam[seg] - 1) & (x_base < cap)
-
-    # threshold search: per segment, the r-th smallest tie key among marginals
-    def tie_body(_, lohi):
-        lo, hi = lohi
-        mid = lo + (hi - lo) // 2   # avoids int32 overflow of lo + hi
-        cnt = reduce(_seg_sum_f32(
-            (marginal & (tie <= mid[seg])).astype(jnp.int32), seg, L))
-        ge = cnt >= r
-        return jnp.where(ge, lo, mid + 1), jnp.where(ge, mid, hi)
-
-    tlo = jnp.full((L,), -1, jnp.int32)
-    thi = jnp.full((L,), 1 << 30, jnp.int32)  # tie keys are < 2^30
-    tlo, thi = jax.lax.fori_loop(0, TIE_ITERS, tie_body, (tlo, thi))
-    grant = marginal & (tie <= thi[seg]) & (r[seg] > 0)
-
-    return x_base + grant.astype(jnp.int32)
+    return waterfill_search(e, cap, tie, k_seg, seg, L, reduce)[0]
 
 
 def _hash_eq(node_hash: jnp.ndarray, exp: jnp.ndarray) -> jnp.ndarray:
@@ -425,40 +588,55 @@ class StrategyInputs(NamedTuple):
     b2: jnp.ndarray       # i32[] scalar
 
 
+def packfill_search(key: jnp.ndarray, cap: jnp.ndarray,
+                    k_seg: jnp.ndarray, seg: jnp.ndarray, L: int,
+                    reduce: Reduce = _identity):
+    """``seg_packfill`` with its trip count: (x i32[N], steps)."""
+    cap = cap.astype(jnp.int32)
+    k_seg = k_seg.astype(jnp.int32)
+    kf = k_seg.astype(jnp.float32)
+
+    # the threshold is the key of a row with room, or one past the last
+    # of them where the segment cannot hold k; closed at -1, under every
+    # key, where nothing is asked or nothing has room
+    want = k_seg > 0
+    if reduce is _identity:
+        has, k_min, k_max = _key_bracket(cap > 0, key, seg, L)
+        want = want & has
+        lo = jnp.where(want, k_min, -1)
+        hi = jnp.where(want, k_max + 1, -1)
+    else:
+        lo = jnp.full_like(k_seg, -1)
+        hi = jnp.where(want, KEY_TOP, -1).astype(jnp.int32)
+    # minimal key threshold with fill >= k (the bracket's top: infeasible)
+    thr, steps = _bisect(
+        lambda mid: reduce(_seg_total(
+            lambda t, key, cap: jnp.where(key <= t, cap, 0),
+            mid, (key, cap), seg, L)) >= kf,
+        lo, hi)
+
+    thr_row = _of_row(thr, seg, L)
+    x = jnp.where(key < thr_row, cap, 0)
+    f = reduce(_seg_sum_f32(x, seg, L))
+    # remainder is exact: whenever r > 0, f < k <= K_CLAMP < 2^24
+    r_row = _of_row(jnp.maximum(kf - f, 0.0), seg, L)
+    # keys are unique, so at most one element per segment sits AT the
+    # threshold; by minimality of thr its capacity covers r
+    grant = (key == thr_row) & (r_row > 0.0)
+    return x + jnp.where(grant, jnp.minimum(
+        cap, r_row.astype(jnp.int32)), 0), steps
+
+
 def seg_packfill(key: jnp.ndarray, cap: jnp.ndarray,
                  k_seg: jnp.ndarray, seg: jnp.ndarray, L: int,
                  reduce: Reduce = _identity) -> jnp.ndarray:
     """Sequential (pack) fill within each segment: nodes take their
     full capacity in ascending ``key`` order until k is placed — the
-    binpack placement primitive.  Keys must be unique per segment
-    (callers pack the node index into the low bits).  Same
-    threshold-search shape as seg_waterfill's tie stage, so it runs
-    under shard_map with the identical ``reduce`` contract."""
-    cap = cap.astype(jnp.int32)
-    kf = k_seg.astype(jnp.float32)
-
-    def body(_, lohi):
-        lo, hi = lohi
-        mid = lo + (hi - lo) // 2   # avoids int32 overflow of lo + hi
-        cnt = reduce(_seg_sum_f32(
-            jnp.where(key <= mid[seg], cap, 0), seg, L))
-        ge = cnt >= kf
-        return jnp.where(ge, lo, mid + 1), jnp.where(ge, mid, hi)
-
-    lo = jnp.full((L,), -1, jnp.int32)
-    hi = jnp.full((L,), 1 << 30, jnp.int32)  # keys are < 2^30
-    lo, hi = jax.lax.fori_loop(0, TIE_ITERS, body, (lo, hi))
-    thr = hi   # minimal key threshold with fill >= k (2^30 infeasible)
-
-    x = jnp.where(key < thr[seg], cap, 0)
-    f = reduce(_seg_sum_f32(x, seg, L))
-    # remainder is exact: whenever r > 0, f < k <= K_CLAMP < 2^24
-    r = jnp.maximum(kf - f, 0.0)
-    # keys are unique, so at most one element per segment sits AT the
-    # threshold; by minimality of thr its capacity covers r
-    grant = (key == thr[seg]) & (r[seg] > 0.0)
-    return x + jnp.where(grant, jnp.minimum(
-        cap, r[seg].astype(jnp.int32)), 0)
+    binpack placement primitive.  Keys lie in [0, 2^30) and must be
+    unique per segment (callers pack the node index into the low
+    bits).  Same threshold search as seg_waterfill's tie stage, so it
+    runs under shard_map with the identical ``reduce`` contract."""
+    return packfill_search(key, cap, k_seg, seg, L, reduce)[0]
 
 
 def _learned_score(nodes: NodeInputs, sin: StrategyInputs

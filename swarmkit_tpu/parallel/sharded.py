@@ -6,8 +6,13 @@ only cross-node dependencies are the water-level and tie-threshold binary
 searches, whose per-iteration state is an [L]-vector of partial sums — so
 the sharded kernel is the *same code* as the single-chip kernel with the
 segment-sum reductions wrapped in a `psum` over the mesh axis.  Collective
-traffic per group: ~120 psums of an [L]-vector (L = spread-branch count,
-usually 1) — a few KB over ICI, independent of node count.
+traffic per group: at most 2 x 31 psums of an [L]-vector (L = spread-branch
+count, usually 1) for the two searches, plus a handful for branch sums and
+fail counts — a few KB over ICI, independent of node count.  A shard sees
+only its own rows, so under a ``reduce`` the searches keep their static
+brackets (ops/kernel.py, "The searches") and take from the single-chip
+form its exit on convergence: ``lo`` and ``hi`` follow from reduced sums,
+so every shard leaves the loop on the same step.
 
 Design notes vs the reference: SwarmKit scales its scheduler by heap bounds
 and batching in one Go process (design/scheduler.md); there is no
@@ -192,7 +197,10 @@ def plan_fused_sharded(shared: FusedShared, groups: FusedGroups,
     """Sharded fused batch: the same scan-over-groups program as
     ops.kernel.plan_fused with the node axis split over the mesh.
     Cross-shard traffic per group is unchanged from the per-group
-    sharded kernel (~120 psums of an [L]-vector per scan step); the
+    sharded kernel (at most 2 x 31 psums of an [L]-vector per scan
+    step for the node level's two searches, which keep their static
+    brackets under a ``reduce`` and leave on convergence, plus a
+    handful for branch sums and fail counts); the
     carry stays sharded across chunked calls, so chunk i+1 consumes
     chunk i's device-resident state with zero host round-trips.
     ``strat`` fuses binpack/weighted/learned groups into the same

@@ -157,3 +157,26 @@ def test_forced_retreats_become_a_nonzero_exit(monkeypatch, capsys):
     assert re.search(r"breaker (open|half-open) .*'trips': [1-9]", failures)
     assert "native commit plane" in failures
     assert "retreat logged: tpu-planner" in failures
+
+
+def test_plan_programs_are_timed_at_each_bucket_beside_their_pass_line(
+        capsys):
+    """``run_s`` of every plan program, per bucket: the before and after
+    of a change to the device programs (tiny buckets here: a count and
+    the oracles, no speed)."""
+    smoke = chip_smoke.Smoke()
+    chip_smoke.plan_program_times(smoke, buckets=((128, 100), (256, 250)),
+                                  seed=5)
+    assert smoke.failures == []
+    assert [row["program"] for row in smoke.programs] == [
+        f"{name}@nb{nb}" for nb in (128, 256) for name in (
+            "plan_group_jit/flat", "plan_group_jit/hier",
+            "plan_strategy_jit/binpack", "plan_fused_jit/g2",
+            "plan_fused_jit/g4")]
+    assert all(row["ok"] and row["run_s"] >= 0 for row in smoke.programs)
+    lines = [line for line in capsys.readouterr().out.splitlines()
+             if line.startswith("program plan_")]
+    assert len(lines) == 10
+    assert all(re.search(r": PASS compile_s=\S+ wall_s=\S+ run_s=\S+", line)
+               for line in lines)
+    assert [nb for nb, _n in chip_smoke.TIMED_BUCKETS] == [1024, 16384]
