@@ -397,28 +397,31 @@ def _timed_twice(row, fn):
     return out
 
 
-#: (node bucket, nodes in it) of the two cells: the plan programs are
-#: timed at both, on a service seen for the first time
-TIMED_BUCKETS = ((1024, 1000), (16384, 10000))
+#: (node bucket, nodes in it, racks a zone) of the three cells: the plan
+#: programs are timed at each, on a service seen for the first time.
+#: ``harness-100k``'s 250 racks a zone make 1,000 leaves, the 4,096 leaf
+#: bucket, where the tree's searches take their scatter form
+TIMED_BUCKETS = ((1024, 1000, 25), (16384, 10000, 25),
+                 (131072, 100000, 250))
 #: group sizes from the cells' mix (60 % of its services have 1-10
 #: replicas); the last fused slot is padding, as a short run's is
 TIMED_K = {"flat": 10, "hier": 100, "binpack": 30,
            "fused": (10, 100, 3, 0)}
 
 
-def _timed_inputs(nb: int, n: int, seed: int):
+def _timed_inputs(nb: int, n: int, seed: int, racks_per_zone: int):
     """Synthetic columns of a fresh service on ``n`` nodes of the
     ``nb`` bucket: no task of its own anywhere, 0-8 tasks of others a
-    node, 64 CPU / 256 GiB free, 4 zones of 25 racks dealt round-robin.
-    Returns (NodeInputs for a flat group, group_of(k), zone i32[nb],
-    rack i32[nb])."""
+    node, 64 CPU / 256 GiB free, 4 zones of ``racks_per_zone`` racks
+    dealt round-robin.  Returns (NodeInputs for a flat group,
+    group_of(k), zone i32[nb], rack i32[nb])."""
     from swarmkit_tpu.ops.kernel import GroupInputs, NodeInputs
     rng = np.random.default_rng([seed, nb])
     i32 = np.int32
     valid = np.arange(nb) < n
     zone = np.where(valid, np.arange(nb) % ZONES, 0).astype(i32)
-    rack = np.where(valid, zone * RACKS_PER_ZONE
-                    + (np.arange(nb) // ZONES) % RACKS_PER_ZONE,
+    rack = np.where(valid, zone * racks_per_zone
+                    + (np.arange(nb) // ZONES) % racks_per_zone,
                     0).astype(i32)
     nodes = NodeInputs(
         valid=valid, ready=valid, res_ok=valid,
@@ -448,8 +451,9 @@ def plan_program_times(smoke, buckets=TIMED_BUCKETS, seed: int = 0) -> None:
     """Each plan program called directly, twice, on a fresh service at
     each of ``buckets``: ``run_s`` on its PASS line is the program's run
     time with the result fetched, what ``plan.d2h`` waits for.  Held to
-    the host mirrors (flat, binpack), to the tree's balance (hier) and
-    to the per-group programs applied in order (fused)."""
+    the host mirrors (flat, binpack), to the tree's balance (hier, at
+    the leaf bucket the bucket's racks a zone give) and to the per-group
+    programs applied in order (fused)."""
     from swarmkit_tpu.ops import fusedbatch
     from swarmkit_tpu.ops.kernel import (
         FusedCarry, FusedGroups, FusedShared, FusedStrategy, StrategyInputs,
@@ -459,9 +463,12 @@ def plan_program_times(smoke, buckets=TIMED_BUCKETS, seed: int = 0) -> None:
     i32, i64 = np.int32, np.int64
     w1, b1, w2, b2 = (np.asarray(a, i32)
                       for a in strategy_mod.learned_params())
-    for nb, n in buckets:
-        nodes, group_of, zone, rack = _timed_inputs(nb, n, seed)
+    for nb, n, racks_per_zone in buckets:
+        nodes, group_of, zone, rack = _timed_inputs(nb, n, seed,
+                                                    racks_per_zone)
         zeros = np.zeros(nb, i32)
+        racks_in_all = ZONES * racks_per_zone
+        leaves = fusedbatch.l_bucket(racks_in_all)
 
         with smoke.program(f"plan_group_jit/flat@nb{nb}") as row:
             k = TIMED_K["flat"]
@@ -476,13 +483,13 @@ def plan_program_times(smoke, buckets=TIMED_BUCKETS, seed: int = 0) -> None:
         with smoke.program(f"plan_group_jit/hier@nb{nb}") as row:
             k = TIMED_K["hier"]
             hier = (((zone, np.zeros(16, i32)),),
-                    (np.arange(256) // RACKS_PER_ZONE
+                    (np.arange(leaves) // racks_per_zone
                      % 16).astype(i32))
             x, _fc, _spill = _timed_twice(
                 row, lambda: plan_group_jit(
-                    nodes._replace(leaf=rack), group_of(k), 256, hier))
+                    nodes._replace(leaf=rack), group_of(k), leaves, hier))
             x = np.asarray(x)
-            racks = np.bincount(rack, x, ZONES * RACKS_PER_ZONE)
+            racks = np.bincount(rack, x, racks_in_all)
             smoke.check(
                 x.sum() == k and within_one(racks)
                 and within_one(racks.reshape(ZONES, -1).sum(1))
@@ -948,7 +955,7 @@ class _RetreatLog(logging.Handler):
 
 def run(device: dict, n_nodes: int = N_NODES, n_agents: int = N_AGENTS,
         replicas: int = REPLICAS, seed: int = 0,
-        timeout: float = 600.0) -> int:
+        timeout: float = 600.0, timed_buckets=TIMED_BUCKETS) -> int:
     """Every phase, then the verdict; returns the exit code."""
     from swarmkit_tpu.utils.compilecache import ensure_compile_cache
     smoke = Smoke()
@@ -989,7 +996,7 @@ def run(device: dict, n_nodes: int = N_NODES, n_agents: int = N_AGENTS,
             print(f"launch_overhead_s={overhead}", flush=True)
         with smoke.phase("programs"):
             programs_phase(smoke, n_nodes, max(replicas // 5, 1), seed)
-            plan_program_times(smoke, seed=seed)
+            plan_program_times(smoke, timed_buckets, seed)
         with smoke.phase("served"):
             served = served_phase(smoke, n_nodes, n_agents, replicas,
                                   seed, timeout)

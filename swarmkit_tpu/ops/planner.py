@@ -69,6 +69,12 @@ from .kernel import (
 
 log = logging.getLogger("tpu-planner")
 
+#: a spread tree with more leaves at its last level than this is a wide
+#: tree (``stats["wide_tree_groups"]`` / ``["wide_tree_s"]``): a property
+#: of the service's preferences and the cluster's labels.  A rung of the
+#: leaf ladder (``fusedbatch.l_bucket``), so the leaf bucket tells it
+WIDE_TREE_LEAVES = 256
+
 # cached Timer references (Registry.reset() resets in place)
 _PLAN_TIMER = _metrics.timer("swarm_planner_plan_latency")
 _COMPILE_TIMER = _metrics.timer("swarm_planner_compile_latency")
@@ -358,6 +364,8 @@ class TPUPlanner:
                       "groups_small_to_host": 0, "route_switches": 0,
                       "tree_cols_hits": 0, "tree_cols_builds": 0,
                       "tree_cols_invalidations": 0,
+                      "h2d_bytes": 0, "d2h_bytes": 0,
+                      "wide_tree_groups": 0, "wide_tree_s": 0.0,
                       "tasks_planned": 0, "plan_seconds": 0.0}
         # the break-even router's two sides (_route_costs): the measured
         # fixed launch overhead (dispatch + D2H round-trip on a minimal
@@ -440,6 +448,38 @@ class TPUPlanner:
         self.stats["plan_seconds"] += dt
         _PLAN_TIMER.observe(dt)
 
+    def _note_h2d(self, reason: str, operands, sp=None) -> int:
+        """Host arrays handed to a device program: their bytes into
+        ``stats["h2d_bytes"]`` (whether or not the telemetry ledger is
+        enabled), into the ledger under ``reason`` and onto the
+        ``plan.dispatch`` span ``sp``."""
+        nbytes = _devtel.tree_nbytes(operands)
+        self._count("h2d_bytes", nbytes)
+        _devtel.note_h2d(reason, nbytes)
+        if sp is not None:
+            sp.args["h2d_bytes"] = nbytes
+        return nbytes
+
+    def _note_d2h(self, reason: str, fetched) -> None:
+        """Results fetched outside ``fetch_plan``: their bytes into
+        ``stats["d2h_bytes"]`` and into the ledger under ``reason``."""
+        nbytes = _devtel.tree_nbytes(fetched)
+        self._count("d2h_bytes", nbytes)
+        _devtel.note_d2h(reason, nbytes)
+
+    def _fetch(self, arrays, sp=None):
+        """``fetch_plan`` with the fetched bytes counted into
+        ``stats["d2h_bytes"]`` and put on the ``plan.d2h`` span ``sp``.
+        The seam counts the ledger's share itself and hands back only
+        the arrays (``benchmark/control.py`` stands in for it under that
+        signature), so the three results' ``nbytes`` are read again."""
+        out = fetch_plan(arrays)
+        nbytes = _devtel.tree_nbytes(out)
+        self._count("d2h_bytes", nbytes)
+        if sp is not None:
+            sp.args["d2h_bytes"] = nbytes
+        return out
+
     @staticmethod
     def _note_inflight(dt: float) -> None:
         """Retroactive ``plan.inflight`` span covering one plan's whole
@@ -457,7 +497,7 @@ class TPUPlanner:
         tracer.record_complete("plan.inflight", "plan",
                                0.0 if time_source_installed() else dt)
 
-    def _call_plan_fn(self, nodes_in, group_in, L, hier):
+    def _call_plan_fn(self, nodes_in, group_in, L, hier, sp=None):
         """Every device-plan dispatch goes through here so XLA cache
         misses are *observed* per static shape bucket (jit cache-size
         delta around the call), not inferred from timing swings.  The
@@ -466,8 +506,7 @@ class TPUPlanner:
         numpy->device transfer at the jit boundary)."""
         import time as _time
         bucket = _bucket_label(nodes_in, group_in, L, hier)
-        _devtel.note_h2d("group_inputs",
-                         _devtel.tree_nbytes((nodes_in, group_in, hier)))
+        self._note_h2d("group_inputs", (nodes_in, group_in, hier), sp)
         before = _jit_cache_size(self._plan_fn)
         t0 = _time.perf_counter()
         out = self._plan_fn(nodes_in, group_in, L, hier)
@@ -478,15 +517,14 @@ class TPUPlanner:
                             node_rows=nodes_in.valid.shape[0])
         return out
 
-    def _call_strategy_fn(self, nodes_in, group_in, sin, sinfo):
+    def _call_strategy_fn(self, nodes_in, group_in, sin, sinfo, sp=None):
         """Strategy-kernel dispatch twin of ``_call_plan_fn``: same
         compile observation, per-strategy bucket suffix (each static
         strategy id is its own jit signature)."""
         import time as _time
         bucket = (_bucket_label(nodes_in, group_in, 1, ())
                   + f"_st{sinfo.sid}")
-        _devtel.note_h2d("group_inputs",
-                         _devtel.tree_nbytes((nodes_in, group_in, sin)))
+        self._note_h2d("group_inputs", (nodes_in, group_in, sin), sp)
         sfn = getattr(self._plan_fn, "strategy", None)
         probe = self._strategy_jit_probe()
         before = _jit_cache_size(probe)
@@ -750,8 +788,7 @@ class TPUPlanner:
             probe_out = _jax.device_get(
                 self._call_plan_fn(nodes_in, group_in, 1, ()))
             self._launch_overhead = _time.perf_counter() - t0
-            _devtel.note_d2h("probe",
-                             2 * _devtel.tree_nbytes(probe_out))
+            self._note_d2h("probe", (probe_out, probe_out))
             # only successful measurements are shared: caching a failed
             # probe (0.0) would poison every future planner's break-even
             cls._launch_overhead_shared = self._launch_overhead
@@ -996,14 +1033,14 @@ class TPUPlanner:
         try:
             with tracer.span("plan.dispatch", "plan", tasks=k,
                              service=t.service_id, label=bucket,
-                             route=route):
+                             route=route) as sp:
                 if flat:
                     sin = self._build_strategy_inputs(built, t, sinfo)
                     arrays = self._call_strategy_fn(nodes_in, group_in,
-                                                    sin, sinfo)
+                                                    sin, sinfo, sp)
                 else:
                     arrays = self._call_plan_fn(nodes_in, group_in, L,
-                                                hier)
+                                                hier, sp)
         except Exception:
             # device dispatch failure degrades THIS group to the host
             # path and feeds the breaker — a sick device trips to
@@ -1016,6 +1053,11 @@ class TPUPlanner:
             return None
         if flat:
             strategy_mod.count_group(sinfo.name, "device")
+        if L > WIDE_TREE_LEAVES:
+            # the scheduler thread's wall on the group so far: the
+            # densify and the launch (fetch_group adds the wait)
+            self._count("wide_tree_groups")
+            self.stats["wide_tree_s"] += _time.perf_counter() - _plan_t0
         handle = _InFlightPlan(sched, t, task_group, decisions, built,
                                _plan_t0, arrays, bucket=bucket,
                                route=route)
@@ -1346,15 +1388,13 @@ class TPUPlanner:
                              service=t.service_id, label=_feas_bucket,
                              route="feasibility"):
                 _cache_before = _jit_cache_size(feasibility_jit)
-                _devtel.note_h2d("group_inputs",
-                                 _devtel.tree_nbytes((nodes_in, group_in)))
+                self._note_h2d("group_inputs", (nodes_in, group_in))
                 _feas_t0 = _time.perf_counter()
                 _fetched = _jax.device_get(
                     feasibility_jit(nodes_in, group_in))
                 _feas_dt = _time.perf_counter() - _feas_t0
                 mask, cap, _ = _fetched
-                _devtel.note_d2h("feasibility",
-                                 _devtel.tree_nbytes(_fetched))
+                self._note_d2h("feasibility", _fetched)
                 _comp = _observe_compile(feasibility_jit, _feas_bucket,
                                          _cache_before, _feas_dt)
                 _devtel.note_kernel(_feas_bucket, "feasibility",
@@ -1435,8 +1475,9 @@ class TPUPlanner:
         # one round-trip for all outputs: each fetch is a host sync
         _d2h_t0 = _time.perf_counter()
         try:
-            with tracer.span("plan.d2h", "plan", service=t.service_id):
-                x, fail_counts, spill = fetch_plan(handle.arrays)
+            with tracer.span("plan.d2h", "plan", service=t.service_id,
+                             label=handle.bucket) as sp:
+                x, fail_counts, spill = self._fetch(handle.arrays, sp)
         except Exception:
             # fetch failure: the plan is lost but the group is not — it
             # re-runs through the host oracle (return False), and the
@@ -1452,6 +1493,8 @@ class TPUPlanner:
         # the d2h wait IS the device plane's busy window: the host is
         # stalled on the accelerator, which is what saturation means here
         _d2h_dt = _time.perf_counter() - _d2h_t0
+        if L > WIDE_TREE_LEAVES:
+            self.stats["wide_tree_s"] += _d2h_dt
         _planes.plane(_planes.DEVICE).note_busy(_d2h_dt)
         if handle.bucket:
             # the fetch half of this plan's kernel-ledger row (bytes
@@ -1590,8 +1633,7 @@ class TPUPlanner:
         if self.breaker.allow_device():
             try:
                 before = _jit_cache_size(gang_fit_jit)
-                _devtel.note_h2d("gang_inputs",
-                                 _devtel.tree_nbytes((nodes_in, group_in)))
+                self._note_h2d("gang_inputs", (nodes_in, group_in))
                 t0 = _time.perf_counter()
                 with tracer.span("plan.gang_fit", "plan",
                                  k=int(group_in.k), label=bucket,
@@ -1655,8 +1697,8 @@ class TPUPlanner:
                     np.stack([getattr(r[2], f) for r in rows])
                     for f in GroupInputs._fields])
                 before = _jit_cache_size(gang_fit_fused_jit)
-                _devtel.note_h2d("gang_inputs", _devtel.tree_nbytes(
-                    (stacked_nodes, stacked_groups)))
+                self._note_h2d("gang_inputs",
+                               (stacked_nodes, stacked_groups))
                 t0 = _time.perf_counter()
                 with tracer.span("plan.gang_fit_fused", "plan",
                                  gangs=len(rows), label=label + "_gfF",
@@ -1808,18 +1850,15 @@ class TPUPlanner:
             _devtel.note_bytes_avoided(_devtel.tree_nbytes(
                 (shared.valid, shared.ready, carry.total, carry.cpu,
                  carry.mem)))
-            _devtel.note_h2d("cold_build", _devtel.tree_nbytes(
-                (shared.os_hash, shared.arch_hash, shared.svc0,
-                 carry.svc_acc)))
+            self._note_h2d("cold_build", (shared.os_hash, shared.arch_hash,
+                                          shared.svc0, carry.svc_acc))
             return (FusedShared(valid=d_valid, ready=d_ready,
                                 os_hash=jnp.asarray(shared.os_hash),
                                 arch_hash=jnp.asarray(shared.arch_hash),
                                 svc0=jnp.asarray(shared.svc0)),
                     FusedCarry(total=d_total, cpu=d_cpu, mem=d_mem,
                                svc_acc=jnp.asarray(carry.svc_acc)))
-        _devtel.note_h2d("cold_build",
-                         _devtel.tree_nbytes((tuple(shared),
-                                              tuple(carry))))
+        self._note_h2d("cold_build", (tuple(shared), tuple(carry)))
         return (FusedShared(*(jnp.asarray(a) for a in shared)),
                 FusedCarry(*(jnp.asarray(a) for a in carry)))
 
@@ -1853,14 +1892,14 @@ class TPUPlanner:
             bucket = run.bucket_label(c)
             probe = self._fused_jit_probe()
             before = _jit_cache_size(probe)
-            _devtel.note_h2d("fused_inputs",
-                             _devtel.tree_nbytes((c.groups, c.strat)))
+            h2d = self._note_h2d("fused_inputs", (c.groups, c.strat))
             c.t0 = _time.perf_counter()
             try:
                 with tracer.span("plan.dispatch", "plan", tasks=c.tasks,
                                  fused_groups=c.count,
                                  service=run.specs[c.start].t.service_id,
-                                 label=bucket, route="fused"):
+                                 label=bucket, route="fused",
+                                 h2d_bytes=h2d):
                     with fusedbatch.x64():
                         fn = (self._fused_fn.fused
                               if self._fused_fn is not None
@@ -1904,8 +1943,9 @@ class TPUPlanner:
         _d2h_t0 = _time.perf_counter()
         try:
             with tracer.span("plan.d2h", "plan", fused_groups=c.count,
-                             service=run.specs[c.start].t.service_id):
-                xs, fcs, spills = fetch_plan(c.arrays)
+                             service=run.specs[c.start].t.service_id,
+                             label=run.bucket_label(c)) as sp:
+                xs, fcs, spills = self._fetch(c.arrays, sp)
         except Exception:
             log.exception("fused fetch failed; remaining groups ride "
                           "the per-group path")

@@ -165,7 +165,8 @@ class ResidentState:
         #: planner._node_value — constraint-key lookup per NodeInfo
         self._node_value = node_value
         #: planner._count — the resident tier's events that the planner
-        #: accounts for (``tree_cols_*``) go through its one counter sink
+        #: accounts for (``tree_cols_*``, the ``h2d_bytes`` of the device
+        #: tier's uploads and scatters) go through its one counter sink
         self._count = count or (lambda key, delta=1: None)
         #: planner mesh (parallel/sharded.py) — when set and the node
         #: bucket divides evenly over it, the device tier lives as
@@ -674,13 +675,19 @@ class ResidentState:
             return
         # host nbytes == device nbytes here (jnp.asarray copies the
         # host columns wholesale under the x64 guard)
-        _devtel.note_h2d(reason, _devtel.tree_nbytes(
+        self._note_h2d(reason, _devtel.tree_nbytes(
             (self.valid, self.ready, self.cpu, self.mem, self.total)))
         _devtel.set_watermark("device_resident",
                               _devtel.tree_nbytes(self.dev))
         self.stats["device_syncs"] += 1
         self._dev_version = self._tracker.version \
             if self._tracker is not None else -1
+
+    def _note_h2d(self, reason: str, nbytes: int) -> None:
+        """An upload of the device tier: into the planner's
+        ``h2d_bytes`` and into the telemetry ledger."""
+        self._count("h2d_bytes", nbytes)
+        _devtel.note_h2d(reason, nbytes)
 
     def _device_sync(self, rows: List[int]) -> None:
         """Scatter dirty rows — plus any host-only backlog — into the
@@ -766,7 +773,7 @@ class ResidentState:
         before = _jit_cache_size(probe)
         staged = _devtel.tree_nbytes(
             (idx, u_valid, u_ready, u_cpu, u_mem, u_total))
-        _devtel.note_h2d(reason, staged)
+        self._note_h2d(reason, staged)
         # what a non-streaming tick would have shipped instead: the
         # full five-column upload, minus what the scatter staged
         full = _devtel.tree_nbytes(

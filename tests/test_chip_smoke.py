@@ -148,7 +148,7 @@ def test_forced_retreats_become_a_nonzero_exit(monkeypatch, capsys):
     monkeypatch.setattr(chip_smoke, "programs_phase",
                         lambda *args: None)
     rc = chip_smoke.run(CPU, n_nodes=64, n_agents=2, replicas=2000,
-                        timeout=120.0)
+                        timeout=120.0, timed_buckets=((128, 100, 25),))
     captured = capsys.readouterr()
     assert rc != 0
     assert '"ok"' not in captured.out
@@ -163,13 +163,14 @@ def test_plan_programs_are_timed_at_each_bucket_beside_their_pass_line(
         capsys):
     """``run_s`` of every plan program, per bucket: the before and after
     of a change to the device programs (tiny buckets here: a count and
-    the oracles, no speed)."""
+    the oracles, no speed; the second has 260 racks, so its tree takes
+    the 4,096 leaf bucket as ``harness-100k``'s does)."""
     smoke = chip_smoke.Smoke()
-    chip_smoke.plan_program_times(smoke, buckets=((128, 100), (256, 250)),
-                                  seed=5)
+    chip_smoke.plan_program_times(
+        smoke, buckets=((128, 100, 25), (2048, 1300, 65)), seed=5)
     assert smoke.failures == []
     assert [row["program"] for row in smoke.programs] == [
-        f"{name}@nb{nb}" for nb in (128, 256) for name in (
+        f"{name}@nb{nb}" for nb in (128, 2048) for name in (
             "plan_group_jit/flat", "plan_group_jit/hier",
             "plan_strategy_jit/binpack", "plan_fused_jit/g2",
             "plan_fused_jit/g4")]
@@ -179,4 +180,6 @@ def test_plan_programs_are_timed_at_each_bucket_beside_their_pass_line(
     assert len(lines) == 10
     assert all(re.search(r": PASS compile_s=\S+ wall_s=\S+ run_s=\S+", line)
                for line in lines)
-    assert [nb for nb, _n in chip_smoke.TIMED_BUCKETS] == [1024, 16384]
+    # the three cells' buckets, the last with harness-100k's wide tree
+    assert chip_smoke.TIMED_BUCKETS == (
+        (1024, 1000, 25), (16384, 10000, 25), (131072, 100000, 250))
