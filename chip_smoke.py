@@ -400,7 +400,8 @@ def _timed_twice(row, fn):
 #: (node bucket, nodes in it, racks a zone) of the three cells: the plan
 #: programs are timed at each, on a service seen for the first time.
 #: ``harness-100k``'s 250 racks a zone make 1,000 leaves, the 4,096 leaf
-#: bucket, where the tree's searches take their scatter form
+#: bucket, where the tree's searches take the dense form on the layout
+#: ``fusedbatch.tree_inputs`` builds (the scatter form before PR 35)
 TIMED_BUCKETS = ((1024, 1000, 25), (16384, 10000, 25),
                  (131072, 100000, 250))
 #: group sizes from the cells' mix (60 % of its services have 1-10
@@ -452,12 +453,17 @@ def plan_program_times(smoke, buckets=TIMED_BUCKETS, seed: int = 0) -> None:
     each of ``buckets``: ``run_s`` on its PASS line is the program's run
     time with the result fetched, what ``plan.d2h`` waits for.  Held to
     the host mirrors (flat, binpack), to the tree's balance (hier, at
-    the leaf bucket the bucket's racks a zone give) and to the per-group
-    programs applied in order (fused)."""
+    the leaf bucket the bucket's racks a zone give; its tree comes from
+    ``fusedbatch.tree_inputs`` as the planner's does, so the line times
+    the form the planner launches, and its node level is held to the
+    scatter-form search, whose two trip counts it prints) and to the
+    per-group programs applied in order (fused)."""
+    import jax
     from swarmkit_tpu.ops import fusedbatch
     from swarmkit_tpu.ops.kernel import (
         FusedCarry, FusedGroups, FusedShared, FusedStrategy, StrategyInputs,
-        plan_fused_jit, plan_group_jit, plan_strategy_jit,
+        plan_fused_jit, plan_group_jit, plan_strategy_jit, search_form,
+        waterfill_search,
     )
     from swarmkit_tpu.scheduler import strategy as strategy_mod
     i32, i64 = np.int32, np.int64
@@ -482,18 +488,33 @@ def plan_program_times(smoke, buckets=TIMED_BUCKETS, seed: int = 0) -> None:
 
         with smoke.program(f"plan_group_jit/hier@nb{nb}") as row:
             k = TIMED_K["hier"]
-            hier = (((zone, np.zeros(16, i32)),),
-                    (np.arange(leaves) // racks_per_zone
-                     % 16).astype(i32))
+            leaf, leaves, hier = fusedbatch.tree_inputs(
+                [zone, rack],
+                [{(z,): z for z in range(ZONES)},
+                 {(r // racks_per_zone, r): r for r in range(racks_in_all)}],
+                n)
             x, _fc, _spill = _timed_twice(
                 row, lambda: plan_group_jit(
-                    nodes._replace(leaf=rack), group_of(k), leaves, hier))
+                    nodes._replace(leaf=leaf), group_of(k), leaves, hier))
+            row["form"] = search_form(
+                leaves, hier[2].W if len(hier) > 2 else 0)
             x = np.asarray(x)
             racks = np.bincount(rack, x, racks_in_all)
             smoke.check(
                 x.sum() == k and within_one(racks)
                 and within_one(racks.reshape(ZONES, -1).sum(1))
                 and x.max() <= 1, f"tree unbalanced: racks {racks}")
+            # the node level again, by rows: what each rack was given,
+            # water-filled over its nodes by the search's other forms
+            x_rows, level_steps, tie_steps = jax.jit(
+                waterfill_search, static_argnames="L")(
+                zeros, np.minimum(nodes.res_cap, k),
+                _tie_key(nodes).astype(i32),
+                np.bincount(rack, x, leaves).astype(i32), leaf, L=leaves)
+            row["level_steps"], row["tie_steps"] = \
+                int(level_steps), int(tie_steps)
+            smoke.check((np.asarray(x_rows) == x).all(),
+                        "node level differs from the search by rows")
 
         sin = StrategyInputs(hr_cpu=zeros, hr_mem=zeros, hr_gen=zeros,
                              weights=np.zeros(4, i32), w1=w1, b1=b1,
@@ -553,6 +574,66 @@ def plan_program_times(smoke, buckets=TIMED_BUCKETS, seed: int = 0) -> None:
                     shared, groups, carry, 1, strat))[0]
                 smoke.check((np.asarray(xs) == np.stack(want[:g])).all(),
                             "differs from the per-group programs in order")
+
+
+#: (form, rows, segments) of a search step timed alone: PR 33's three
+#: (the flat group's sum, the mask at the 16,384 bucket's 256 racks, the
+#: scatter at ``harness-100k``'s bucket) and the dense form on that
+#: bucket's layout, [4096, 128]
+STEP_SHAPES = (("sum", 131072, 1), ("mask", 16384, 256),
+               ("scatter", 131072, 4096), ("dense", 131072, 4096))
+#: a reading is the difference of two programs' run times, of
+#: ``STEP_REPEATS[0]`` and of both numbers of steps, over the second
+#: number: the call's own 3-5 ms falls out
+STEP_REPEATS = (32, 512)
+
+
+def search_step_times(shapes=STEP_SHAPES, seed: int = 0) -> dict:
+    """One step of the level search (``kernel._seg_total`` of the fill
+    at a level that moves with the last step's sums, so no step is
+    hoisted) in each form, in microseconds.  The readings behind the
+    table over ``kernel.MASK_FORM_MAX_L``; orientation, no verdict."""
+    import jax
+    import jax.numpy as jnp
+    from swarmkit_tpu.ops import fusedbatch, kernel
+    rng = np.random.default_rng([seed, 35])
+    few, more = STEP_REPEATS
+    out = {}
+    for form, n, L in shapes:
+        # 128 rows a segment in use, as ``harness-100k``'s racks hold
+        seg = (np.arange(n) % min(L, max(n // 128, 1))).astype(np.int32)
+        e = rng.integers(0, 9, n).astype(np.int32)
+        cap = rng.integers(0, 9, n).astype(np.int32)
+        layout = fusedbatch.leaf_layout(seg, n, L) if form == "dense" \
+            else None
+        got = kernel.search_form(L, layout.W if layout else 0)
+        assert got == form, (form, got)
+
+        @jax.jit
+        def steps(e, cap, seg, layout, n_steps):
+            if layout is not None:
+                e, cap, seg = layout.lay(e, L), layout.lay(cap, L), None
+
+            def body(_, lam):
+                total = kernel._seg_total(
+                    lambda lam, e, cap: jnp.clip(lam - e, 0, cap),
+                    lam, (e, cap), seg, L)
+                return lam + (total >= 3.0).astype(jnp.int32)
+            return jax.lax.fori_loop(0, n_steps, body,
+                                     jnp.zeros(L, jnp.int32))
+
+        def run_s(n_steps):
+            rows = [{} for _ in range(3)]
+            for row in rows:
+                _timed_twice(row, lambda: steps(e, cap, seg, layout,
+                                                np.int32(n_steps)))
+            return min(row["run_s"] for row in rows)
+        short, long_ = run_s(few), run_s(few + more)
+        shape = f"[{L}, {layout.W}]" if layout else f"{n} rows, L {L}"
+        out[form] = round((long_ - short) / more * 1e6, 2)
+        print(f"step {form} ({shape}): {out[form]} us a step ({few} "
+              f"steps {short}s, {few + more} steps {long_}s)", flush=True)
+    return out
 
 
 def _gang_programs(smoke, nodes, k):
@@ -955,7 +1036,8 @@ class _RetreatLog(logging.Handler):
 
 def run(device: dict, n_nodes: int = N_NODES, n_agents: int = N_AGENTS,
         replicas: int = REPLICAS, seed: int = 0,
-        timeout: float = 600.0, timed_buckets=TIMED_BUCKETS) -> int:
+        timeout: float = 600.0, timed_buckets=TIMED_BUCKETS,
+        step_shapes=STEP_SHAPES) -> int:
     """Every phase, then the verdict; returns the exit code."""
     from swarmkit_tpu.utils.compilecache import ensure_compile_cache
     smoke = Smoke()
@@ -978,7 +1060,7 @@ def run(device: dict, n_nodes: int = N_NODES, n_agents: int = N_AGENTS,
           f"{cache_entries}", flush=True)
 
     overhead = None
-    served = {}
+    served, step_us = {}, {}
     try:
         with smoke.phase("native_build"):
             from swarmkit_tpu import native
@@ -997,6 +1079,7 @@ def run(device: dict, n_nodes: int = N_NODES, n_agents: int = N_AGENTS,
         with smoke.phase("programs"):
             programs_phase(smoke, n_nodes, max(replicas // 5, 1), seed)
             plan_program_times(smoke, timed_buckets, seed)
+            step_us = search_step_times(step_shapes, seed)
         with smoke.phase("served"):
             served = served_phase(smoke, n_nodes, n_agents, replicas,
                                   seed, timeout)
@@ -1024,6 +1107,7 @@ def run(device: dict, n_nodes: int = N_NODES, n_agents: int = N_AGENTS,
         "nodes": n_nodes, "replicas": replicas, "agents": n_agents,
         "launch_overhead_s": overhead,
         "programs": smoke.programs,
+        "search_step_us": step_us,
         "served": served,
         "compile": compile_total,
         "phase_s": smoke.phase_s,

@@ -48,6 +48,7 @@ from ..scheduler.filters import normalize_arch
 from .hashing import str_hash
 from .kernel import (
     FusedCarry, FusedGroups, FusedShared, FusedStrategy, K_CLAMP,
+    LeafLayout, search_form,
 )
 
 # static shape buckets to bound recompiles (shared with the per-group
@@ -255,11 +256,33 @@ def flat_leaf(infos, nb: int, descriptor: str
     return leaf, max(len(values), 1)
 
 
-def tree_inputs(segs, level_ids):
-    """The kernel's view of a numbered spread tree: ``(leaf, L, hier)``
-    from the per-level segment columns and path-prefix -> id maps.
-    ``hier`` = ((seg, parent) per upper level, leaf_parent), every
-    parent array at its level's ``l_bucket`` width."""
+def leaf_layout(leaf: np.ndarray, n: int, L: int) -> Optional[LeafLayout]:
+    """The leaf-major dense layout of a wide tree's leaf level
+    (``kernel.LeafLayout``) over the first ``n`` rows of ``leaf``, the
+    real ones: a row's rank is its place among its leaf's rows in row
+    order, so an appended row takes its leaf's next rank.  None where
+    the kernel would not take the dense form (``L`` of at most
+    ``MASK_FORM_MAX_L``, or a leaf so full that ``L * W`` is over
+    ``DENSE_FORM_MAX_ENTRIES``)."""
+    rows = leaf[:n]
+    pop = np.bincount(rows, minlength=1)
+    W = pow2_bucket(int(pop.max()))
+    if search_form(L, W) != "dense":
+        return None
+    order = np.argsort(rows, kind="stable")
+    rank = np.empty(n, np.int64)
+    rank[order] = np.arange(n) - (np.cumsum(pop) - pop)[rows[order]]
+    slot = np.full(len(leaf), L * W, np.int32)
+    slot[:n] = rows * W + rank
+    return LeafLayout(slot, W)
+
+
+def tree_inputs(segs, level_ids, n: int):
+    """The kernel's view of a numbered spread tree over ``n`` rows:
+    ``(leaf, L, hier)`` from the per-level segment columns and
+    path-prefix -> id maps.  ``hier`` = ((seg, parent) per upper level,
+    leaf_parent), every parent array at its level's ``l_bucket`` width,
+    and for a wide tree a third element, ``leaf_layout``'s."""
     depth = len(segs)
     L = l_bucket(max(len(level_ids[-1]), 1))
     upper = []
@@ -272,7 +295,9 @@ def tree_inputs(segs, level_ids):
     leaf_parent = np.zeros(L, np.int32)
     for path, cid in level_ids[-1].items():
         leaf_parent[cid] = level_ids[-2][path[:depth - 1]]
-    return segs[-1], L, (tuple(upper), leaf_parent)
+    layout = leaf_layout(segs[-1], n, L)
+    hier = (tuple(upper), leaf_parent)
+    return segs[-1], L, hier if layout is None else hier + (layout,)
 
 
 def spread_path(info, descriptors) -> tuple:
@@ -297,7 +322,7 @@ def spread_tree(infos, nb: int, descriptors):
             seg[i] = ids.setdefault(path[:di + 1], len(ids))
         level_ids.append(ids)
         segs.append(seg)
-    return tree_inputs(segs, level_ids)
+    return tree_inputs(segs, level_ids, len(paths))
 
 
 # ----------------------------------------------------------- fusability

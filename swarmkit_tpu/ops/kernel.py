@@ -71,7 +71,25 @@ shard's rows, and a bracket from them would differ between shards: it
 keeps the static brackets, closed where k = 0 or r = 0 (both replicated),
 and gains the convergence exit alone; ``lo`` and ``hi`` follow from
 reduced sums, so the loop's predicate is the same on every shard.
-What a step costs follows the static L: see `MASK_FORM_MAX_L`.
+  (f) the dense form's padding.  Above MASK_FORM_MAX_L a caller that
+      brings the leaf level's `LeafLayout` has the columns laid
+      leaf-major [L, W]; a slot no row fills reads e = cap = tie = 0.
+      With cap = 0 it is no row with room, so no bracket sees it (a);
+      it fills clip(λ - 0, 0, 0) = 0 at every λ and adds 0.0 to every
+      sum; x_base = 0 is not < cap, so it is never marginal, counts
+      for no threshold and is granted nothing (d); the pack-fill gives
+      it min(cap, r) = 0.  The bucket's padding rows, which have no
+      slot, have cap = 0 and no tasks: the row forms give them 0 too.
+      So brackets, trip counts and placements are the scatter form's,
+      and the f32 sums, taken along a row of the layout, stay inside
+      the argument at the foot of this docstring.
+What a step costs follows the static L and, above MASK_FORM_MAX_L,
+whether the layout came (`search_form`; the table over
+`MASK_FORM_MAX_L`): a reduction in three of the four forms, a walk of
+every row (a scatter-add and a gather) only where a tree of more than
+256 leaves came without its layout or the caller reduces across
+shards.  The dense form walks the rows five times a program (four
+columns in, x out), not twice a step.
 
 Resource accounting is **exact**: the host densifier compares int64
 nano-cpus/bytes and floor-divides in int64 (matching the reference's integer
@@ -86,7 +104,7 @@ all addends are non-negative, so every partial sum <= the true total; totals
 relative accuracy (error ~ N*eps) to stay far above K_CLAMP = 2^22 — either
 way the `sum >= k` comparison is decided correctly, in whatever order the
 addends are taken (a scatter-add, a plain reduction, a reduction over the
-membership mask).
+membership mask, a reduction along a row of the dense layout).
 """
 
 from __future__ import annotations
@@ -173,22 +191,99 @@ def _member(seg: jnp.ndarray, L: int) -> jnp.ndarray:
 
 
 # How a per-segment quantity is taken over the rows follows the static
-# L (measured on one TPU v5 lite, PERF.md §6 PR 33: a scatter-add or a
-# gather over N rows is a serial walk of them, 9-17 ns a row):
-#   L == 1             one segment holds every row: a plain reduction
-#                      and a scalar broadcast
-#   L <= MASK_FORM_MAX_L   reduce over the [N, L] membership mask: 7 ps
-#                      an entry, 9x faster than the scatter at L = 256
-#   above              N * L entries cost more than the walk: scatter
-#                      and gather
+# L and, above MASK_FORM_MAX_L, whether the caller brought the leaf
+# level's layout (`search_form`).  A step of the level search alone, on
+# one TPU v5 lite (`chip_smoke.py search_step_times`, PERF.md §6 PRs 33
+# and 35; a scatter-add or a gather over N rows is a serial walk of
+# them, 9-17 ns a row):
+#   sum      L == 1: one segment holds every row, a plain reduction and
+#            a scalar broadcast: 2.5-3.0 us at 131,072 rows
+#   mask     L <= MASK_FORM_MAX_L: reduce over the [N, L] membership
+#            mask, 7 ps an entry: 30.4 us at 16,384 rows and L = 256,
+#            9x faster than the scatter there
+#   dense    above, with a `LeafLayout` of at most
+#            DENSE_FORM_MAX_ENTRIES slots: the columns are laid
+#            leaf-major [L, W] once a program (a walk each, 0.7-0.8 ms
+#            at 131,072 rows, and 1.1 ms for x back) and a step reduces
+#            along axis 1 and broadcasts back: 3.8 us at [4096, 128],
+#            7 ps a slot
+#   scatter  above, without one (or a caller with a `reduce`): N * L
+#            mask entries cost more than the walk, so scatter and
+#            gather: 2,084 us at 131,072 rows and L = 4,096
 MASK_FORM_MAX_L = 256
+# a dense column is 4 * L * W bytes and a program holds some eight of
+# them (four laid, the step's temporaries, x): 2^24 slots are 64 MiB a
+# column, half a GiB a program, a thirtieth of one chip's memory.  The
+# step is not what limits it: at the bound, [4096, 4096], it takes
+# 180 us (11 ps a slot from HBM; 46 us at [4096, 1024]), an eleventh of
+# one scatter-form step at 131,072 rows.  A tree with one giant leaf
+# and thousands of tiny ones is over the bound and keeps the scatter.
+DENSE_FORM_MAX_ENTRIES = 1 << 24
 
 
-def _seg_reduce(x: jnp.ndarray, seg: jnp.ndarray, L: int, *, over, scatter,
-                fill) -> jnp.ndarray:
-    """[L] reduction (``over``: jnp.sum / min / max) of x i32|f32[N] by
+@jax.tree_util.register_pytree_node_class
+class LeafLayout:
+    """Where each row of a wide tree's leaf level sits in the leaf-major
+    dense layout ``[L, W]`` (row ``l`` holds the nodes of leaf ``l``):
+    ``slot`` i32[N] = ``leaf * W + rank``, the rank a row's place among
+    its leaf's rows in row order, and ``L * W`` (no slot: dropped on the
+    way in, read as 0 on the way out) on the bucket's padding rows,
+    which have no room and no tasks.  ``W`` is static, a power of two at
+    or above the fullest leaf's population: it rides as the pytree's
+    aux data, so a leaf that outgrows it is a new jit signature, as a
+    node bucket that grows is.  Built by ``fusedbatch.tree_inputs`` and
+    carried as the third element of ``hier``."""
+
+    def __init__(self, slot, W: int):
+        self.slot = slot
+        self.W = W
+
+    @property
+    def nbytes(self) -> int:
+        return self.slot.nbytes     # what a launch sends up
+
+    def tree_flatten(self):
+        return (self.slot,), self.W
+
+    @classmethod
+    def tree_unflatten(cls, W, children):
+        return cls(children[0], W)
+
+    def lay(self, col: jnp.ndarray, L: int) -> jnp.ndarray:
+        """``col`` [N] in the layout, [L, W]; the slots no row fills
+        read 0, which with ``cap = 0`` changes nothing (module
+        docstring, "The searches" (f))."""
+        flat = jnp.zeros(L * self.W, col.dtype)
+        return flat.at[self.slot].set(col, mode="drop").reshape(L, self.W)
+
+    def rows(self, dense: jnp.ndarray) -> jnp.ndarray:
+        """Back to row order, [N] from [L, W]."""
+        return dense.reshape(-1).at[self.slot].get(mode="fill",
+                                                   fill_value=0)
+
+
+def search_form(L: int, W: int = 0, reduce: Reduce = _identity) -> str:
+    """The form a search over ``L`` segments takes (the table above);
+    ``W`` the width of the caller's `LeafLayout`, 0 without one."""
+    if L == 1:
+        return "sum"
+    if L <= MASK_FORM_MAX_L:
+        return "mask"
+    if 0 < L * W <= DENSE_FORM_MAX_ENTRIES and reduce is _identity:
+        return "dense"
+    return "scatter"
+
+
+# In the primitives below ``seg is None`` says the columns are dense
+# [L, W]; else they are [N] with ``seg`` i32[N] the row's segment.
+
+def _seg_reduce(x: jnp.ndarray, seg: Optional[jnp.ndarray], L: int, *,
+                over, scatter, fill) -> jnp.ndarray:
+    """[L] reduction (``over``: jnp.sum / min / max) of x i32|f32 by
     segment; ``fill`` is the reduction's identity, ``scatter`` its
     jax.ops form."""
+    if seg is None:
+        return over(x, axis=1)
     if L == 1:
         return over(x).reshape(1)
     if L <= MASK_FORM_MAX_L:
@@ -196,7 +291,8 @@ def _seg_reduce(x: jnp.ndarray, seg: jnp.ndarray, L: int, *, over, scatter,
     return scatter(x, seg, num_segments=L)
 
 
-def _seg_sum_f32(x: jnp.ndarray, seg: jnp.ndarray, L: int) -> jnp.ndarray:
+def _seg_sum_f32(x: jnp.ndarray, seg: Optional[jnp.ndarray],
+                 L: int) -> jnp.ndarray:
     """int32 segment sum carried in f32 so totals up to N*k (~2^42) cannot
     wrap.  Safe for comparisons against bounds <= K_CLAMP — see module
     docstring for the exactness argument, which holds in any order of
@@ -205,9 +301,13 @@ def _seg_sum_f32(x: jnp.ndarray, seg: jnp.ndarray, L: int) -> jnp.ndarray:
                        scatter=jax.ops.segment_sum, fill=0.0)
 
 
-def _of_row(v_seg: jnp.ndarray, seg: jnp.ndarray, L: int) -> jnp.ndarray:
-    """Each row's entry of a per-segment vector ([N] from [L]); a row
-    whose segment id is no segment reads 0 in the mask form."""
+def _of_row(v_seg: jnp.ndarray, seg: Optional[jnp.ndarray],
+            L: int) -> jnp.ndarray:
+    """Each row's entry of a per-segment vector ([N] from [L], or
+    broadcastable to [L, W]); a row whose segment id is no segment
+    reads 0 in the mask form."""
+    if seg is None:
+        return v_seg[:, None]
     if L == 1:
         return v_seg[0]
     if L <= MASK_FORM_MAX_L:
@@ -218,21 +318,22 @@ def _of_row(v_seg: jnp.ndarray, seg: jnp.ndarray, L: int) -> jnp.ndarray:
 
 
 def _seg_total(fn: Callable, v_seg: jnp.ndarray, rows: Tuple,
-               seg: jnp.ndarray, L: int) -> jnp.ndarray:
+               seg: Optional[jnp.ndarray], L: int) -> jnp.ndarray:
     """What a search step needs, f32[L]: per segment, the sum over its
     rows of ``fn(v, *rows)`` with v the segment's entry of ``v_seg`` —
-    ``_seg_sum_f32(fn(_of_row(v_seg), *rows))`` in one pass over the
-    mask, so a step neither gathers nor scatters.  ``fn`` is
-    elementwise and non-negative."""
-    if L == 1 or L > MASK_FORM_MAX_L:
+    ``_seg_sum_f32(fn(_of_row(v_seg), *rows))``, in one pass over the
+    mask in the mask form and over the layout in the dense one, so a
+    step of either neither gathers nor scatters.  ``fn`` is elementwise
+    and non-negative."""
+    if seg is None or L == 1 or L > MASK_FORM_MAX_L:
         return _seg_sum_f32(fn(_of_row(v_seg, seg, L), *rows), seg, L)
     vals = fn(v_seg[None, :], *(r[:, None] for r in rows))
     return jnp.sum(jnp.where(_member(seg, L), vals, 0).astype(jnp.float32),
                    axis=0)
 
 
-def _key_bracket(live: jnp.ndarray, key: jnp.ndarray, seg: jnp.ndarray,
-                 L: int):
+def _key_bracket(live: jnp.ndarray, key: jnp.ndarray,
+                 seg: Optional[jnp.ndarray], L: int):
     """Per segment (has, min, max) of ``key`` over the ``live`` rows;
     min and max mean nothing where ``has`` is False."""
     lo = _seg_reduce(jnp.where(live, key, _I32_MAX), seg, L, over=jnp.min,
@@ -264,11 +365,12 @@ def _bisect(reaches: Callable[[jnp.ndarray], jnp.ndarray],
 
 
 def waterfill_search(e: jnp.ndarray, cap: jnp.ndarray, tie: jnp.ndarray,
-                     k_seg: jnp.ndarray, seg: jnp.ndarray, L: int,
+                     k_seg: jnp.ndarray, seg: Optional[jnp.ndarray], L: int,
                      reduce: Reduce = _identity):
-    """``seg_waterfill`` with its two trip counts: (x i32[N], level
-    steps, tie steps).  The counts are what the tests pin; the jitted
-    programs return x alone and the compiler drops the counters."""
+    """``seg_waterfill`` with its two trip counts: (x, level steps, tie
+    steps), x shaped as the columns are.  The counts are what the tests
+    pin; the jitted programs return x alone and the compiler drops the
+    counters."""
     e = e.astype(jnp.int32)
     cap = cap.astype(jnp.int32)
     k_seg = k_seg.astype(jnp.int32)
@@ -327,7 +429,7 @@ def waterfill_search(e: jnp.ndarray, cap: jnp.ndarray, tie: jnp.ndarray,
 
 
 def seg_waterfill(e: jnp.ndarray, cap: jnp.ndarray, tie: jnp.ndarray,
-                  k_seg: jnp.ndarray, seg: jnp.ndarray, L: int,
+                  k_seg: jnp.ndarray, seg: Optional[jnp.ndarray], L: int,
                   reduce: Reduce = _identity) -> jnp.ndarray:
     """Capacity-bounded water-filling within each segment.
 
@@ -339,7 +441,9 @@ def seg_waterfill(e: jnp.ndarray, cap: jnp.ndarray, tie: jnp.ndarray,
     tie:  i32[N] tie-break key in [0, 2^30), unique per element (lower =
           preferred)
     k_seg:i32[L] units to place per segment (each <= K_CLAMP)
-    seg:  i32[N] segment id per element (all 0 where L == 1)
+    seg:  i32[N] segment id per element (all 0 where L == 1); None
+          where e, cap and tie come in a `LeafLayout`'s dense [L, W],
+          as x then does
     reduce: cross-shard sum for [L]-shaped partials (psum under shard_map)
     """
     return waterfill_search(e, cap, tie, k_seg, seg, L, reduce)[0]
@@ -436,6 +540,12 @@ def plan_group(nodes: NodeInputs, group: GroupInputs, L: int,
       stage B: nodes within each leaf — level per-service counts
                (failure-down-weighted), tie-broken by total tasks.
 
+    Where ``hier`` brings the leaf level's `LeafLayout` and
+    ``search_form`` says "dense", what the leaf level reads (e, cap, the
+    tie key, the valid rows' service tasks) is laid out once, the leaf
+    level's branch sums and stage B run on the layout, and x is read
+    back to row order: five walks of the rows a program, none a step.
+
     Returns (x i32[N] tasks per node, fail_counts i32[7] per-filter
     failure counts in pipeline order, spill bool scalar — True when a
     spread branch saturated and the caller should use the host path for
@@ -464,8 +574,9 @@ def plan_group(nodes: NodeInputs, group: GroupInputs, L: int,
     # equi-preferred, caps above k are equivalent to k.
     kk = jnp.minimum(group.k, K_CLAMP)
     svc_valid = jnp.where(nodes.valid, svc, 0)
+    tie = (jnp.clip(nodes.total_tasks, 0, TOTAL_CLAMP) << IDX_BITS) | idx
 
-    def branch_arrays(seg, n_segs):
+    def branch_arrays(seg, n_segs, svc_valid=svc_valid, cap=cap):
         load = jnp.minimum(
             reduce(_seg_sum_f32(svc_valid, seg, n_segs)),
             float(LOAD_CLAMP)).astype(jnp.int32)
@@ -474,11 +585,20 @@ def plan_group(nodes: NodeInputs, group: GroupInputs, L: int,
                            kk.astype(jnp.float32)).astype(jnp.int32)
         return load, bcap, raw_cap
 
-    # hier = (upper_levels, leaf_parent):
+    # hier = (upper_levels, leaf_parent[, layout]):
     #   upper_levels — tuple of (seg_nodes i32[N], parent i32[L_d]) pairs,
     #   top level first, for every level ABOVE the leaves;
-    #   leaf_parent  — i32[L] mapping each leaf to its upper-level branch.
-    upper_levels, leaf_parent = hier if hier else ((), None)
+    #   leaf_parent  — i32[L] mapping each leaf to its upper-level branch;
+    #   layout       — the leaf level's `LeafLayout`, of a wide tree only.
+    upper_levels, leaf_parent, *layout = hier if hier else ((), None)
+    dense = bool(layout) and search_form(L, layout[0].W, reduce) == "dense"
+    # what the leaf level reads, by rows under nodes.leaf or laid dense
+    leaf_seg, leaf_cols = nodes.leaf, (svc_valid, cap, e, tie)
+    if dense:
+        (layout,) = layout
+        leaf_seg = None
+        leaf_cols = tuple(layout.lay(col, L) for col in leaf_cols)
+    svc_leaf, cap_leaf, e_leaf, tie_leaf = leaf_cols
 
     k_parent = kk.reshape(1)   # the root's allocation
     parent_count = 1
@@ -522,7 +642,7 @@ def plan_group(nodes: NodeInputs, group: GroupInputs, L: int,
         _, branch_cap, _raw = branch_arrays(nodes.leaf, 1)
         k_branch = jnp.minimum(kk, branch_cap)
     else:
-        load, bcap, raw_cap = branch_arrays(nodes.leaf, L)
+        load, bcap, raw_cap = branch_arrays(leaf_seg, L, svc_leaf, cap_leaf)
         seg = leaf_parent if leaf_parent is not None \
             else jnp.zeros((L,), jnp.int32)
         k_branch = seg_waterfill(
@@ -532,9 +652,10 @@ def plan_group(nodes: NodeInputs, group: GroupInputs, L: int,
             spill = spill | level_spill(k_branch, raw_cap)
 
     # ---- stage B: nodes within each leaf branch
-    tie = (jnp.clip(nodes.total_tasks, 0, TOTAL_CLAMP) << IDX_BITS) | idx
-    x = seg_waterfill(e=e, cap=cap, tie=tie, k_seg=k_branch,
-                      seg=nodes.leaf, L=L, reduce=reduce)
+    x = seg_waterfill(e=e_leaf, cap=cap_leaf, tie=tie_leaf, k_seg=k_branch,
+                      seg=leaf_seg, L=L, reduce=reduce)
+    if dense:
+        x = layout.rows(x)
     return x, fail_counts, spill
 
 
@@ -589,9 +710,10 @@ class StrategyInputs(NamedTuple):
 
 
 def packfill_search(key: jnp.ndarray, cap: jnp.ndarray,
-                    k_seg: jnp.ndarray, seg: jnp.ndarray, L: int,
+                    k_seg: jnp.ndarray, seg: Optional[jnp.ndarray], L: int,
                     reduce: Reduce = _identity):
-    """``seg_packfill`` with its trip count: (x i32[N], steps)."""
+    """``seg_packfill`` with its trip count: (x, steps); dense columns
+    and ``seg`` None as in ``seg_waterfill``."""
     cap = cap.astype(jnp.int32)
     k_seg = k_seg.astype(jnp.int32)
     kf = k_seg.astype(jnp.float32)
@@ -628,7 +750,7 @@ def packfill_search(key: jnp.ndarray, cap: jnp.ndarray,
 
 
 def seg_packfill(key: jnp.ndarray, cap: jnp.ndarray,
-                 k_seg: jnp.ndarray, seg: jnp.ndarray, L: int,
+                 k_seg: jnp.ndarray, seg: Optional[jnp.ndarray], L: int,
                  reduce: Reduce = _identity) -> jnp.ndarray:
     """Sequential (pack) fill within each segment: nodes take their
     full capacity in ascending ``key`` order until k is placed — the

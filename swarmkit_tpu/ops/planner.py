@@ -64,7 +64,7 @@ from .hashing import str_hash
 from .kernel import (
     GroupInputs, K_CLAMP, NodeInputs, StrategyInputs, fetch_plan,
     gang_fit_fused_jit, gang_fit_jit, plan_fused_jit, plan_group_jit,
-    plan_strategy_jit,
+    plan_strategy_jit, search_form,
 )
 
 log = logging.getLogger("tpu-planner")
@@ -366,6 +366,7 @@ class TPUPlanner:
                       "tree_cols_invalidations": 0,
                       "h2d_bytes": 0, "d2h_bytes": 0,
                       "wide_tree_groups": 0, "wide_tree_s": 0.0,
+                      "dense_tree_groups": 0,
                       "tasks_planned": 0, "plan_seconds": 0.0}
         # the break-even router's two sides (_route_costs): the measured
         # fixed launch overhead (dispatch + D2H round-trip on a minimal
@@ -1030,10 +1031,12 @@ class TPUPlanner:
         if flat:
             bucket += f"_st{sinfo.sid}"
         route = "strategy" if flat else "group"
+        # the form the program's searches take at the leaf level
+        form = search_form(L, hier[2].W if len(hier) > 2 else 0)
         try:
             with tracer.span("plan.dispatch", "plan", tasks=k,
                              service=t.service_id, label=bucket,
-                             route=route) as sp:
+                             route=route, form=form) as sp:
                 if flat:
                     sin = self._build_strategy_inputs(built, t, sinfo)
                     arrays = self._call_strategy_fn(nodes_in, group_in,
@@ -1057,6 +1060,8 @@ class TPUPlanner:
             # the scheduler thread's wall on the group so far: the
             # densify and the launch (fetch_group adds the wait)
             self._count("wide_tree_groups")
+            if form == "dense":
+                self._count("dense_tree_groups")
             self.stats["wide_tree_s"] += _time.perf_counter() - _plan_t0
         handle = _InFlightPlan(sched, t, task_group, decisions, built,
                                _plan_t0, arrays, bucket=bucket,
@@ -1233,6 +1238,10 @@ class TPUPlanner:
             else:
                 leaf, L, hier = fusedbatch.spread_tree(infos, nb,
                                                        descriptors)
+            if self._plan_fn is not plan_group_jit:
+                # a wide tree's layout is the single-device program's:
+                # an injected plan_fn (the mesh's, a stub) takes the two
+                hier = hier[:2]
 
         nodes_in = NodeInputs(
             valid=valid, ready=ready, res_ok=res_ok, res_cap=res_cap,

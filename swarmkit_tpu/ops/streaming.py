@@ -135,8 +135,9 @@ class _TreeColumns:
     (``fusedbatch.spread_tree``) numbers it: per level the segment-id
     column and the path-prefix -> id map, each row's path, and the
     kernel inputs derived from the numbering (``fusedbatch.tree_inputs``:
-    parent arrays at their bucket widths, ``leaf_parent``, ``L``),
-    derived again once an appended row opened a new branch."""
+    parent arrays at their bucket widths, ``leaf_parent``, ``L``, a wide
+    tree's ``LeafLayout``), derived again once an appended row opened a
+    new branch or outgrew the layout's ``W``."""
 
     __slots__ = ("segs", "level_ids", "paths", "inputs")
 
@@ -147,7 +148,10 @@ class _TreeColumns:
         self.paths: List[tuple] = []
         self.inputs = None
 
-    def append(self, path: tuple) -> None:
+    def append(self, path: tuple) -> bool:
+        """Row ``len(paths)`` joins with ``path``.  True where it was
+        one more than its leaf's ``W`` slots hold: the layout is laid
+        again at twice the width, a new jit signature."""
         i = len(self.paths)
         self.paths.append(path)
         for di, ids in enumerate(self.level_ids):
@@ -155,6 +159,16 @@ class _TreeColumns:
             self.segs[di][i] = ids.setdefault(path[:di + 1], known)
             if len(ids) != known:
                 self.inputs = None
+        if self.inputs is None or len(self.inputs[2]) < 3:
+            return False
+        # the kept layout: the row takes its leaf's next rank
+        layout, leaf = self.inputs[2][2], self.segs[-1]
+        rank = int(np.count_nonzero(leaf[:i] == leaf[i]))
+        if rank < layout.W:
+            layout.slot[i] = leaf[i] * layout.W + rank
+            return False
+        self.inputs = None
+        return True
 
 
 class ResidentState:
@@ -465,7 +479,8 @@ class ResidentState:
         entry = self.tree_cols[descriptors]
         path = fusedbatch.spread_path(info, descriptors)
         if append:
-            entry.append(path)
+            if entry.append(path):
+                self._count("tree_cols_invalidations")
         elif entry.paths[i] != path:
             # as a flat leaf's value change: ids at every level are
             # first-appearance ordered in row order, so a moved row can
@@ -635,8 +650,8 @@ class ResidentState:
         else:
             self._count("tree_cols_hits")
         if entry.inputs is None:
-            entry.inputs = fusedbatch.tree_inputs(entry.segs,
-                                                  entry.level_ids)
+            entry.inputs = fusedbatch.tree_inputs(
+                entry.segs, entry.level_ids, len(entry.paths))
         return entry.inputs
 
     # --------------------------------------------------------- device tier

@@ -148,7 +148,8 @@ def test_forced_retreats_become_a_nonzero_exit(monkeypatch, capsys):
     monkeypatch.setattr(chip_smoke, "programs_phase",
                         lambda *args: None)
     rc = chip_smoke.run(CPU, n_nodes=64, n_agents=2, replicas=2000,
-                        timeout=120.0, timed_buckets=((128, 100, 25),))
+                        timeout=120.0, timed_buckets=((128, 100, 25),),
+                        step_shapes=(("sum", 128, 1),))
     captured = capsys.readouterr()
     assert rc != 0
     assert '"ok"' not in captured.out
@@ -180,6 +181,31 @@ def test_plan_programs_are_timed_at_each_bucket_beside_their_pass_line(
     assert len(lines) == 10
     assert all(re.search(r": PASS compile_s=\S+ wall_s=\S+ run_s=\S+", line)
                for line in lines)
+    # the tree's line says the form its searches took and their steps:
+    # built through fusedbatch.tree_inputs, 260 racks get the layout
+    hier = [row for row in smoke.programs if "hier" in row["program"]]
+    assert [row["form"] for row in hier] == ["mask", "dense"]
+    assert all(0 < row["level_steps"] <= 7 and 0 <= row["tie_steps"] <= 24
+               for row in hier)
+    json.dumps(smoke.programs)      # the summary line carries every row
+    assert all(re.search(r" form=\w+ level_steps=\d+ tie_steps=\d+$", line)
+               for line in lines if "/hier@" in line)
     # the three cells' buckets, the last with harness-100k's wide tree
     assert chip_smoke.TIMED_BUCKETS == (
         (1024, 1000, 25), (16384, 10000, 25), (131072, 100000, 250))
+
+
+def test_a_search_step_is_timed_in_each_of_its_four_forms(capsys):
+    """Tiny shapes here: that each shape takes the form it names and a
+    line is printed for it, no speed."""
+    shapes = (("sum", 512, 1), ("mask", 512, 16), ("scatter", 2048, 300),
+              ("dense", 2048, 300))
+    out = chip_smoke.search_step_times(shapes, seed=1)
+    assert list(out) == ["sum", "mask", "scatter", "dense"]
+    lines = [line for line in capsys.readouterr().out.splitlines()
+             if line.startswith("step ")]
+    assert len(lines) == 4 and "step dense ([300, 128])" in lines[3]
+    assert all(re.search(r": \S+ us a step ", line) for line in lines)
+    # on the chip: PR 33's three and the dense form at [4096, 128]
+    assert [(form, L) for form, _n, L in chip_smoke.STEP_SHAPES] == [
+        ("sum", 1), ("mask", 256), ("scatter", 4096), ("dense", 4096)]

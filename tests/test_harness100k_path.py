@@ -8,8 +8,9 @@ harness-100k.json``) under the cut its cell brings for the CPU
 racks a zone), dealt by ``benchmark/cluster.plain_nodes``.  260 racks are
 more than 256 leaves, so a two-level topology group is a *wide tree*: its
 leaf bucket is 4,096, its label ``..._L4096_h2``, and its searches take
-the scatter form of ``ops/kernel.py`` (``L > MASK_FORM_MAX_L``), the one
-branch neither smaller configuration runs.
+the dense form of ``ops/kernel.py`` (``L > MASK_FORM_MAX_L`` with the
+tree's ``LeafLayout``, kept by the resident tier beside the level
+columns), the one branch neither smaller configuration runs.
 
 One tick of the four shapes is deployed twice: the router's probes are
 pinned to what they read at the configuration's size (a launch of 5 ms
@@ -18,9 +19,10 @@ every group to the device, as in the cell.  The outcome is held to the
 plain reference's comparison (``benchmark/reference.py compare``), to the
 host route (the same tick with a launch no group amortises, every group
 placed by the host oracle) and, launch by launch, to the same program
-traced with ``MASK_FORM_MAX_L`` raised to the leaf bucket: the scatter
-form and the mask form of a step must agree at 4,096 leaves as PR 33's
-cases show them to at 256.
+without the layout (the scatter form) and traced with
+``MASK_FORM_MAX_L`` raised to the leaf bucket (the mask form): the three
+forms of a step must agree at 4,096 leaves as PR 33's cases show two of
+them to at 256.
 
 "The same placement" is said of a group's per-node counts: the tasks of a
 group are interchangeable.  The flat shapes agree node for node between
@@ -246,16 +248,29 @@ def test_every_group_rides_the_device_and_the_trees_ride_the_wide_label():
         assert grown.get("groups_fallback", 0) == 0
         assert grown["groups_planned"] + grown["groups_fused"] == len(TICK)
         assert grown["tasks_planned"] == sum(k for _s, k in TICK)
-        # the topology groups and only them
-        assert grown["wide_tree_groups"] == len(TREES)
+        # the topology groups and only them, every one in the dense form
+        assert grown["wide_tree_groups"] == len(TREES) \
+            == grown["dense_tree_groups"]
         assert grown["wide_tree_s"] > 0
         wide = [c for c in r["launches"] if c["L"] > 256]
         assert [c["label"] for c in wide] == [TREE_LABEL] * len(TREES)
         assert [int(c["operands"][1].k) for c in wide] == TREES
+        for c in wide:
+            layout = c["operands"][2][2]
+            assert kernel_mod.search_form(c["L"], layout.W) == "dense"
+            assert layout.W == fusedbatch.pow2_bucket(int(np.bincount(
+                c["operands"][0].leaf[:len(run["nodes"])]).max()))
+    # the span of a group launched on its own says the form it took
+    # (the flat groups ride fused runs: only the trees launch alone)
+    own = [a for name, a in run["spans"]
+           if name == "plan.dispatch" and a["route"] == "group"]
+    assert [(a["form"], a["label"]) for a in own] \
+        == [("dense", TREE_LABEL)] * (ROUNDS * len(TREES))
     host = outcome("host")
     for r in host["rounds"]:
         assert r["planner"]["groups_small_to_host"] == len(TICK)
-        assert r["planner"]["wide_tree_groups"] == 0
+        assert r["planner"]["wide_tree_groups"] == 0 \
+            == r["planner"]["dense_tree_groups"]
         assert r["planner"]["wide_tree_s"] == 0 and not r["launches"]
 
 
@@ -296,22 +311,25 @@ def test_the_wide_trees_equal_the_host_oracle_level_by_level():
             # ``topology_leaf_skew``, held at 1 above)
 
 
-def test_the_scatter_form_and_the_mask_form_agree_at_4096_leaves(
-        monkeypatch):
-    """Every wide-tree launch of the served path, run again through the
-    same program traced with the mask form allowed up to the leaf
+@pytest.mark.parametrize("form", ["scatter", "mask"])
+def test_the_three_forms_agree_at_4096_leaves(form, monkeypatch):
+    """Every wide-tree launch of the served path took the dense form;
+    run again through the same program without the layout (the scatter
+    form), and traced with the mask form allowed up to the leaf
     bucket."""
     import jax
     run = outcome("device")
     wide = [c for r in run["rounds"] for c in r["launches"] if c["L"] > 256]
     assert len(wide) == ROUNDS * len(TREES)
     assert kernel_mod.MASK_FORM_MAX_L < 4096
-    monkeypatch.setattr(kernel_mod, "MASK_FORM_MAX_L", 4096)
-    masked = jax.jit(kernel_mod.plan_group, static_argnames=("L",))
+    if form == "mask":
+        monkeypatch.setattr(kernel_mod, "MASK_FORM_MAX_L", 4096)
+    assert kernel_mod.search_form(4096) == form
+    again = jax.jit(kernel_mod.plan_group, static_argnames=("L",))
     for c in wide:
         nodes_in, group_in, hier = c["operands"]
-        x, fail_counts, spill = masked(nodes_in, group_in, L=c["L"],
-                                       hier=hier)
+        x, fail_counts, spill = again(nodes_in, group_in, L=c["L"],
+                                      hier=hier[:2])
         assert (np.asarray(x) == c["out"][0]).all()
         assert (np.asarray(fail_counts) == c["out"][1]).all()
         assert bool(spill) == bool(c["out"][2]) is False

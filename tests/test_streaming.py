@@ -494,6 +494,109 @@ def test_resident_tree_cache_is_bounded(frozen_clock):
     assert planner.stats["tree_cols_builds"] == len(keys)
 
 
+# ---- a wide tree's leaf layout (more than 256 leaves: kernel.LeafLayout)
+
+def _wide_tree_sched(n_nodes=599, racks=300):
+    """``_tree_sched`` over ``racks`` racks, nodes dealt round-robin:
+    two to a rack but the last, which has one."""
+    store = MemoryStore()
+
+    def fill(tx):
+        for i in range(n_nodes):
+            tx.create(_tree_node(i, i % racks))
+        svc, tasks = _mk_service("tree", 7, _tree_spec(2))
+        tx.create(svc)
+        for t in tasks:
+            tx.create(t)
+    store.update(fill)
+    planner = TPUPlanner()
+    planner.enable_small_group_routing = False
+    sched = Scheduler(store, batch_planner=planner, pipeline_depth=1)
+    _, sub = store.view_and_watch(
+        lambda tx: sched._setup_tasks_list(tx), accepts_blocks=True)
+    return store, sched, sub
+
+
+def _assert_layout(layout, leaf, n, L):
+    """Unique slots inside [0, L * W), each in its leaf's row of the
+    layout and ranked in row order; ``W`` the power of two at or above
+    the fullest leaf; no slot on the bucket's padding rows."""
+    from swarmkit_tpu.ops import fusedbatch
+    slot, W = layout.slot, layout.W
+    assert slot.dtype == np.int32 and slot.shape == leaf.shape
+    pop = np.bincount(leaf[:n])
+    assert W == fusedbatch.pow2_bucket(int(pop.max()))
+    assert len(set(slot[:n].tolist())) == n
+    assert slot[:n].min() >= 0 and slot[:n].max() < L * W
+    assert (slot[:n] // W == leaf[:n]).all()
+    for lf in range(len(pop)):
+        ranks = slot[:n][leaf[:n] == lf] % W
+        assert ranks.tolist() == list(range(pop[lf]))     # row order
+    assert (slot[n:] == L * W).all()
+    assert layout.nbytes == slot.nbytes
+
+
+def test_a_wide_trees_leaf_layout_is_kept_with_its_columns(frozen_clock):
+    """The resident tier's layout against the walk's, through a build,
+    an appended node that takes its leaf's next rank in place, and one
+    that outgrows ``W``: laid again at twice the width and counted."""
+    store, sched, sub = _wide_tree_sched()
+    planner = sched.batch_planner
+    walk = TPUPlanner()
+    walk.streaming_enabled = False
+    stats = planner.stats
+
+    def same(n, builds, hits, invalidations):
+        _pump(sched, sub)
+        leaf, L, hier = _tree_inputs_of(planner, sched, 2)
+        w_leaf, w_L, w_hier = _tree_inputs_of(walk, sched, 2)
+        assert L == w_L == 4096 and len(hier) == len(w_hier) == 3
+        _assert_same_tree((leaf, L, hier[:2]), (w_leaf, w_L, w_hier[:2]), 2)
+        _assert_layout(hier[2], leaf, n, L)
+        assert hier[2].W == w_hier[2].W
+        np.testing.assert_array_equal(hier[2].slot, w_hier[2].slot)
+        assert (stats["tree_cols_builds"], stats["tree_cols_hits"],
+                stats["tree_cols_invalidations"]) \
+            == (builds, hits, invalidations), stats
+        return leaf, hier[2]
+
+    leaf, layout = same(599, 1, 0, 0)
+    assert layout.W == 2 and leaf[598] == 298 and leaf[299] == 299
+    # rack 299 has one node: the next one takes rank 1 there, in place
+    store.update(lambda tx: tx.create(_tree_node(599, 299)))
+    leaf, grown = same(600, 1, 1, 0)
+    assert grown is layout and grown.slot[599] == 299 * 2 + 1
+    # a third node in rack 5: one more than its two slots
+    store.update(lambda tx: tx.create(_tree_node(600, 5)))
+    leaf, wider = same(601, 1, 2, 1)
+    assert wider is not layout and wider.W == 4
+    assert wider.slot[600] == 5 * 4 + 2
+    assert planner._streaming.stats["full"] == 1      # appended, not rebuilt
+    # a rack that is new: the tree's inputs come again, the layout too
+    store.update(lambda tx: tx.create(_tree_node(601, 300)))
+    leaf, again = same(602, 1, 3, 1)
+    assert again is not wider and again.W == 4 and leaf[601] == 300
+
+
+@pytest.mark.parametrize("racks,wide", [(256, False), (257, True)])
+def test_only_a_tree_past_the_mask_forms_bound_gets_a_layout(racks, wide):
+    from swarmkit_tpu.ops import fusedbatch, kernel
+    assert kernel.MASK_FORM_MAX_L == 256
+    n, nb = 3 * racks, 1024
+    rack = np.zeros(nb, np.int32)
+    rack[:n] = np.arange(n) % racks
+    rack[:5] = 0                                  # the fullest leaf: 7
+    segs = [np.where(np.arange(nb) < n, rack % 4, 0).astype(np.int32), rack]
+    level_ids = [{(z,): z for z in range(4)},
+                 {(r % 4, r): r for r in range(racks)}]
+    leaf, L, hier = fusedbatch.tree_inputs(segs, level_ids, n)
+    assert L == fusedbatch.l_bucket(racks) == (4096 if wide else 256)
+    assert len(hier) == (3 if wide else 2)
+    if wide:
+        assert hier[2].W == 8
+        _assert_layout(hier[2], leaf, n, L)
+
+
 def test_two_level_group_places_the_same_on_the_resident_tier_and_the_walk(
         frozen_clock):
     """``schedule_group`` on the resident tier and on a tracker-less

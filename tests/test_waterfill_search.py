@@ -373,3 +373,223 @@ def test_a_search_that_has_nothing_to_find_takes_no_step():
                       cap=np.zeros(1024, np.int32))):
         assert check_waterfill(case) == (0, 0)
         assert check_packfill(case) == 0
+
+
+# ------------------------------------------- the dense form, above 256 leaves
+#
+# A wide tree's leaf level laid leaf-major [L, W] once (kernel.LeafLayout,
+# built by fusedbatch.leaf_layout): the fourth form of the same searches.
+
+@functools.partial(jax.jit, static_argnames=("L",))
+def dense_searches(e, cap, tie, key, k_seg, layout, L):
+    """The water-fill and the pack-fill on the layout, back by rows:
+    (x, level steps, tie steps, x of the pack-fill, its steps)."""
+    e, cap, tie, key = (layout.lay(col, L) for col in (e, cap, tie, key))
+    x, level_steps, tie_steps = waterfill_search(e, cap, tie, k_seg, None, L)
+    xp, steps = packfill_search(key, cap, k_seg, None, L)
+    return layout.rows(x), level_steps, tie_steps, layout.rows(xp), steps
+
+
+def check_dense(case: dict, n: int = None) -> kernel_mod.LeafLayout:
+    """``case`` through the dense form against the scatter form and the
+    34-step reference: placements bit for bit, trip counts equal.
+    ``n``: the real rows (the rest is the bucket's padding)."""
+    L = case["L"]
+    n = len(case["seg"]) if n is None else n
+    layout = fusedbatch.leaf_layout(case["seg"], n, L)
+    assert kernel_mod.search_form(L, layout.W) == "dense"
+    key = pack_key(case)
+    x, level_steps, tie_steps, xp, steps = dense_searches(
+        case["e"], case["cap"], case["tie"], key, case["k_seg"], layout, L)
+    assert x.dtype == xp.dtype == level_steps.dtype == jnp.int32
+    ref, scatter, s_level, s_tie = both_waterfills(
+        case["e"], case["cap"], case["tie"], case["k_seg"], case["seg"], L)
+    assert (np.asarray(x) == np.asarray(scatter)).all()
+    assert (np.asarray(x) == np.asarray(ref)).all()
+    assert (int(level_steps), int(tie_steps)) == (int(s_level), int(s_tie))
+    pref, pscatter, p_steps = both_packfills(
+        key, case["cap"], case["k_seg"], case["seg"], L)
+    assert (np.asarray(xp) == np.asarray(pscatter)).all()
+    assert (np.asarray(xp) == np.asarray(pref)).all()
+    assert int(steps) == int(p_steps)
+    return layout
+
+
+@pytest.mark.parametrize("k", (0, 1, 1000, K_CLAMP))
+@pytest.mark.parametrize("kind", KINDS)
+def test_the_dense_form_places_what_the_scatter_form_places_in_as_many_steps(
+        kind, k):
+    case = make_case(kind, WIDE_L, k, seed=4)
+    layout = check_dense(case)
+    # the layout holds every row once, in its own leaf's row
+    slot = layout.slot
+    assert len(np.unique(slot)) == len(slot) and slot.max() < WIDE_L * layout.W
+    assert (slot // layout.W == case["seg"]).all()
+    # the host mirror on a few segments: 4,096 calls a case otherwise
+    few = case["seg"] < 8
+    small = dict(case, L=8, k_seg=case["k_seg"][:8],
+                 **{c: case[c][few] for c in ("e", "cap", "tie", "seg")})
+    want = host_by_segment(small, lambda rows, k: strategy_mod.waterfill_host(
+        small["e"][rows], small["cap"][rows], small["tie"][rows], k))
+    got = np.asarray(dense_searches(
+        case["e"], case["cap"], case["tie"], pack_key(case), case["k_seg"],
+        layout, WIDE_L)[0])[few]
+    assert (got == want).all()
+
+
+def _shaped_case(shape: str) -> tuple:
+    """(case, real rows) for the layout's own edges."""
+    case = make_case("plain", WIDE_L, 50, seed=5)
+    n = rows = len(case["seg"])
+    if shape == "a_leaf_filled_to_exactly_W":
+        case["seg"][:64] = 7          # with the leaf's own rows: 64 or more
+        extra = int((case["seg"] == 7).sum())
+        case["seg"][64:][case["seg"][64:] == 7] = 8
+        assert (case["seg"] == 7).sum() == 64 <= extra
+        case["k_seg"][7] = 3 * 64     # and asked for more than it has rows
+    elif shape == "empty_leaves":
+        case["seg"] = (case["seg"] % 300 * 13).astype(np.int32)
+    elif shape == "padding_rows":
+        # a bucket's tail: segment 0, no room, no tasks, and no slot
+        n = rows * 3 // 4
+        for c, v in (("seg", 0), ("cap", 0), ("e", 0)):
+            case[c][n:] = v
+        case["tie"][n:] = np.arange(n, rows)
+    return case, n
+
+
+@pytest.mark.parametrize("shape", ("a_leaf_filled_to_exactly_W",
+                                   "empty_leaves", "padding_rows"))
+def test_the_layouts_edges_place_what_the_scatter_form_places(shape):
+    case, n = _shaped_case(shape)
+    layout = check_dense(case, n)
+    pop = np.bincount(case["seg"][:n], minlength=WIDE_L)
+    assert layout.W == fusedbatch.pow2_bucket(int(pop.max()))
+    if shape == "a_leaf_filled_to_exactly_W":
+        assert layout.W == 64 == pop[7]
+        assert sorted(layout.slot[case["seg"] == 7]) \
+            == list(range(7 * 64, 8 * 64))
+    elif shape == "empty_leaves":
+        assert (pop == 0).sum() >= WIDE_L - 300
+    else:
+        assert (layout.slot[n:] == WIDE_L * layout.W).all()
+        assert layout.slot[:n].max() < WIDE_L * layout.W
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_the_dense_form_under_the_fused_paths_x64(kind):
+    with fusedbatch.x64():
+        for k in (0, 10, K_CLAMP):
+            check_dense(make_case(kind, WIDE_L, k, seed=6))
+
+
+# ---- the three ways back to the scatter form, through plan_group
+
+WIDE_NB, WIDE_N, WIDE_RACKS = 1024, 900, 300
+
+
+def wide_tree_inputs(seed: int = 0, k: int = 77):
+    """(NodeInputs, GroupInputs, L, hier) of a two-level tree of 300
+    racks in 4 zones over 900 nodes of the 1,024 bucket, built as the
+    cold path builds it."""
+    from swarmkit_tpu.ops.kernel import GroupInputs, NodeInputs
+    rng = np.random.default_rng([seed, 35])
+    nb, n, i32 = WIDE_NB, WIDE_N, np.int32
+    valid = np.arange(nb) < n
+    rack = np.where(valid, rng.integers(0, WIDE_RACKS, nb), 0)
+    zone = rack % 4
+    segs, level_ids = [], []
+    for col in ([(z,) for z in zone], list(zip(zone, rack))):
+        ids = {}
+        segs.append(np.array([ids.setdefault(p, len(ids)) if v else 0
+                              for p, v in zip(col, valid)], i32))
+        level_ids.append(ids)
+    leaf, L, hier = fusedbatch.tree_inputs(segs, level_ids, n)
+    nodes = NodeInputs(
+        valid=valid, ready=valid & (rng.random(nb) < 0.95), res_ok=valid,
+        res_cap=np.where(valid, rng.integers(0, 4, nb), 0).astype(i32),
+        svc_tasks=np.where(valid, rng.integers(0, 3, nb), 0).astype(i32),
+        total_tasks=np.where(valid, rng.integers(0, 9, nb), 0).astype(i32),
+        failures=np.zeros(nb, i32), leaf=leaf,
+        os_hash=np.zeros((2, nb), i32), arch_hash=np.zeros((2, nb), i32),
+        port_conflict=np.zeros(nb, bool), extra_mask=np.ones(nb, bool))
+    group = GroupInputs(
+        k=i32(k), con_hash=np.zeros((1, 2, nb), i32),
+        con_op=np.full(1, 2, i32), con_exp=np.zeros((1, 2), i32),
+        plat=np.full((1, 4), -1, i32), maxrep=i32(0),
+        port_limited=np.bool_(False))
+    return nodes, group, L, hier
+
+
+def _plan(nodes, group, L, hier, reduce=kernel_mod._identity):
+    """(outputs, whether the traced program scatter-adds: the scatter
+    form's segment sums; the dense form's scatters only set)."""
+    fn = functools.partial(kernel_mod.plan_group, L=L, reduce=reduce)
+    text = str(jax.make_jaxpr(fn)(nodes, group, hier=hier))
+    out = jax.jit(fn)(nodes, group, hier=hier)
+    return [np.asarray(a) for a in out], "scatter-add" in text
+
+
+@pytest.mark.parametrize("way", ("no_layout", "over_the_bound",
+                                 "a_caller_with_a_reduce"))
+def test_the_ways_back_to_the_scatter_form(way, monkeypatch):
+    nodes, group, L, hier = wide_tree_inputs()
+    layout = hier[2]
+    assert L == WIDE_L and len(hier) == 3
+    assert kernel_mod.search_form(L, layout.W) == "dense"
+    dense, adds = _plan(nodes, group, L, hier)
+    assert not adds and dense[0].sum() == int(group.k)
+    reduce = kernel_mod._identity
+    if way == "no_layout":
+        hier = hier[:2]
+        assert kernel_mod.search_form(L) == "scatter"
+    elif way == "over_the_bound":
+        monkeypatch.setattr(kernel_mod, "DENSE_FORM_MAX_ENTRIES",
+                            L * layout.W - 1)
+        assert kernel_mod.search_form(L, layout.W) == "scatter"
+        # and the tree's builder lays none the kernel would not take
+        assert fusedbatch.leaf_layout(nodes.leaf, WIDE_N, L) is None
+    else:
+        reduce = lambda v: v                                # noqa: E731
+        assert kernel_mod.search_form(L, layout.W, reduce) == "scatter"
+    back, adds = _plan(nodes, group, L, hier, reduce)
+    assert adds
+    for got, want in zip(back, dense):
+        assert (got == want).all()
+
+
+def test_the_form_follows_the_static_inputs_alone():
+    form = kernel_mod.search_form
+    assert [form(L) for L in (1, 16, 256, 257, 4096)] \
+        == ["sum", "mask", "mask", "scatter", "scatter"]
+    # a layout changes nothing at or under the mask form's bound
+    assert [form(L, 128) for L in (1, 256, 257, 4096)] \
+        == ["sum", "mask", "dense", "dense"]
+    top = kernel_mod.DENSE_FORM_MAX_ENTRIES
+    assert form(4096, top // 4096) == "dense"
+    assert form(4096, top // 4096 * 2) == "scatter"
+
+
+def _walks(jaxpr, inside_loop=False, out=None) -> dict:
+    """Scatters and gathers of a traced program, those inside a
+    ``while`` apart: {(primitive, inside a loop): count}."""
+    out = {} if out is None else out
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name.startswith(("scatter", "gather")):
+            out[name, inside_loop] = out.get((name, inside_loop), 0) + 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _walks(sub, inside_loop or name == "while", out)
+    return out
+
+
+def test_the_dense_program_walks_the_rows_five_times_and_never_in_a_step():
+    """Four columns into the layout, x back; the scatter form of the
+    same program gathers and scatter-adds inside both searches."""
+    nodes, group, L, hier = wide_tree_inputs()
+    fn = functools.partial(kernel_mod.plan_group, L=L)
+    dense = _walks(jax.make_jaxpr(fn)(nodes, group, hier=hier).jaxpr)
+    assert dense == {("scatter", False): 4, ("gather", False): 1}
+    scatter = _walks(jax.make_jaxpr(fn)(nodes, group, hier=hier[:2]).jaxpr)
+    assert scatter["scatter-add", True] == 2 == scatter["gather", True]
+    assert sum(scatter.values()) == 14     # each a walk; ten of them once
