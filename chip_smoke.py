@@ -28,6 +28,7 @@ from __future__ import annotations
 
 import argparse
 import contextlib
+import itertools
 import json
 import logging
 import os
@@ -516,6 +517,24 @@ def plan_program_times(smoke, buckets=TIMED_BUCKETS, seed: int = 0) -> None:
             smoke.check((np.asarray(x_rows) == x).all(),
                         "node level differs from the search by rows")
 
+        # one preference over the racks (``harness-100k-ha``): the flat
+        # leaf column comes without a layout, so above 256 racks the
+        # searches take the scatter form
+        wide = search_form(leaves) == "scatter"
+        if wide:
+            with smoke.program(f"plan_group_jit/pref@nb{nb}") as row:
+                k = TIMED_K["hier"]
+                x, _fc, spill = _timed_twice(
+                    row, lambda: plan_group_jit(
+                        nodes._replace(leaf=rack), group_of(k), leaves,
+                        ()))
+                row["form"] = search_form(leaves)
+                x = np.asarray(x)
+                smoke.check(
+                    not spill and x.sum() == k and x.max() <= 1
+                    and within_one(np.bincount(rack, x, racks_in_all)),
+                    "racks unbalanced under one preference")
+
         sin = StrategyInputs(hr_cpu=zeros, hr_mem=zeros, hr_gen=zeros,
                              weights=np.zeros(4, i32), w1=w1, b1=b1,
                              w2=w2, b2=b2)
@@ -531,21 +550,30 @@ def plan_program_times(smoke, buckets=TIMED_BUCKETS, seed: int = 0) -> None:
 
         # the fused scan: spread, spread, binpack and a padded slot; no
         # reservations, so each step sees the last one's totals and no
-        # other change, and the per-group programs say what it must give
+        # other change, and the per-group programs say what it must give.
+        # Then, above 256 racks, the same run with its spread groups
+        # under the rack preference: the run's static ``L`` is the leaf
+        # bucket and every spread group of the scan rides it
         ks = np.asarray(TIMED_K["fused"], i32)
         sids = np.asarray([0, 0, strategy_mod.STRAT_BINPACK, 0], i32)
-        want, total = [], nodes.total_tasks
-        for k, sid in zip(ks, sids):
-            seen = nodes._replace(total_tasks=total,
-                                  res_cap=np.where(nodes.valid,
-                                                   strategy_mod.K_CLAMP,
-                                                   0).astype(i32))
-            x = (plan_strategy_jit(seen, group_of(k), sin, int(sid))
-                 if sid else plan_group_jit(seen, group_of(k), 1, ()))[0]
-            want.append(np.asarray(x))
-            total = total + want[-1]
-        for g in (2, 4):
-            with smoke.program(f"plan_fused_jit/g{g}@nb{nb}") as row, \
+        runs = [("", 1, zeros)] + ([(f"_L{leaves}", leaves, rack)]
+                                   if wide else [])
+        wants = {}
+        for tag, L, leaf_col in runs:
+            want, total = [], nodes.total_tasks
+            for k, sid in zip(ks, sids):
+                seen = nodes._replace(
+                    total_tasks=total, leaf=zeros if sid else leaf_col,
+                    res_cap=np.where(nodes.valid, strategy_mod.K_CLAMP,
+                                     0).astype(i32))
+                x = (plan_strategy_jit(seen, group_of(k), sin, int(sid))
+                     if sid else plan_group_jit(seen, group_of(k), L,
+                                                ()))[0]
+                want.append(np.asarray(x))
+                total = total + want[-1]
+            wants[tag] = want
+        for (tag, L, leaf_col), g in itertools.product(runs, (2, 4)):
+            with smoke.program(f"plan_fused_jit/g{g}{tag}@nb{nb}") as row, \
                     fusedbatch.x64():
                 shared = FusedShared(
                     valid=nodes.valid, ready=nodes.ready,
@@ -560,7 +588,8 @@ def plan_program_times(smoke, buckets=TIMED_BUCKETS, seed: int = 0) -> None:
                     con_exp=np.zeros((g, 1, 2), i32),
                     plat=np.full((g, 1, 4), -1, i32),
                     failures=np.zeros((g, nb), i32),
-                    leaf=np.zeros((g, nb), i32),
+                    leaf=np.where(sids[:g, None] == 0, leaf_col[None, :],
+                                  0).astype(i32),
                     extra_mask=np.ones((g, nb), bool))
                 carry = FusedCarry(
                     total=nodes.total_tasks,
@@ -571,9 +600,10 @@ def plan_program_times(smoke, buckets=TIMED_BUCKETS, seed: int = 0) -> None:
                                       weights=np.zeros((g, 4), i32),
                                       w1=w1, b1=b1, w2=w2, b2=b2)
                 xs = _timed_twice(row, lambda: plan_fused_jit(
-                    shared, groups, carry, 1, strat))[0]
-                smoke.check((np.asarray(xs) == np.stack(want[:g])).all(),
-                            "differs from the per-group programs in order")
+                    shared, groups, carry, L, strat))[0]
+                smoke.check(
+                    (np.asarray(xs) == np.stack(wants[tag][:g])).all(),
+                    "differs from the per-group programs in order")
 
 
 #: (form, rows, segments) of a search step timed alone: PR 33's three

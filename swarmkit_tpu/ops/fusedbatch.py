@@ -333,8 +333,8 @@ class GroupSpec:
 
     __slots__ = ("group", "t", "k", "constraints", "platforms",
                  "pref_descriptor", "wants_plugins", "cpu_d", "mem_d",
-                 "maxrep", "slot", "quota_blocked", "sid", "sname",
-                 "weights")
+                 "maxrep", "slot", "pref_L", "quota_blocked", "sid",
+                 "sname", "weights")
 
     def __init__(self, group: Dict[str, Task], t: Task, k: int,
                  constraints, platforms, pref_descriptor, wants_plugins,
@@ -352,6 +352,7 @@ class GroupSpec:
         self.mem_d = mem_d
         self.maxrep = maxrep
         self.slot = 0    # service slot, assigned at build time
+        self.pref_L = 0  # its own preference's leaf bucket, at build time
         # frozen tenant-quota admission verdict (group_quota_blocked):
         # True builds an all-False quota mask row for this group
         self.quota_blocked = quota_blocked
@@ -559,7 +560,8 @@ def build_run(planner, sched, specs: List[GroupSpec]
             else:
                 leaf, n_values = flat_leaf(infos, nb, sp.pref_descriptor)
             leaves.append(leaf)
-            L = max(L, l_bucket(n_values))
+            sp.pref_L = l_bucket(n_values)
+            L = max(L, sp.pref_L)
         else:
             leaves.append(None)
 

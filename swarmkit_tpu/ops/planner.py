@@ -75,6 +75,14 @@ log = logging.getLogger("tpu-planner")
 #: leaf ladder (``fusedbatch.l_bucket``), so the leaf bucket tells it
 WIDE_TREE_LEAVES = 256
 
+
+def _spread_prefs(t: Task) -> int:
+    """How many spread preferences the task's placement carries."""
+    placement = t.spec.placement
+    return sum(1 for p in (placement.preferences if placement else ())
+               if p.spread)
+
+
 # cached Timer references (Registry.reset() resets in place)
 _PLAN_TIMER = _metrics.timer("swarm_planner_plan_latency")
 _COMPILE_TIMER = _metrics.timer("swarm_planner_compile_latency")
@@ -296,10 +304,10 @@ class _InFlightPlan:
     needs to finish the group once the device triple lands."""
 
     __slots__ = ("sched", "t", "task_group", "decisions", "built",
-                 "plan_t0", "arrays", "bucket", "route")
+                 "plan_t0", "arrays", "bucket", "route", "pref_L")
 
     def __init__(self, sched, t, task_group, decisions, built, plan_t0,
-                 arrays, bucket="", route="group"):
+                 arrays, bucket="", route="group", pref_L=0):
         self.sched = sched
         self.t = t
         self.task_group = task_group
@@ -311,6 +319,9 @@ class _InFlightPlan:
         # stage noted its half under the same key)
         self.bucket = bucket
         self.route = route
+        # the leaf bucket of a group under exactly one spread preference
+        # (0 for every other group): ``stats["pref_groups"]``
+        self.pref_L = pref_L
 
 
 class TPUPlanner:
@@ -367,6 +378,10 @@ class TPUPlanner:
                       "h2d_bytes": 0, "d2h_bytes": 0,
                       "wide_tree_groups": 0, "wide_tree_s": 0.0,
                       "dense_tree_groups": 0,
+                      "pref_groups": 0, "pref_wide_groups": 0,
+                      "fused_wide_runs": 0, "fused_wide_groups": 0,
+                      "fused_wide_s": 0.0,
+                      "leaf_cols_hits": 0, "leaf_cols_builds": 0,
                       "tasks_planned": 0, "plan_seconds": 0.0}
         # the break-even router's two sides (_route_costs): the measured
         # fixed launch overhead (dispatch + D2H round-trip on a minimal
@@ -444,6 +459,15 @@ class TPUPlanner:
                              delta)
         else:
             _metrics.counter(f"swarm_planner_{key}", delta)
+
+    def _count_pref_group(self, pref_L: int) -> None:
+        """A device-planned group, of its own or in a fused run, whose
+        service carries exactly one spread preference (``pref_L``: that
+        preference's leaf bucket; 0 for any other group)."""
+        if pref_L:
+            self._count("pref_groups")
+            if pref_L > WIDE_TREE_LEAVES:
+                self._count("pref_wide_groups")
 
     def _observe_plan(self, dt: float) -> None:
         self.stats["plan_seconds"] += dt
@@ -1063,9 +1087,10 @@ class TPUPlanner:
             if form == "dense":
                 self._count("dense_tree_groups")
             self.stats["wide_tree_s"] += _time.perf_counter() - _plan_t0
-        handle = _InFlightPlan(sched, t, task_group, decisions, built,
-                               _plan_t0, arrays, bucket=bucket,
-                               route=route)
+        handle = _InFlightPlan(
+            sched, t, task_group, decisions, built, _plan_t0, arrays,
+            bucket=bucket, route=route,
+            pref_L=L if not flat and _spread_prefs(t) == 1 else 0)
         self._inflight.append(handle)
         return handle
 
@@ -1573,6 +1598,7 @@ class TPUPlanner:
                     placed += 1
 
         self._count("groups_planned")
+        self._count_pref_group(handle.pref_L)
         self._count("tasks_planned", placed)
         return True
 
@@ -1773,6 +1799,8 @@ class TPUPlanner:
         FusedRun handle or None when the batch cannot be built or the
         first dispatch fails — the caller falls back group-by-group
         (identical placements; no mirror state was touched here)."""
+        import time as _time
+        _run_t0 = _time.perf_counter()
         try:
             with tracer.span("plan.fused_build", "plan",
                              services=len(specs),
@@ -1799,6 +1827,12 @@ class TPUPlanner:
             return None
         if run.dispatch_dead and run.next_dispatch == 0:
             return None
+        if run.L > WIDE_TREE_LEAVES:
+            # the scheduler thread's wall on the run so far: its densify,
+            # its node state's placement and the first launches
+            # (fetch_fused_chunk adds each wait and the launch after it)
+            self._count("fused_wide_runs")
+            self.stats["fused_wide_s"] += _time.perf_counter() - _run_t0
         self._fused_active = run
         return run
 
@@ -1908,6 +1942,7 @@ class TPUPlanner:
                                  fused_groups=c.count,
                                  service=run.specs[c.start].t.service_id,
                                  label=bucket, route="fused",
+                                 form=search_form(run.L), L=run.L,
                                  h2d_bytes=h2d):
                     with fusedbatch.x64():
                         fn = (self._fused_fn.fused
@@ -1978,6 +2013,8 @@ class TPUPlanner:
         run.last_fetch_end = end
         self._note_inflight(end - c.t0)
         self._dispatch_fused_chunks(run)   # keep the pipeline primed
+        if run.L > WIDE_TREE_LEAVES:
+            self.stats["fused_wide_s"] += _time.perf_counter() - _d2h_t0
         return (np.asarray(xs), np.asarray(fcs), np.asarray(spills),
                 c.start, c.count)
 
@@ -2010,6 +2047,9 @@ class TPUPlanner:
                 del task_group[task_id]
         run.applied = gi + 1
         self._count("groups_fused")
+        self._count_pref_group(spec.pref_L)
+        if run.L > WIDE_TREE_LEAVES:
+            self._count("fused_wide_groups")
         if spec.sid:
             # non-spread group served by the fused device path: same
             # per-strategy route accounting as the per-group kernel

@@ -179,8 +179,9 @@ class ResidentState:
         #: planner._node_value — constraint-key lookup per NodeInfo
         self._node_value = node_value
         #: planner._count — the resident tier's events that the planner
-        #: accounts for (``tree_cols_*``, the ``h2d_bytes`` of the device
-        #: tier's uploads and scatters) go through its one counter sink
+        #: accounts for (``tree_cols_*``, ``leaf_cols_*``, the ``h2d_bytes``
+        #: of the device tier's uploads and scatters) go through its one
+        #: counter sink
         self._count = count or (lambda key, delta=1: None)
         #: planner mesh (parallel/sharded.py) — when set and the node
         #: bucket divides evenly over it, the device tier lives as
@@ -628,6 +629,9 @@ class ResidentState:
                 leaf[i] = ids.setdefault(v, len(ids))
             entry = (leaf, ids, values)
             self.leaf_cols[descriptor] = entry
+            self._count("leaf_cols_builds")
+        else:
+            self._count("leaf_cols_hits")
         leaf, ids, _values = entry
         return leaf, max(len(ids), 1)
 

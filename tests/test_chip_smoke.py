@@ -170,15 +170,24 @@ def test_plan_programs_are_timed_at_each_bucket_beside_their_pass_line(
     chip_smoke.plan_program_times(
         smoke, buckets=((128, 100, 25), (2048, 1300, 65)), seed=5)
     assert smoke.failures == []
+    # above 256 racks the one-preference program and the fused run at
+    # the leaf bucket are timed too (``harness-100k-ha``, PR 36)
     assert [row["program"] for row in smoke.programs] == [
-        f"{name}@nb{nb}" for nb in (128, 2048) for name in (
+        f"{name}@nb128" for name in (
             "plan_group_jit/flat", "plan_group_jit/hier",
             "plan_strategy_jit/binpack", "plan_fused_jit/g2",
-            "plan_fused_jit/g4")]
+            "plan_fused_jit/g4")] + [
+        f"{name}@nb2048" for name in (
+            "plan_group_jit/flat", "plan_group_jit/hier",
+            "plan_group_jit/pref", "plan_strategy_jit/binpack",
+            "plan_fused_jit/g2", "plan_fused_jit/g4",
+            "plan_fused_jit/g2_L4096", "plan_fused_jit/g4_L4096")]
     assert all(row["ok"] and row["run_s"] >= 0 for row in smoke.programs)
     lines = [line for line in capsys.readouterr().out.splitlines()
              if line.startswith("program plan_")]
-    assert len(lines) == 10
+    assert len(lines) == 13
+    pref = [row for row in smoke.programs if "/pref@" in row["program"]]
+    assert [row["form"] for row in pref] == ["scatter"]
     assert all(re.search(r": PASS compile_s=\S+ wall_s=\S+ run_s=\S+", line)
                for line in lines)
     # the tree's line says the form its searches took and their steps:
