@@ -462,9 +462,9 @@ def plan_program_times(smoke, buckets=TIMED_BUCKETS, seed: int = 0) -> None:
     import jax
     from swarmkit_tpu.ops import fusedbatch
     from swarmkit_tpu.ops.kernel import (
-        FusedCarry, FusedGroups, FusedShared, FusedStrategy, StrategyInputs,
-        plan_fused_jit, plan_group_jit, plan_strategy_jit, search_form,
-        waterfill_search,
+        MASK_FORM_MAX_L, FusedCarry, FusedGroups, FusedShared,
+        FusedStrategy, StrategyInputs, plan_fused_jit, plan_group_jit,
+        plan_strategy_jit, search_form, waterfill_search,
     )
     from swarmkit_tpu.scheduler import strategy as strategy_mod
     i32, i64 = np.int32, np.int64
@@ -517,18 +517,23 @@ def plan_program_times(smoke, buckets=TIMED_BUCKETS, seed: int = 0) -> None:
             smoke.check((np.asarray(x_rows) == x).all(),
                         "node level differs from the search by rows")
 
-        # one preference over the racks (``harness-100k-ha``): the flat
-        # leaf column comes without a layout, so above 256 racks the
-        # searches take the scatter form
-        wide = search_form(leaves) == "scatter"
+        # one preference over the racks (``harness-100k-ha``): above
+        # 256 racks the flat leaf column brings its layout, as
+        # ``fusedbatch.flat_leaf`` hands it to the planner, and the
+        # searches take the dense form
+        wide = leaves > MASK_FORM_MAX_L
         if wide:
+            pref_layout = fusedbatch.leaf_layout(rack, n, leaves)
+            pref_hier = () if pref_layout is None \
+                else ((), None, pref_layout)
             with smoke.program(f"plan_group_jit/pref@nb{nb}") as row:
                 k = TIMED_K["hier"]
                 x, _fc, spill = _timed_twice(
                     row, lambda: plan_group_jit(
                         nodes._replace(leaf=rack), group_of(k), leaves,
-                        ()))
-                row["form"] = search_form(leaves)
+                        pref_hier))
+                row["form"] = search_form(
+                    leaves, pref_layout.W if pref_layout else 0)
                 x = np.asarray(x)
                 smoke.check(
                     not spill and x.sum() == k and x.max() <= 1
@@ -553,13 +558,15 @@ def plan_program_times(smoke, buckets=TIMED_BUCKETS, seed: int = 0) -> None:
         # other change, and the per-group programs say what it must give.
         # Then, above 256 racks, the same run with its spread groups
         # under the rack preference: the run's static ``L`` is the leaf
-        # bucket and every spread group of the scan rides it
+        # bucket, and it ships what ``fusedbatch.build_run`` ships (the
+        # groups' slot rows under the run's ``W`` where the layout came:
+        # the dense form; the padded slot runs the ``L == 1`` program)
         ks = np.asarray(TIMED_K["fused"], i32)
         sids = np.asarray([0, 0, strategy_mod.STRAT_BINPACK, 0], i32)
-        runs = [("", 1, zeros)] + ([(f"_L{leaves}", leaves, rack)]
-                                   if wide else [])
+        runs = [("", 1, zeros, ())] + (
+            [(f"_L{leaves}", leaves, rack, pref_hier)] if wide else [])
         wants = {}
-        for tag, L, leaf_col in runs:
+        for tag, L, leaf_col, hier in runs:
             want, total = [], nodes.total_tasks
             for k, sid in zip(ks, sids):
                 seen = nodes._replace(
@@ -568,11 +575,11 @@ def plan_program_times(smoke, buckets=TIMED_BUCKETS, seed: int = 0) -> None:
                                      0).astype(i32))
                 x = (plan_strategy_jit(seen, group_of(k), sin, int(sid))
                      if sid else plan_group_jit(seen, group_of(k), L,
-                                                ()))[0]
+                                                hier))[0]
                 want.append(np.asarray(x))
                 total = total + want[-1]
             wants[tag] = want
-        for (tag, L, leaf_col), g in itertools.product(runs, (2, 4)):
+        for (tag, L, leaf_col, hier), g in itertools.product(runs, (2, 4)):
             with smoke.program(f"plan_fused_jit/g{g}{tag}@nb{nb}") as row, \
                     fusedbatch.x64():
                 shared = FusedShared(
@@ -591,6 +598,14 @@ def plan_program_times(smoke, buckets=TIMED_BUCKETS, seed: int = 0) -> None:
                     leaf=np.where(sids[:g, None] == 0, leaf_col[None, :],
                                   0).astype(i32),
                     extra_mask=np.ones((g, nb), bool))
+                dense = fusedbatch.dense_rows(
+                    [(rack, racks_in_all, hier[2]) if k and not sid
+                     else None for k, sid in zip(ks[:g], sids[:g])],
+                    n, L) if hier else None
+                if dense:
+                    W, rows = dense
+                    leaf, flat = fusedbatch.dense_leaf(rows, g, nb, L, W)
+                    groups = groups._replace(leaf=leaf, flat=flat)
                 carry = FusedCarry(
                     total=nodes.total_tasks,
                     cpu=np.where(nodes.valid, NODE_CPU, 0).astype(i64),
@@ -601,6 +616,7 @@ def plan_program_times(smoke, buckets=TIMED_BUCKETS, seed: int = 0) -> None:
                                       w1=w1, b1=b1, w2=w2, b2=b2)
                 xs = _timed_twice(row, lambda: plan_fused_jit(
                     shared, groups, carry, L, strat))[0]
+                row["form"] = search_form(L, dense[0] if dense else 0)
                 smoke.check(
                     (np.asarray(xs) == np.stack(wants[tag][:g])).all(),
                     "differs from the per-group programs in order")

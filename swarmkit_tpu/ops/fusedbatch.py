@@ -244,23 +244,26 @@ def plugin_mask(t: Task, infos, nb: int) -> np.ndarray:
 
 
 def flat_leaf(infos, nb: int, descriptor: str
-              ) -> Tuple[np.ndarray, int]:
+              ) -> Tuple[np.ndarray, int, Optional[LeafLayout]]:
     """Flat (single-level) spread leaf ids keyed by the raw preference
-    value, first-appearance order.  Returns (leaf [nb], value count)."""
+    value, first-appearance order.  Returns (leaf [nb], value count,
+    the column's ``leaf_layout`` at the count's ``l_bucket``)."""
     from ..scheduler.nodeset import _pref_value
     leaf = np.zeros(nb, np.int32)
     values: Dict[str, int] = {}
     for i, info in enumerate(infos):
         v = _pref_value(info, descriptor) or ""
         leaf[i] = values.setdefault(v, len(values))
-    return leaf, max(len(values), 1)
+    n_values = max(len(values), 1)
+    return leaf, n_values, leaf_layout(leaf, len(infos), l_bucket(n_values))
 
 
 def leaf_layout(leaf: np.ndarray, n: int, L: int) -> Optional[LeafLayout]:
-    """The leaf-major dense layout of a wide tree's leaf level
-    (``kernel.LeafLayout``) over the first ``n`` rows of ``leaf``, the
-    real ones: a row's rank is its place among its leaf's rows in row
-    order, so an appended row takes its leaf's next rank.  None where
+    """The leaf-major dense layout (``kernel.LeafLayout``) of a wide
+    leaf level, a tree's or a flat column's, over the first ``n`` rows
+    of ``leaf``, the real ones: a row's rank is its place among its
+    leaf's rows in row order, so an appended row takes its leaf's next
+    rank.  None where
     the kernel would not take the dense form (``L`` of at most
     ``MASK_FORM_MAX_L``, or a leaf so full that ``L * W`` is over
     ``DENSE_FORM_MAX_ENTRIES``)."""
@@ -275,6 +278,60 @@ def leaf_layout(leaf: np.ndarray, n: int, L: int) -> Optional[LeafLayout]:
     slot = np.full(len(leaf), L * W, np.int32)
     slot[:n] = rows * W + rank
     return LeafLayout(slot, W)
+
+
+def dense_rows(cols, n: int, L: int):
+    """What a fused run of the dense form ships in place of its groups'
+    leaf rows: ``(W, rows)``, ``rows[g]`` group g's slot row i32[nb]
+    under the run's shared ``L`` and its widest ``W`` (None for a group
+    without a preference), from ``cols[g]``, the group's ``flat_leaf``
+    triple or None.  A column laid at another width, or narrower than
+    the run (a preference over 256 values or fewer has no layout of its
+    own: it is laid here, at ``L``), is relaid to the one ``[L, W]``:
+    leaf and rank stay, ``W`` and the padding rows' no-slot move.
+    None where the run keeps the row forms: ``L`` of at most
+    ``MASK_FORM_MAX_L``, or some column over
+    ``DENSE_FORM_MAX_ENTRIES`` at ``L`` times the widest ``W``."""
+    if search_form(L, 1) != "dense":
+        return None
+    laid = {}     # id(leaf) -> (layout, the L its no-slot was set for)
+    for col in cols:
+        if col is None or id(col[0]) in laid:
+            continue
+        leaf, n_values, layout = col
+        own_L = l_bucket(n_values)
+        if layout is None:
+            layout, own_L = leaf_layout(leaf, n, L), L
+            if layout is None:
+                return None
+        laid[id(leaf)] = layout, own_L
+    W = max(layout.W for layout, _own_L in laid.values())
+    if search_form(L, W) != "dense":
+        return None
+    rows = {}
+    for key, (layout, own_L) in laid.items():
+        if (layout.W, own_L) == (W, L):
+            rows[key] = layout.slot
+            continue
+        slot = np.full(len(layout.slot), L * W, np.int32)
+        own = layout.slot[:n]
+        slot[:n] = own // layout.W * W + own % layout.W
+        rows[key] = slot
+    return W, [None if col is None else rows[id(col[0])] for col in cols]
+
+
+def dense_leaf(rows, gb: int, nb: int, L: int, W: int):
+    """A chunk's ``FusedGroups.leaf`` and ``.flat`` in the dense form
+    from its groups' ``dense_rows``: one `LeafLayout`, slot
+    i32[gb, nb]; a group without a row (no preference, another
+    strategy, a padded slot) has no slot anywhere and is ``flat``."""
+    slot = np.full((gb, nb), L * W, np.int32)
+    flat = np.ones(gb, bool)
+    for j, row in enumerate(rows):
+        if row is not None:
+            slot[j] = row
+            flat[j] = False
+    return LeafLayout(slot, W), flat
 
 
 def tree_inputs(segs, level_ids, n: int):
@@ -455,11 +512,12 @@ class FusedRun:
 
     __slots__ = ("sched", "specs", "cols", "shared", "carry", "chunks",
                  "next_dispatch", "next_fetch", "last_fetch_end", "L",
-                 "nb", "cc", "pb", "sb", "has_quota", "has_strat",
+                 "W", "nb", "cc", "pb", "sb", "has_quota", "has_strat",
                  "aborted", "dispatch_dead", "applied")
 
     def __init__(self, sched, specs, cols, shared, carry, chunks,
-                 L, nb, cc, pb, sb, has_quota=False, has_strat=False):
+                 L, nb, cc, pb, sb, has_quota=False, has_strat=False,
+                 W=0):
         self.sched = sched
         self.specs = specs
         self.cols = cols
@@ -470,6 +528,9 @@ class FusedRun:
         self.next_fetch = 0
         self.last_fetch_end = 0.0   # perf_counter of the last fetch
         self.L = L
+        # the width of the run's one `LeafLayout`; 0 where it ships
+        # none and its searches go by rows
+        self.W = W
         self.nb = nb
         self.cc = cc
         self.pb = pb
@@ -483,6 +544,12 @@ class FusedRun:
     @property
     def n_groups(self) -> int:
         return len(self.specs)
+
+    @property
+    def form(self) -> str:
+        """The form the run's spread groups search their leaf level in
+        (``kernel.search_form``)."""
+        return search_form(self.L, self.W)
 
     def bucket_label(self, chunk: FusedChunk) -> str:
         """Stable jit-signature name for one fused chunk shape."""
@@ -551,19 +618,28 @@ def build_run(planner, sched, specs: List[GroupSpec]
     ts = planner.fail_ts()   # tick-frozen: parity with the per-group path
     fail_idx = list(st.fail_rows) if st is not None else \
         [i for i, info in enumerate(infos) if info.recent_failures]
-    leaves: List[Optional[np.ndarray]] = []
+    leaves: List[Optional[tuple]] = []   # flat_leaf's triple a group
     L = 1
     for sp in specs:
         if sp.pref_descriptor is not None:
             if st is not None:
-                leaf, n_values = st.flat_leaf(sched, sp.pref_descriptor)
+                col = st.flat_leaf(sched, sp.pref_descriptor)
             else:
-                leaf, n_values = flat_leaf(infos, nb, sp.pref_descriptor)
-            leaves.append(leaf)
-            sp.pref_L = l_bucket(n_values)
+                col = flat_leaf(infos, nb, sp.pref_descriptor)
+            leaves.append(col)
+            sp.pref_L = l_bucket(col[1])
             L = max(L, sp.pref_L)
         else:
             leaves.append(None)
+    # the run's form, read off its input (kernel.plan_fused): above 256
+    # leaves the dense one where every preference's column has a layout
+    # within the bound at the run's L and widest W; the layout is the
+    # single-device program's, so an injected fused fn (the mesh's, a
+    # stub) keeps the rows
+    dense = dense_rows(leaves, n, L) \
+        if getattr(planner, "_fused_fn", None) is None else None
+    W, rows = dense or (0, [None if col is None else col[0]
+                            for col in leaves])
 
     shared = FusedShared(valid=valid, ready=ready, os_hash=os_hash,
                          arch_hash=arch_hash, svc0=svc0)
@@ -610,7 +686,10 @@ def build_run(planner, sched, specs: List[GroupSpec]
         con_exp = np.zeros((gb, cc, 2), np.int32)
         plat = np.full((gb, pb, 4), -1, np.int32)
         failures = np.zeros((gb, nb), np.int32)
-        leaf = np.zeros((gb, nb), np.int32)
+        if dense:
+            leaf, flat = dense_leaf(rows[start:start + count], gb, nb, L, W)
+        else:
+            leaf, flat = np.zeros((gb, nb), np.int32), None
         extra = np.ones((gb, nb), bool)
         quota = np.ones((gb, nb), bool) if has_quota else None
         sid = np.zeros(gb, np.int32) if has_strat else None
@@ -643,8 +722,8 @@ def build_run(planner, sched, specs: List[GroupSpec]
                 fill_platforms(sp.platforms, plat[j])
             for i in fail_idx:
                 failures[j, i] = infos[i].count_recent_failures(ts, sp.t)
-            if leaves[start + j] is not None:
-                leaf[j] = leaves[start + j]
+            if not dense and rows[start + j] is not None:
+                leaf[j] = rows[start + j]
             if sp.wants_plugins:
                 extra[j] = plugin_mask(sp.t, infos, nb)
         chunks.append(FusedChunk(
@@ -652,7 +731,8 @@ def build_run(planner, sched, specs: List[GroupSpec]
             FusedGroups(k=k, slot=slot, maxrep=maxrep, cpu_d=cpu_d,
                         mem_d=mem_d, con_hash=con_hash, con_op=con_op,
                         con_exp=con_exp, plat=plat, failures=failures,
-                        leaf=leaf, extra_mask=extra, quota_ok=quota),
+                        leaf=leaf, extra_mask=extra, quota_ok=quota,
+                        flat=flat),
             tasks,
             strat=(FusedStrategy(sid=sid, weights=weights, w1=lw1,
                                  b1=lb1, w2=lw2, b2=lb2)
@@ -661,4 +741,4 @@ def build_run(planner, sched, specs: List[GroupSpec]
 
     return FusedRun(sched, specs, cols, shared, carry, chunks,
                     L, nb, cc, pb, sb, has_quota=has_quota,
-                    has_strat=has_strat)
+                    has_strat=has_strat, W=W)

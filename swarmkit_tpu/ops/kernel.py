@@ -86,10 +86,11 @@ reduced sums, so the loop's predicate is the same on every shard.
 What a step costs follows the static L and, above MASK_FORM_MAX_L,
 whether the layout came (`search_form`; the table over
 `MASK_FORM_MAX_L`): a reduction in three of the four forms, a walk of
-every row (a scatter-add and a gather) only where a tree of more than
-256 leaves came without its layout or the caller reduces across
-shards.  The dense form walks the rows five times a program (four
-columns in, x out), not twice a step.
+every row (a scatter-add and a gather) only where a leaf level of more
+than 256 segments, a tree's or a one-preference spread's flat column,
+is over the layout's bound (one giant leaf among thousands), or the
+caller reduces across shards.  The dense form walks the rows five
+times a program (four columns in, x out), not twice a step.
 
 Resource accounting is **exact**: the host densifier compares int64
 nano-cpus/bytes and floor-divides in int64 (matching the reference's integer
@@ -202,14 +203,18 @@ def _member(seg: jnp.ndarray, L: int) -> jnp.ndarray:
 #            mask, 7 ps an entry: 30.4 us at 16,384 rows and L = 256,
 #            9x faster than the scatter there
 #   dense    above, with a `LeafLayout` of at most
-#            DENSE_FORM_MAX_ENTRIES slots: the columns are laid
+#            DENSE_FORM_MAX_ENTRIES slots, which both builders of a
+#            leaf level bring (a tree's, a flat column's, the latter
+#            of its own and inside a fused run): the columns are laid
 #            leaf-major [L, W] once a program (a walk each, 0.7-0.8 ms
 #            at 131,072 rows, and 1.1 ms for x back) and a step reduces
 #            along axis 1 and broadcasts back: 3.8 us at [4096, 128],
 #            7 ps a slot
-#   scatter  above, without one (or a caller with a `reduce`): N * L
-#            mask entries cost more than the walk, so scatter and
-#            gather: 2,084 us at 131,072 rows and L = 4,096
+#   scatter  above, where the level is over that bound (no layout is
+#            built: `fusedbatch.leaf_layout`) or the caller has a
+#            `reduce` (the sharded twins get none): N * L mask entries
+#            cost more than the walk, so scatter and gather: 2,084 us
+#            at 131,072 rows and L = 4,096
 MASK_FORM_MAX_L = 256
 # a dense column is 4 * L * W bytes and a program holds some eight of
 # them (four laid, the step's temporaries, x): 2^24 slots are 64 MiB a
@@ -223,7 +228,8 @@ DENSE_FORM_MAX_ENTRIES = 1 << 24
 
 @jax.tree_util.register_pytree_node_class
 class LeafLayout:
-    """Where each row of a wide tree's leaf level sits in the leaf-major
+    """Where each row of a wide leaf level (a multi-level tree's, or the
+    flat column of a one-preference spread) sits in the leaf-major
     dense layout ``[L, W]`` (row ``l`` holds the nodes of leaf ``l``):
     ``slot`` i32[N] = ``leaf * W + rank``, the rank a row's place among
     its leaf's rows in row order, and ``L * W`` (no slot: dropped on the
@@ -231,8 +237,12 @@ class LeafLayout:
     which have no room and no tasks.  ``W`` is static, a power of two at
     or above the fullest leaf's population: it rides as the pytree's
     aux data, so a leaf that outgrows it is a new jit signature, as a
-    node bucket that grows is.  Built by ``fusedbatch.tree_inputs`` and
-    carried as the third element of ``hier``."""
+    node bucket that grows is.  Built by ``fusedbatch.leaf_layout`` for
+    ``fusedbatch.tree_inputs`` (the tree) and ``fusedbatch.flat_leaf``
+    (the flat column), kept by row in the resident tier
+    (``ops/streaming.py``) and carried as the third element of
+    ``hier``; a fused run of the dense form carries its groups' slot
+    rows as one layout, ``slot`` i32[G, N] (``FusedGroups.leaf``)."""
 
     def __init__(self, slot, W: int):
         self.slot = slot
@@ -588,8 +598,10 @@ def plan_group(nodes: NodeInputs, group: GroupInputs, L: int,
     # hier = (upper_levels, leaf_parent[, layout]):
     #   upper_levels — tuple of (seg_nodes i32[N], parent i32[L_d]) pairs,
     #   top level first, for every level ABOVE the leaves;
-    #   leaf_parent  — i32[L] mapping each leaf to its upper-level branch;
-    #   layout       — the leaf level's `LeafLayout`, of a wide tree only.
+    #   leaf_parent  — i32[L] mapping each leaf to its upper-level branch
+    #   (None under one preference: the root is every leaf's parent);
+    #   layout       — the leaf level's `LeafLayout`, of a wide level only
+    #   (a one-preference spread's hier is ``((), None, layout)``).
     upper_levels, leaf_parent, *layout = hier if hier else ((), None)
     dense = bool(layout) and search_form(L, layout[0].W, reduce) == "dense"
     # what the leaf level reads, by rows under nodes.leaf or laid dense
@@ -891,12 +903,19 @@ class FusedGroups(NamedTuple):
     con_exp: jnp.ndarray      # i32[G, Cc, 2]
     plat: jnp.ndarray         # i32[G, P, 4] (-1 row sentinel = unused)
     failures: jnp.ndarray     # i32[G, N] recent failures for the group
-    leaf: jnp.ndarray         # i32[G, N] spread leaf id (0 when no prefs)
+    leaf: jnp.ndarray         # i32[G, N] spread leaf id (0 when no prefs);
+    #                           in a run of the dense form (`plan_fused`)
+    #                           the groups' `LeafLayout` in its place,
+    #                           slot i32[G, N] under the run's one W
     extra_mask: jnp.ndarray   # bool[G, N] plugin/volume masks
     # tenant-quota mask rows: all-False rows for groups whose tenant
     # was exhausted at admission; None when no group in the run is
     # quota-blocked (signature stability for quota-free workloads)
     quota_ok: Optional[jnp.ndarray] = None   # bool[G, N] or None
+    # a run of the dense form only (None in every other, whose
+    # signatures it leaves alone): the group has no preference of its
+    # own, so no row of the layout, and runs the L == 1 program
+    flat: Optional[jnp.ndarray] = None       # bool[G] or None
 
 
 class FusedCarry(NamedTuple):
@@ -955,8 +974,32 @@ def plan_fused(shared: FusedShared, groups: FusedGroups,
     ones instead of breaking the run.  Headroom columns are computed
     from the carry (the same int64 divisions the host densifier runs),
     and hr_gen is the neutral HR_CLAMP because groups demanding
-    generic resources never fuse (probe_group rejects them)."""
+    generic resources never fuse (probe_group rejects them).
+
+    The run's form.  ``L`` is static and shared: the widest leaf bucket
+    of the run's preferences, and up to MASK_FORM_MAX_L every spread
+    group of the scan searches under it by rows (``groups.leaf`` the
+    leaf ids, all 0 for a group without a preference: one usable leaf).
+    Above, ``fusedbatch.build_run`` reads the choice off its input:
+    where every preference group's column has a `LeafLayout` and the
+    run's widest ``W`` keeps ``L * W`` within DENSE_FORM_MAX_ENTRIES,
+    ``groups.leaf`` is that layout (the groups' slot rows in place of
+    their leaf rows, relaid to the one ``W``) and a step hands
+    ``plan_group`` its group's row of it: the dense form.  A spread
+    group without a preference has no row in any ``[L, W]`` layout
+    (``groups.flat``); a ``lax.cond`` runs the ``L == 1`` program for
+    it, which places what the shared-``L`` program places on one usable
+    leaf (``k_branch = min(k, cap)``, no spill) at the flat search's
+    price.  Else (no layout, a run over the bound, a caller with a
+    ``reduce``) the run keeps the scatter form, row for row as before."""
     no_ports = jnp.zeros_like(shared.valid)
+    dense = isinstance(groups.leaf, LeafLayout)
+    if dense:
+        assert search_form(L, groups.leaf.W, reduce) == "dense", \
+            (L, groups.leaf.W)
+        # the dense form reads the layout and the L == 1 program no
+        # leaf at all
+        no_leaf = jnp.zeros(shared.valid.shape, jnp.int32)
 
     def step(state: FusedCarry, xs):
         if strat is None:
@@ -978,17 +1021,29 @@ def plan_fused(shared: FusedShared, groups: FusedGroups,
         nodes = NodeInputs(
             valid=shared.valid, ready=shared.ready, res_ok=res_ok,
             res_cap=res_cap, svc_tasks=svc, total_tasks=state.total,
-            failures=g.failures, leaf=g.leaf, os_hash=shared.os_hash,
-            arch_hash=shared.arch_hash, port_conflict=no_ports,
-            extra_mask=g.extra_mask,
+            failures=g.failures, leaf=no_leaf if dense else g.leaf,
+            os_hash=shared.os_hash, arch_hash=shared.arch_hash,
+            port_conflict=no_ports, extra_mask=g.extra_mask,
             quota_ok=g.quota_ok if groups.quota_ok is not None else None)
         grp = GroupInputs(
             k=g.k, con_hash=g.con_hash, con_op=g.con_op,
             con_exp=g.con_exp, plat=g.plat, maxrep=g.maxrep,
             port_limited=jnp.zeros((), jnp.bool_))
+
+        def _spread():
+            if not dense:
+                return plan_group(nodes, grp, L, reduce=reduce,
+                                  idx_offset=idx_offset)
+            return jax.lax.cond(
+                g.flat,
+                lambda: plan_group(nodes, grp, 1, reduce=reduce,
+                                   idx_offset=idx_offset),
+                lambda: plan_group(nodes, grp, L, reduce=reduce,
+                                   idx_offset=idx_offset,
+                                   hier=((), None, g.leaf)))
+
         if strat is None:
-            x, fail_counts, spill = plan_group(
-                nodes, grp, L, reduce=reduce, idx_offset=idx_offset)
+            x, fail_counts, spill = _spread()
         else:
             sin = StrategyInputs(
                 hr_cpu=_fused_headroom(state.cpu, g.cpu_d),
@@ -996,10 +1051,6 @@ def plan_fused(shared: FusedShared, groups: FusedGroups,
                 hr_gen=jnp.full(res_cap.shape, HR_CLAMP, jnp.int32),
                 weights=g_weights, w1=strat.w1, b1=strat.b1,
                 w2=strat.w2, b2=strat.b2)
-
-            def _spread():
-                return plan_group(nodes, grp, L, reduce=reduce,
-                                  idx_offset=idx_offset)
 
             def _strategy(sid_static):
                 return plan_strategy(nodes, grp, sin, sid_static,

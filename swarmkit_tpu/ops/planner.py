@@ -105,7 +105,8 @@ def _bucket_label(nodes_in, group_in, L: int, hier) -> str:
     """Stable name for one static jit signature: node bucket, constraint
     slots, platform slots, spread leaf bucket, spread depth.  Bounded
     cardinality — every component comes from a fixed bucket ladder."""
-    depth = len(hier[0]) + 1 if hier else 0
+    # a one-preference spread's hier, ((), None, layout), is no tree
+    depth = len(hier[0]) + 1 if hier and hier[1] is not None else 0
     q = "_q1" if nodes_in.quota_ok is not None else ""
     return (f"nb{nodes_in.valid.shape[0]}_cc{group_in.con_hash.shape[0]}"
             f"_p{group_in.plat.shape[0]}_L{L}_h{depth}{q}")
@@ -304,10 +305,11 @@ class _InFlightPlan:
     needs to finish the group once the device triple lands."""
 
     __slots__ = ("sched", "t", "task_group", "decisions", "built",
-                 "plan_t0", "arrays", "bucket", "route", "pref_L")
+                 "plan_t0", "arrays", "bucket", "route", "pref_L",
+                 "form")
 
     def __init__(self, sched, t, task_group, decisions, built, plan_t0,
-                 arrays, bucket="", route="group", pref_L=0):
+                 arrays, bucket="", route="group", pref_L=0, form=""):
         self.sched = sched
         self.t = t
         self.task_group = task_group
@@ -322,6 +324,8 @@ class _InFlightPlan:
         # the leaf bucket of a group under exactly one spread preference
         # (0 for every other group): ``stats["pref_groups"]``
         self.pref_L = pref_L
+        # the form the program's searches take at the leaf level
+        self.form = form
 
 
 class TPUPlanner:
@@ -379,9 +383,11 @@ class TPUPlanner:
                       "wide_tree_groups": 0, "wide_tree_s": 0.0,
                       "dense_tree_groups": 0,
                       "pref_groups": 0, "pref_wide_groups": 0,
+                      "dense_pref_groups": 0,
                       "fused_wide_runs": 0, "fused_wide_groups": 0,
                       "fused_wide_s": 0.0,
                       "leaf_cols_hits": 0, "leaf_cols_builds": 0,
+                      "leaf_cols_invalidations": 0,
                       "tasks_planned": 0, "plan_seconds": 0.0}
         # the break-even router's two sides (_route_costs): the measured
         # fixed launch overhead (dispatch + D2H round-trip on a minimal
@@ -460,14 +466,17 @@ class TPUPlanner:
         else:
             _metrics.counter(f"swarm_planner_{key}", delta)
 
-    def _count_pref_group(self, pref_L: int) -> None:
+    def _count_pref_group(self, pref_L: int, form: str) -> None:
         """A device-planned group, of its own or in a fused run, whose
         service carries exactly one spread preference (``pref_L``: that
-        preference's leaf bucket; 0 for any other group)."""
+        preference's leaf bucket; 0 for any other group), planned by a
+        program whose leaf level searched in ``form``."""
         if pref_L:
             self._count("pref_groups")
             if pref_L > WIDE_TREE_LEAVES:
                 self._count("pref_wide_groups")
+                if form == "dense":
+                    self._count("dense_pref_groups")
 
     def _observe_plan(self, dt: float) -> None:
         self.stats["plan_seconds"] += dt
@@ -1090,7 +1099,8 @@ class TPUPlanner:
         handle = _InFlightPlan(
             sched, t, task_group, decisions, built, _plan_t0, arrays,
             bucket=bucket, route=route,
-            pref_L=L if not flat and _spread_prefs(t) == 1 else 0)
+            pref_L=L if not flat and _spread_prefs(t) == 1 else 0,
+            form=form)
         self._inflight.append(handle)
         return handle
 
@@ -1249,11 +1259,13 @@ class TPUPlanner:
             # (resident leaf column when the streaming plane holds one)
             descriptor = prefs[0].spread.spread_descriptor
             if st is not None:
-                leaf, n_values = st.flat_leaf(sched, descriptor)
+                leaf, n_values, layout = st.flat_leaf(sched, descriptor)
             else:
-                leaf, n_values = fusedbatch.flat_leaf(infos, nb,
-                                                      descriptor)
+                leaf, n_values, layout = fusedbatch.flat_leaf(
+                    infos, nb, descriptor)
             L = _l_bucket(n_values)
+            if layout is not None:
+                hier = ((), None, layout)
         elif prefs:
             # two or more levels: the resident twin of the tree (its
             # level columns kept row-wise, as the flat leaf's) or the walk
@@ -1263,10 +1275,11 @@ class TPUPlanner:
             else:
                 leaf, L, hier = fusedbatch.spread_tree(infos, nb,
                                                        descriptors)
-            if self._plan_fn is not plan_group_jit:
-                # a wide tree's layout is the single-device program's:
-                # an injected plan_fn (the mesh's, a stub) takes the two
-                hier = hier[:2]
+        if len(hier) > 2 and self._plan_fn is not plan_group_jit:
+            # a wide level's layout is the single-device program's: an
+            # injected plan_fn (the mesh's, a stub) takes the two, or
+            # under one preference none
+            hier = hier[:2] if hier[1] is not None else ()
 
         nodes_in = NodeInputs(
             valid=valid, ready=ready, res_ok=res_ok, res_cap=res_cap,
@@ -1598,7 +1611,7 @@ class TPUPlanner:
                     placed += 1
 
         self._count("groups_planned")
-        self._count_pref_group(handle.pref_L)
+        self._count_pref_group(handle.pref_L, handle.form)
         self._count("tasks_planned", placed)
         return True
 
@@ -1942,7 +1955,7 @@ class TPUPlanner:
                                  fused_groups=c.count,
                                  service=run.specs[c.start].t.service_id,
                                  label=bucket, route="fused",
-                                 form=search_form(run.L), L=run.L,
+                                 form=run.form, L=run.L,
                                  h2d_bytes=h2d):
                     with fusedbatch.x64():
                         fn = (self._fused_fn.fused
@@ -2047,7 +2060,7 @@ class TPUPlanner:
                 del task_group[task_id]
         run.applied = gi + 1
         self._count("groups_fused")
-        self._count_pref_group(spec.pref_L)
+        self._count_pref_group(spec.pref_L, run.form)
         if run.L > WIDE_TREE_LEAVES:
             self._count("fused_wide_groups")
         if spec.sid:

@@ -73,7 +73,7 @@ from ..models.types import NodeAvailability, NodeState
 from ..obs import devicetelemetry as _devtel
 from ..utils.metrics import registry as _metrics
 from . import fusedbatch
-from .fusedbatch import SENTINEL, n_bucket, split_hash
+from .fusedbatch import SENTINEL, l_bucket, n_bucket, split_hash
 from .hashing import str_hash
 
 log = logging.getLogger("tpu-streaming")
@@ -171,6 +171,56 @@ class _TreeColumns:
         return True
 
 
+class _LeafColumn:
+    """One cached flat spread column, as ``fusedbatch.flat_leaf`` numbers
+    it: the leaf-id column, the value -> id map, each row's value, and
+    the column's ``LeafLayout`` (None where it has none: 256 values or
+    fewer, or over the bound), kept by row as ``_TreeColumns`` keeps the
+    tree's and laid again (``laid`` False) once a new value moved the
+    leaf bucket or a leaf outgrew the layout's ``W``."""
+
+    __slots__ = ("leaf", "ids", "values", "layout", "laid")
+
+    def __init__(self, nb: int):
+        self.leaf = np.zeros(nb, np.int32)
+        self.ids: Dict[str, int] = {}
+        self.values: List[str] = []
+        self.layout = None
+        self.laid = False
+
+    def append(self, v: str) -> bool:
+        """Row ``len(values)`` joins with value ``v``.  True where a
+        layout that stood was dropped for it: a new jit signature."""
+        i = len(self.values)
+        self.values.append(v)
+        known = len(self.ids)
+        self.leaf[i] = self.ids.setdefault(v, known)
+        if not self.laid:
+            return False
+        if l_bucket(len(self.ids)) != l_bucket(max(known, 1)):
+            # a rung of the leaf ladder: another L, so another layout
+            # (or a first one, past 256 values)
+            self.laid = False
+        elif self.layout is not None:
+            # the kept layout: the row takes its leaf's next rank
+            layout, leaf = self.layout, self.leaf
+            rank = int(np.count_nonzero(leaf[:i] == leaf[i]))
+            if rank < layout.W:
+                layout.slot[i] = leaf[i] * layout.W + rank
+            else:
+                self.laid = False
+        return not self.laid and self.layout is not None
+
+    def inputs(self):
+        """``fusedbatch.flat_leaf``'s triple."""
+        n_values = max(len(self.ids), 1)
+        if not self.laid:
+            self.layout = fusedbatch.leaf_layout(
+                self.leaf, len(self.values), l_bucket(n_values))
+            self.laid = True
+        return self.leaf, n_values, self.layout
+
+
 class ResidentState:
     """Persistent densified node state, refreshed O(churn) per tick."""
 
@@ -206,8 +256,7 @@ class ResidentState:
         self.fail_rows: Dict[int, None] = {}
         self.svc_cols: Dict[str, np.ndarray] = {}
         self.con_cols: Dict[str, _ConColumn] = {}
-        self.leaf_cols: Dict[str, Tuple[np.ndarray, Dict[str, int],
-                                        List[str]]] = {}
+        self.leaf_cols: Dict[str, _LeafColumn] = {}
         self.tree_cols: Dict[Tuple[str, ...], _TreeColumns] = {}
         self.epoch = _UNSET
         self._tracker = None
@@ -461,13 +510,12 @@ class ResidentState:
         entry = self.leaf_cols.get(desc_key)
         if entry is None:
             return   # already invalidated earlier in this absorb pass
-        leaf, ids, values = entry
         v = _pref_value(info, desc_key) or ""
         if append:
-            values.append(v)
-            leaf[i] = ids.setdefault(v, len(ids))
+            if entry.append(v):
+                self._count("leaf_cols_invalidations")
             return
-        if values[i] == v:
+        if entry.values[i] == v:
             return
         # a value change can renumber OTHER rows (leaf ids are
         # first-appearance ordered in row order, and branch index is a
@@ -608,32 +656,27 @@ class ResidentState:
             con_op[ci] = con.operator
             con_exp[ci] = split_hash(str_hash(expected))
 
-    def flat_leaf(self, sched, descriptor: str
-                  ) -> Tuple[np.ndarray, int]:
-        """Streaming twin of ``fusedbatch.flat_leaf`` — leaf ids stay
-        first-appearance ordered in ROW order (a tie-break the kernel
-        reads), so value changes that would renumber rebuild the
-        column."""
+    def flat_leaf(self, sched, descriptor: str):
+        """Streaming twin of ``fusedbatch.flat_leaf`` (its triple,
+        read-only to callers) — leaf ids stay first-appearance ordered
+        in ROW order (a tie-break the kernel reads), so value changes
+        that would renumber rebuild the column.  An appended row
+        extends the column and its ``LeafLayout``, which is laid again
+        here where the row dropped it (``_LeafColumn.append``)."""
         self.absorb(sched)
         entry = self.leaf_cols.get(descriptor)
         if entry is None:
             from ..scheduler.nodeset import _pref_value
             if len(self.leaf_cols) >= LEAF_CACHE_CAP:
                 self.leaf_cols.pop(next(iter(self.leaf_cols)))
-            leaf = np.zeros(self.nb, np.int32)
-            ids: Dict[str, int] = {}
-            values: List[str] = []
-            for i, info in enumerate(self.infos):
-                v = _pref_value(info, descriptor) or ""
-                values.append(v)
-                leaf[i] = ids.setdefault(v, len(ids))
-            entry = (leaf, ids, values)
+            entry = _LeafColumn(self.nb)
+            for info in self.infos:
+                entry.append(_pref_value(info, descriptor) or "")
             self.leaf_cols[descriptor] = entry
             self._count("leaf_cols_builds")
         else:
             self._count("leaf_cols_hits")
-        leaf, ids, _values = entry
-        return leaf, max(len(ids), 1)
+        return entry.inputs()
 
     def spread_tree(self, sched, descriptors: Tuple[str, ...]):
         """Streaming twin of ``fusedbatch.spread_tree`` for two or more

@@ -186,8 +186,15 @@ def test_plan_programs_are_timed_at_each_bucket_beside_their_pass_line(
     lines = [line for line in capsys.readouterr().out.splitlines()
              if line.startswith("program plan_")]
     assert len(lines) == 13
-    pref = [row for row in smoke.programs if "/pref@" in row["program"]]
-    assert [row["form"] for row in pref] == ["scatter"]
+    # the three lines at the leaf bucket print the form their program
+    # took: the flat column's layout came, of its own and in the run
+    pref = [row for row in smoke.programs if "/pref@" in row["program"]
+            or "_L4096@" in row["program"]]
+    assert [row["form"] for row in pref] == ["dense"] * 3
+    assert [row["form"] for row in smoke.programs
+            if re.search(r"fused_jit/g\d@", row["program"])] == ["sum"] * 4
+    assert all(re.search(r" form=dense\b", line) for line in lines
+               if "/pref@" in line or "_L4096@" in line)
     assert all(re.search(r": PASS compile_s=\S+ wall_s=\S+ run_s=\S+", line)
                for line in lines)
     # the tree's line says the form its searches took and their steps:
