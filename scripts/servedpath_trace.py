@@ -13,8 +13,15 @@ it, ``--service ID`` follows one deploy through it) and prints one
 that fired them, mean debounce, tasks a tick), where the thread's time
 went (``sched.tick`` / ``sched.debounce`` / ``sched.idle``), the self
 time of ``sched.tick`` and its children's sum, the commit stages against
-``sched.commit``, the CPU seconds by thread, and the largest service of
-the window (to follow).
+``sched.commit``, the update lock's waits by holder and by waiter, the
+legs of a deploy before the tick (``before_the_tick_ms``: the RPC, the
+orchestrator's and the allocator's wait and work, each with what its
+thread waited for the lock and spent off the CPU, as means over spans
+and, weighted by the tasks a span carried, as means over tasks; with the
+client's side of the same path at the median: the generator's lateness
+and the lag to PENDING), the traced window's own ``assign_p50_ms`` (a
+``--trace 1`` line has the per-layer metrics alone), the CPU seconds by
+thread, and the largest service of the window (to follow).
 
 ``--kept`` runs a cell that ``BENCHMARK.json`` does not list, kept as data
 files (``tests/benchmark/rehearse_cells.py KEPT``), through
@@ -31,6 +38,7 @@ import json  # noqa: E402
 import logging  # noqa: E402
 import os  # noqa: E402
 import re  # noqa: E402
+import statistics  # noqa: E402
 import sys  # noqa: E402
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -38,9 +46,52 @@ sys.path.insert(0, ROOT)
 sys.path.insert(0, os.path.join(ROOT, "tests", "benchmark"))
 
 
-def summary(doc: dict) -> dict:
-    """The ``servedpath`` line's object, from the tracer's document."""
-    from swarmkit_tpu.obs.report import phase_table, x_events
+def _mean(rows: list, weights: list = None):
+    """Mean of ``rows``, weighted where ``weights`` are given; None of
+    nothing."""
+    weights = [1] * len(rows) if weights is None else weights
+    total = sum(weights)
+    return sum(r * w for r, w in zip(rows, weights)) / total \
+        if total else None
+
+
+def before_the_tick(events: list) -> dict:
+    """A deploy's legs from ``create_service`` to PENDING, in ms: for
+    each of the three threads its spans' mean duration (``ms``), the age
+    of what it took up (``wait_ms``, and the oldest, ``wait_max_ms``),
+    what it waited for the update lock and spent off the CPU; means over
+    spans, as the per-layer metrics read them, and ``*_task_ms``, the same
+    weighted by the tasks a span carried."""
+    def leg(name: str, wait: str = None, tasks: str = None) -> dict:
+        rows = [e for e in events if e["name"] == name]
+        dur = [e["dur"] / 1e3 for e in rows]
+        out = {"spans": len(rows), "ms": _mean(dur)}
+        for arg in ("lock_wait_ms", "offcpu_ms"):
+            out[arg] = _mean([e["args"][arg] for e in rows
+                              if arg in e["args"]])
+        if wait:
+            # a span that several tasks share says how many it carried
+            ages = [e["args"].get(wait, 0.0) for e in rows]
+            n = [e["args"].get(tasks, 0) for e in rows]
+            out.update(
+                wait_ms=_mean(ages),
+                wait_max_ms=max((e["args"].get("wait_max_ms", a)
+                                 for e, a in zip(rows, ages)), default=None),
+                tasks=sum(n), task_ms=_mean(dur, n),
+                wait_task_ms=_mean(ages, n))
+        return out
+    return {"api": leg("api.create_service"),
+            "orchestrator": leg("orchestrator.service", "wait_ms",
+                                "created"),
+            "allocator": leg("allocator.tasks", "wait_mean_ms", "tasks")}
+
+
+def summary(doc: dict, series: dict = None) -> dict:
+    """The ``servedpath`` line's object, from the tracer's document;
+    ``series``: what the harness's clients recorded of the window
+    (``Observations.series``), for the client's side of the legs."""
+    from benchmark.readers import percentile
+    from swarmkit_tpu.obs.report import phase_table, thread_names, x_events
     events = x_events(doc)
     table = phase_table(doc)
     phases = table["phases"]
@@ -82,12 +133,22 @@ def summary(doc: dict) -> dict:
             deploys[sid] = max(deploys.get(sid, 0),
                                e["args"].get("created", 0))
     largest = max(deploys, key=deploys.get) if deploys else None
-    lock_by_holder = {}
+    thread_of = thread_names(doc)
+    lock_by_holder, lock_by_waiter = {}, {}
     for e in events:
         if e["name"] == "store.lock_wait":
             who = e["args"].get("holder") or "(free)"
             lock_by_holder[who] = lock_by_holder.get(who, 0.0) \
                 + e["dur"] / 1e6
+            who = re.sub(r"-\d+$", "", thread_of.get(e["tid"], "?"))
+            lock_by_waiter[who] = lock_by_waiter.get(who, 0.0) \
+                + e["dur"] / 1e6
+    legs = before_the_tick(events)
+    series = series or {}
+    for key, name in (("generator_late_p50_ms", "generator_late_s"),
+                      ("pending_lag_p50_ms", "pending_lag_s")):
+        if series.get(name):
+            legs[key] = 1e3 * statistics.median(series[name])
     return {
         "ticks": len(ticks),
         "ticks_by_cause": by_cause,
@@ -111,6 +172,11 @@ def summary(doc: dict) -> dict:
                       for name in ("sched.commit", "commit.lock_wait",
                                    "commit.apply", "commit.publish")},
         "lock_wait_s_by_holder": lock_by_holder,
+        "lock_wait_s_by_waiter": lock_by_waiter,
+        "before_the_tick_ms": legs,
+        # as the untraced run's line has it (nearest rank)
+        "assign_p50_ms": 1e3 * percentile(series["assign_s"], 50)
+        if series.get("assign_s") else None,
         "thread_cpu_s": dict(sorted(threads.items(),
                                     key=lambda kv: -kv[1])),
         "dropped_spans": (doc.get("otherData") or {}).get(
@@ -156,6 +222,14 @@ def main(argv=None) -> int:
             cell=rehearse_cells.KEPT.get(args.workload))
     from benchmark import harness
     from swarmkit_tpu.obs import tracer
+    # what ``run_cell`` hands the readers and does not return: the
+    # clients' series of the window
+    observed, read_all = [], harness.readers.read_all
+
+    def tapped(cell, obs, per_layer):
+        observed.append(obs)
+        return read_all(cell, obs, per_layer)
+    harness.readers.read_all = tapped
     code, line = harness.run_cell(args.workload, args.seed, args.seconds,
                                   True, t_start=T_START,
                                   rehearsal=rehearsal)
@@ -166,7 +240,9 @@ def main(argv=None) -> int:
     os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
     with open(args.out + ".trace.json", "w") as f:
         json.dump(doc, f, separators=(",", ":"))
-    print("servedpath " + json.dumps(summary(doc)), flush=True)
+    print("servedpath " + json.dumps(
+        summary(doc, observed[-1].series if observed else None)),
+        flush=True)
     return code
 
 

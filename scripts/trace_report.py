@@ -17,8 +17,12 @@ whole trace.
 deltas (A = baseline, B = candidate): the ``obs/report.py`` aggregation
 the tests assert on.
 ``--service ID`` follows one deploy: every span that carries the service
-id (or nests under one that does), from ``api.create_service`` to
-``commit.publish``, in start order with its thread.  The plain table
+id (or nests under one that does), from ``api.create_service`` through
+``orchestrator.service`` and the allocator's batches (``allocator.tasks``,
+where the service's task is the batch's first) to ``commit.publish``, in
+start order with its thread; the first three carry what their thread
+waited for the update lock (``lock_wait_ms``), what it spent off the CPU
+(``offcpu_ms``) and the age of what it took up.  The plain table
 also prints ``self_s`` and ``cpu_s`` per phase, the scheduler loop's time
 between ticks (``sched.idle`` / ``sched.debounce`` / ``sched.events``,
 which are not tick time) and the trace's ``thread_cpu_s``: the CPU

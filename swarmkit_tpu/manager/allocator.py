@@ -15,6 +15,11 @@ port bookkeeping**: published ports are assigned from the dynamic range
 Service allocation materializes ``service.endpoint`` from the endpoint spec;
 task allocation copies the service endpoint onto the task so the scheduler's
 host-port filter sees published ports.
+
+While ``obs.tracer`` is on, each pass that has work is one span on the
+allocator's thread (``allocator.networks``, ``allocator.services``,
+``allocator.tasks``): one a batch, never one a task, and nothing is
+computed for them while it is off.
 """
 
 from __future__ import annotations
@@ -29,8 +34,11 @@ from ..models.types import (
     Endpoint, EndpointSpec, EndpointVIP, IPAMConfig, IPAMOptions,
     NetworkAttachment, PortConfig, PublishMode, TaskState, TaskStatus, now,
 )
+from ..obs.trace import tracer
 from ..state.events import Event, EventCommit, EventSnapshotRestore
-from ..state.store import Batch, ByName, MemoryStore
+from ..state.store import (
+    Batch, ByName, MemoryStore, lock_waited_s, span_waits,
+)
 from ..state.watch import Closed
 from .netdriver import NetworkDriverRegistry
 
@@ -210,6 +218,20 @@ class IPAM:
                 int(ipaddress.ip_address(addr.split("/")[0])))
         except ValueError:
             pass
+
+
+def _taken_up(tasks: Dict[str, Task]) -> dict:
+    """``allocator.tasks``'s arguments about the batch it takes up (the
+    tracer is on): how many tasks of which services, and how long they
+    had waited since the commit that created them (``meta.created_at``)."""
+    ts = now()
+    ids = [t.service_id for t in tasks.values()]
+    ages = [ts - t.meta.created_at for t in tasks.values()
+            if t.meta.created_at] or [0.0]
+    return {"tasks": len(tasks), "service": ids[0],
+            "services": len(set(ids)),
+            "wait_mean_ms": round(1e3 * sum(ages) / len(ages), 3),
+            "wait_max_ms": round(1e3 * max(ages), 3)}
 
 
 class Allocator:
@@ -395,10 +417,15 @@ class Allocator:
     def _tick(self) -> None:
         if self._pending_networks:
             networks, self._pending_networks = self._pending_networks, {}
-            self._allocate_networks(networks)
+            with tracer.span("allocator.networks", "allocator",
+                             networks=len(networks)):
+                self._allocate_networks(networks)
         if self._pending_services:
             services, self._pending_services = self._pending_services, {}
-            self._allocate_services(services)
+            with tracer.span("allocator.services", "allocator",
+                             services=len(services),
+                             service=next(iter(services))):
+                self._allocate_services(services)
         if self._pending_tasks:
             tasks, self._pending_tasks = self._pending_tasks, {}
             self._allocate_tasks(tasks)
@@ -554,7 +581,27 @@ class Allocator:
             log.exception("service allocation batch failed")
 
     def _allocate_tasks(self, tasks: Dict[str, Task]) -> None:
-        def cb(batch: Batch) -> None:
+        """One batch of NEW tasks to PENDING, under one span: what it
+        took up and how long that had waited, what became of it, and
+        what the allocator's thread waited for meanwhile."""
+        with tracer.span("allocator.tasks", "allocator") as sp:
+            if sp is not None:
+                sp.args = _taken_up(tasks)
+                waited0 = lock_waited_s()
+            batch = self._allocate_tasks_inner(tasks)
+            if sp is not None:
+                # an allocated task is the one change it commits; the
+                # deferred are what this thread queued again meanwhile
+                sp.args.update(
+                    allocated=batch.committed if batch else 0,
+                    deferred=len(self._pending_tasks),
+                    flushes=batch.flushes if batch else 0)
+        if sp is not None:
+            span_waits(sp, waited0)
+
+    def _allocate_tasks_inner(self, tasks: Dict[str, Task]
+                              ) -> Optional[Batch]:
+        def cb(batch: Batch) -> Batch:
             for task in tasks.values():
                 def one(tx, task=task):
                     t = tx.get(Task, task.id)
@@ -609,8 +656,10 @@ class Allocator:
                     batch.update(one)
                 except Exception:
                     log.exception("task allocation failed")
+            return batch
 
         try:
-            self.store.batch(cb)
+            return self.store.batch(cb)
         except Exception:
             log.exception("task allocation batch failed")
+            return None

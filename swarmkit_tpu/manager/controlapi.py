@@ -30,7 +30,7 @@ from ..scheduler import strategy as strategy_mod
 from ..state.store import (
     AlreadyExists as StoreExists, ByKind, ByName, ByNamePrefix,
     ByReferencedSecret, ByReferencedConfig, MemoryStore, NameConflict,
-    NotFound as StoreNotFound, SequenceConflict,
+    NotFound as StoreNotFound, SequenceConflict, lock_waited_s, span_waits,
 )
 from ..utils import new_id
 
@@ -417,6 +417,7 @@ class ControlAPI:
             if sp is not None:
                 # the identifier every later span of this deploy carries
                 sp.args = {"service": service.id}
+                waited0 = lock_waited_s()
 
             def cb(tx):
                 self._check_secret_existence(tx, spec)
@@ -428,7 +429,13 @@ class ControlAPI:
             except NameConflict:
                 raise AlreadyExists(
                     f"service {spec.annotations.name} already exists")
-            return self.store.view(lambda tx: tx.get(Service, service.id))
+            created = self.store.view(
+                lambda tx: tx.get(Service, service.id))
+        if sp is not None:
+            # the caller's thread: what it waited for the update lock,
+            # and what it spent off the CPU (the lock, the interpreter)
+            span_waits(sp, waited0)
+        return created
 
     def get_service(self, service_id: str) -> Service:
         s = self.store.view(lambda tx: tx.get(Service, service_id))

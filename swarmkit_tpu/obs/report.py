@@ -152,17 +152,24 @@ def format_table(table: Dict[str, Any]) -> str:
     return "\n".join(lines)
 
 
+def thread_names(doc: Dict[str, Any]) -> Dict[int, str]:
+    """{tid: thread name} from the document's metadata events."""
+    return {e["tid"]: e["args"]["name"]
+            for e in doc.get("traceEvents", ())
+            if e.get("ph") == "M" and e.get("name") == "thread_name"}
+
+
 def follow_service(doc: Dict[str, Any], service: str
                    ) -> List[Dict[str, Any]]:
     """Every span that carries ``service`` (a service id), in start
-    order, with its thread: one deploy from ``api.create_service`` to
+    order, with its thread: one deploy from ``api.create_service``
+    through ``orchestrator.service`` and ``allocator.tasks`` to
     ``commit.publish``.  Spans nested under one that carries it (the
     ``commit.*`` stages under a group's ``sched.commit``, a lock wait
-    under the RPC) belong to it too."""
+    under the RPC) belong to it too.  A span shared by several services
+    (an allocator's batch, a commit) carries the first's id."""
     events = x_events(doc)
-    threads = {e["tid"]: e["args"]["name"]
-               for e in doc.get("traceEvents", ())
-               if e.get("ph") == "M" and e.get("name") == "thread_name"}
+    threads = thread_names(doc)
     by_id = {e["args"]["span_id"]: e for e in events
              if "span_id" in (e.get("args") or {})}
 

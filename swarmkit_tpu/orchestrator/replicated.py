@@ -16,10 +16,12 @@ import threading
 from typing import Dict, List, Optional, Tuple
 
 from ..models.objects import Cluster, Node, Service, Task
-from ..models.types import TaskState
+from ..models.types import TaskState, now
 from ..obs.trace import tracer
 from ..state.events import Event, EventCommit, EventSnapshotRestore
-from ..state.store import Batch, ByName, ByNode, ByService, MemoryStore
+from ..state.store import (
+    Batch, ByName, ByNode, ByService, MemoryStore, lock_waited_s, span_waits,
+)
 from ..state.watch import Closed
 from ..utils.metrics import registry as _metrics
 from . import common
@@ -36,6 +38,12 @@ _RECONCILE_TIMER = _metrics.timer(
     'swarm_orchestrator_reconcile{kind="replicated"}')
 
 
+def _age_ms(stamp: float) -> float:
+    """Milliseconds since a store stamp (``models.types.now``'s clock);
+    0.0 for an object that carries none."""
+    return round(max(0.0, now() - stamp) * 1e3, 3) if stamp else 0.0
+
+
 class Orchestrator:
     def __init__(self, store: MemoryStore,
                  restarts: Optional[RestartSupervisor] = None,
@@ -45,6 +53,10 @@ class Orchestrator:
         self.updater = updater or UpdateSupervisor(store, self.restarts)
         self.cluster: Optional[Cluster] = None
         self.reconcile_services: Dict[str, Service] = {}
+        # service id -> the store stamp of the object whose event queued
+        # it first, kept while the tracer is on: what the
+        # ``orchestrator.service`` span's ``wait_ms`` is the age of
+        self._queued_at: Dict[str, float] = {}
         # [tasks set out to create, store transactions taken] by the
         # reconcile under way: the ``orchestrator.service`` span's counts
         self._made = [0, 0]
@@ -107,6 +119,7 @@ class Orchestrator:
 
     def _resync(self) -> None:
         self.reconcile_services.clear()
+        self._queued_at.clear()
         self.restart_tasks.clear()
 
         def init(tx):
@@ -130,14 +143,16 @@ class Orchestrator:
                 common.set_service_tasks_remove(self.store, obj)
                 self.restarts.clear_service_history(obj.id)
                 self.reconcile_services.pop(obj.id, None)
+                self._queued_at.pop(obj.id, None)
             else:
-                self.reconcile_services[obj.id] = obj
+                self._queue(obj, obj.meta.updated_at)
         elif isinstance(obj, Task):
             if ev.action == "delete":
                 if obj.desired_state <= TaskState.RUNNING and obj.service_id:
                     service = self.store.raw_get(Service, obj.service_id)
                     if common.is_replicated_service(service):
-                        self.reconcile_services[service.id] = service
+                        # a delete leaves no stamp of its own
+                        self._queue(service, None)
                 self.restarts.cancel(obj.id)
             else:
                 self._handle_task_change(obj)
@@ -151,6 +166,15 @@ class Orchestrator:
             if ev.action != "delete":
                 self.cluster = obj
 
+    def _queue(self, service: Service, stamp: Optional[float]) -> None:
+        """Queue ``service`` for the next reconcile.  ``stamp``: when the
+        store stamped the object whose event queues it (None: the event
+        left none, so this instant); the first to queue a service wins,
+        and it is kept only while the tracer is on."""
+        self.reconcile_services[service.id] = service
+        if tracer.enabled:
+            self._queued_at.setdefault(service.id, stamp or now())
+
     def _handle_task_change(self, t: Task) -> None:
         """A task changed (usually agent status): queue restart if it died
         or its node became invalid (reference: tasks.go:120)."""
@@ -163,7 +187,7 @@ class Orchestrator:
                     and t.service_id:
                 service = self.store.raw_get(Service, t.service_id)
                 if common.is_replicated_service(service):
-                    self.reconcile_services[service.id] = service
+                    self._queue(service, t.meta.updated_at)
             return
         n = self.store.raw_get(Node, t.node_id) if t.node_id else None
         service = self.store.raw_get(Service, t.service_id) \
@@ -219,22 +243,35 @@ class Orchestrator:
         if not self.reconcile_services:
             return
         services, self.reconcile_services = self.reconcile_services, {}
+        queued_at, self._queued_at = self._queued_at, {}
         with tracer.span("orchestrator.reconcile", "orchestrator",
                          kind="replicated",
                          services=len(services)) as batch_sp:
             created_all = 0
+            wait_max = 0.0
             with _RECONCILE_TIMER.time():
                 for s in services.values():
                     with tracer.span("orchestrator.service",
                                      "orchestrator", service=s.id) as sp:
+                        if sp is not None:
+                            # the age of the commit that queued it: a
+                            # service behind a large one shows its wait
+                            wait = _age_ms(queued_at.get(s.id)
+                                           or s.meta.updated_at)
+                            wait_max = max(wait_max, wait)
+                            sp.args["wait_ms"] = wait
+                            waited0 = lock_waited_s()
                         made = self._made = [0, 0]
                         self._reconcile(s)
                         if sp is not None:
                             sp.args.update(created=made[0],
                                            batches=made[1])
+                    if sp is not None:
+                        span_waits(sp, waited0)
                     created_all += made[0]
             if batch_sp is not None:
-                batch_sp.args["created"] = created_all
+                batch_sp.args.update(created=created_all,
+                                     wait_max_ms=wait_max)
 
     # ------------------------------------------------------------- reconcile
 

@@ -69,6 +69,33 @@ _BATCH_TIMER = _metrics.timer("swarm_store_batch_latency")
 _BLOCK_COMMIT_TIMER = _metrics.timer("swarm_store_block_commit_latency")
 
 
+# the update lock by waiter: seconds each thread has waited for a store's
+# update lock while the tracer was on (``_TimedLock.acquire`` adds them)
+_waited = threading.local()
+
+
+def lock_waited_s() -> float:
+    """The calling thread's running total of waits for the update lock,
+    short ones too, summed while the tracer is on and no time source is
+    installed.  A span's owner reads it at the span's start and again at
+    its end (``span_waits``)."""
+    return getattr(_waited, "s", 0.0)
+
+
+def span_waits(sp, waited0: float) -> None:
+    """The two arguments a span reads off the machine, set once it has
+    ended: ``lock_wait_ms``, what its thread waited for the update lock
+    since ``waited0`` (``lock_waited_s()`` at its start), and
+    ``offcpu_ms``, its wall less its thread's CPU.  Both are left out
+    where the span has no ``cpu``: an installed time source."""
+    if sp.cpu is None:
+        return
+    if sp.args is None:
+        sp.args = {}
+    sp.args["lock_wait_ms"] = round((lock_waited_s() - waited0) * 1e3, 3)
+    sp.args["offcpu_ms"] = round((sp.duration - sp.cpu) * 1e3, 3)
+
+
 class _TimedLock:
     """Update-lock wrapper with a lock-age tripwire and hold-time metric
     (reference: memory.go timedMutex — logs when the store wedges)."""
@@ -104,10 +131,12 @@ class _TimedLock:
         # a wait is read off the machine's clock: under an installed time
         # source (the sim) a writer preempted for a millisecond on a
         # loaded host would put a span into a seed-pure trace
-        if wait >= LOCK_WAIT_SPAN_S and tracer.enabled \
-                and not time_source_installed():
-            tracer.record_complete("store.lock_wait", "store", wait,
-                                   holder=holder)
+        if tracer.enabled and not time_source_installed():
+            # every wait, the short ones too, on the waiter's own account
+            _waited.s = getattr(_waited, "s", 0.0) + wait
+            if wait >= LOCK_WAIT_SPAN_S:
+                tracer.record_complete("store.lock_wait", "store", wait,
+                                       holder=holder)
 
     def release(self) -> None:
         held = time.monotonic() - self._acquired_at

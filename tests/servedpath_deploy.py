@@ -4,9 +4,10 @@ per-layer metrics that read it (``benchmark/test_layer_metrics_inside.py``).
 
 One standalone manager with the device scheduler, 48 nodes, a warm-up
 round with the tracer off, then with the tracer on two deploys through the
-control API: one over the device break-even, one under it.  Returns plain
-data; the manager is stopped and the process-wide tracer left off and
-empty."""
+control API: one over the device break-even, one under it, and then a
+stack of two created back to back, which one tick plans as a fused run.
+Returns plain data; the manager is stopped and the process-wide tracer left
+off and empty."""
 
 import threading
 import time
@@ -25,6 +26,9 @@ from test_scheduler import make_ready_node
 
 #: replicas of the deploy the device plans, and of the one the host does
 DEVICE_REPLICAS, HOST_REPLICAS = 160, 6
+#: the debounce gap while a stack's two services are created: both are
+#: PENDING before the tick that takes them, on a loaded runner too
+STACK_GAP_S = 0.3
 
 
 def spec(name: str, replicas: int) -> ServiceSpec:
@@ -51,6 +55,21 @@ def wait_assigned(mgr, service_id: str, replicas: int,
     raise AssertionError(f"{service_id}: not assigned within {timeout}s")
 
 
+def deploy_stack(mgr, prefix: str) -> list:
+    """Two services over the break-even, created back to back under a
+    debounce gap wide enough that one tick holds both groups: a fused
+    run.  Returns their ids, every task assigned."""
+    gap, mgr.scheduler.debounce_gap = mgr.scheduler.debounce_gap, STACK_GAP_S
+    try:
+        ids = [mgr.control_api.create_service(
+            spec(f"{prefix}-{i}", DEVICE_REPLICAS)).id for i in range(2)]
+        for sid in ids:
+            wait_assigned(mgr, sid, DEVICE_REPLICAS)
+    finally:
+        mgr.scheduler.debounce_gap = gap
+    return ids
+
+
 def contend(store, holder: str = "lock-holder",
             hold_s: float = 0.02) -> None:
     """A second thread, named ``holder``, holds the store's update lock
@@ -71,7 +90,8 @@ def contend(store, holder: str = "lock-holder",
 
 def traced_deploy() -> dict:
     """{"spans": [(thread, name, start, end, args, span_id, parent_id,
-    cpu)], "services": {"device": id, "host": id}, "counters":
+    cpu)], "services": {"device": id, "host": id, "stack": [id, id]},
+    "counters":
     {"scheduler.stats": growth}, "wall": (t0, t1), "doc": chrome trace,
     "stats": the scheduler's counters at the end}."""
     # nodes without an agent must not be marked DOWN while the test runs
@@ -89,6 +109,7 @@ def traced_deploy() -> dict:
         # warm-up, tracer off: the device program compiles here
         for name, k in (("warm-d", DEVICE_REPLICAS), ("warm-h", 3)):
             wait_assigned(mgr, api.create_service(spec(name, k)).id, k)
+        deploy_stack(mgr, "warm-stack")
         tracer.reset()
         tracer.enable()
         before = {k: v for k, v in mgr.scheduler.stats.items()
@@ -98,6 +119,7 @@ def traced_deploy() -> dict:
         for key, k in (("device", DEVICE_REPLICAS), ("host", HOST_REPLICAS)):
             ids[key] = api.create_service(spec(f"traced-{key}", k)).id
             wait_assigned(mgr, ids[key], k)
+        ids["stack"] = deploy_stack(mgr, "traced-stack")
         contend(mgr.store)
         # let the loop close its last episode (the counters advance there)
         deadline = time.monotonic() + 5.0

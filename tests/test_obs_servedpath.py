@@ -212,12 +212,13 @@ def test_one_deploy_is_followed_from_rpc_to_commit(deploy):
     rows = follow_service(deploy["doc"], sid)
     names = [r["name"] for r in rows]
     assert names[0] == "api.create_service"
-    for name in ("orchestrator.service", "plan.route",
+    for name in ("orchestrator.service", "allocator.tasks", "plan.route",
                  "plan.build_inputs", "plan.dispatch", "plan.d2h",
                  "plan.apply", "sched.finish_group", "sched.commit",
                  "commit.lock_wait", "commit.apply", "commit.publish"):
         assert name in names, (name, names)
     assert names.index("orchestrator.service") \
+        < names.index("allocator.tasks") \
         < names.index("plan.dispatch") < names.index("commit.publish")
     created = [r["args"] for r in rows
                if r["name"] == "orchestrator.service"
