@@ -388,6 +388,7 @@ class TPUPlanner:
                       "fused_wide_s": 0.0,
                       "leaf_cols_hits": 0, "leaf_cols_builds": 0,
                       "leaf_cols_invalidations": 0,
+                      "svc_cols_builds": 0, "svc_col_rows": 0,
                       "tasks_planned": 0, "plan_seconds": 0.0}
         # the break-even router's two sides (_route_costs): the measured
         # fixed launch overhead (dispatch + D2H round-trip on a minimal

@@ -3,10 +3,10 @@
 ``TPUPlanner._build_columns`` re-densifies the whole NodeSet mirror into
 SoA columns every tick — O(cluster) Python work per tick, even when the
 tick's churn touched three nodes.  ``ResidentState`` keeps those columns
-(and the per-group column *precursors*: per-service task counts, node
-platform hashes, constraint hash columns, spread leaves, the level
-columns of multi-level spread trees, failure rows) alive across ticks
-and refreshes only the rows the scheduler's ``DeltaTracker`` marked dirty — the hardware-task-scheduler move of
+(and the per-group column *precursors*: which rows hold an active task
+of which service, node platform hashes, constraint hash columns, spread
+leaves, the level columns of multi-level spread trees, failure rows)
+alive across ticks and refreshes only the rows the scheduler's ``DeltaTracker`` marked dirty — the hardware-task-scheduler move of
 amortizing decision cost across a persistent structure (PAPERS.md: HTS
 1907.00271, DaphneSched 2308.01607).
 
@@ -84,10 +84,8 @@ _REFRESH_TIMER = _metrics.timer("swarm_streaming_refresh_latency")
 #: dirtier than the top bucket re-uploads the columns wholesale
 D_BUCKETS = (16, 256, 4096)
 
-#: per-service column cache bound (FIFO eviction — oldest-built goes
-#: first; deterministic): steady-state workloads cycle a few dozen
-#: services, and an evicted column simply rebuilds on next demand
-SVC_CACHE_CAP = 64
+#: column cache bounds (FIFO eviction — oldest-built goes first;
+#: deterministic): an evicted column simply rebuilds on next demand
 CON_CACHE_CAP = 32
 LEAF_CACHE_CAP = 16
 
@@ -229,9 +227,9 @@ class ResidentState:
         #: planner._node_value — constraint-key lookup per NodeInfo
         self._node_value = node_value
         #: planner._count — the resident tier's events that the planner
-        #: accounts for (``tree_cols_*``, ``leaf_cols_*``, the ``h2d_bytes``
-        #: of the device tier's uploads and scatters) go through its one
-        #: counter sink
+        #: accounts for (``tree_cols_*``, ``leaf_cols_*``, ``svc_cols_builds``
+        #: and ``svc_col_rows``, the ``h2d_bytes`` of the device tier's
+        #: uploads and scatters) go through its one counter sink
         self._count = count or (lambda key, delta=1: None)
         #: planner mesh (parallel/sharded.py) — when set and the node
         #: bucket divides evenly over it, the device tier lives as
@@ -254,7 +252,12 @@ class ResidentState:
         #: mirrors the ``if info.recent_failures`` guard of the per-group
         #: failure loop, so the fill visits the same rows it would
         self.fail_rows: Dict[int, None] = {}
-        self.svc_cols: Dict[str, np.ndarray] = {}
+        #: service id -> the rows that hold an active task of it, and per
+        #: row the services it is entered under: exactly the live
+        #: (service, row) pairs, so a service's column costs its non-zero
+        #: rows and a service that holds nothing costs no row at all
+        self.svc_rows: Dict[str, Dict[int, None]] = {}
+        self.row_svcs: List[tuple] = []
         self.con_cols: Dict[str, _ConColumn] = {}
         self.leaf_cols: Dict[str, _LeafColumn] = {}
         self.tree_cols: Dict[Tuple[str, ...], _TreeColumns] = {}
@@ -272,7 +275,7 @@ class ResidentState:
         self.stats = {"colds": 0, "resyncs": 0, "fallbacks": 0,
                       "incremental": 0, "full": 0, "rows": 0,
                       "dirty_frac": 0.0, "device_syncs": 0,
-                      "svc_evictions": 0, "bytes_avoided": 0,
+                      "bytes_avoided": 0,
                       "shard_syncs": 0, "scatter_failures": 0}
 
     # --------------------------------------------------------- mesh tier
@@ -409,6 +412,7 @@ class ResidentState:
             self.infos.append(info)
             self.node_ids.append(nid)
             self.task_dicts.append(info.tasks)
+            self.row_svcs.append(())
             self.valid[i] = True
             self._recompute_row(i, info, append=True)
             rows.append(i)
@@ -467,15 +471,32 @@ class ResidentState:
             self.fail_rows[i] = None
         else:
             self.fail_rows.pop(i, None)
-        by_svc = info.active_tasks_count_by_service
-        for sid, col in self.svc_cols.items():
-            col[i] = by_svc.get(sid, 0)
+        self._recompute_svc_row(i, info)
         for key in list(self.con_cols):
             self._recompute_con_row(key, i, info)
         for desc_key in list(self.leaf_cols):
             self._recompute_leaf_row(desc_key, i, info, append)
         for descriptors in list(self.tree_cols):
             self._recompute_tree_row(descriptors, i, info, append)
+
+    def _recompute_svc_row(self, i: int, info) -> None:
+        """Row ``i``'s entries of the service index, from its live counts
+        (``NodeInfo`` keeps a service it no longer holds at 0; the index
+        does not, and drops an entry that empties)."""
+        live = tuple([sid for sid, c
+                      in info.active_tasks_count_by_service.items() if c])
+        held = self.row_svcs[i]
+        if live == held:
+            return
+        self.row_svcs[i] = live
+        svc_rows = self.svc_rows
+        for sid in set(held).difference(live):
+            rows = svc_rows[sid]
+            del rows[i]
+            if not rows:
+                del svc_rows[sid]
+        for sid in set(live).difference(held):
+            svc_rows.setdefault(sid, {})[i] = None
 
     def _recompute_platform_row(self, i: int, info) -> None:
         desc = info.node.description
@@ -572,7 +593,8 @@ class ResidentState:
         self.fail_rows = {}
         # column caches rebuild lazily at their new width; a full
         # device upload covers every row, so the host-only backlog dies
-        self.svc_cols = {}
+        self.svc_rows = {}
+        self.row_svcs = [()] * n
         self.con_cols = {}
         self.leaf_cols = {}
         self.tree_cols = {}
@@ -586,19 +608,18 @@ class ResidentState:
     # -------------------------------------------------- cached precursors
 
     def svc_tasks_col(self, sched, service_id: str) -> np.ndarray:
-        """Per-service active-task column (read-only to callers)."""
+        """Per-service active-task column, built from the rows the
+        service index names: O(rows that hold the service), each count
+        read from the row's ``NodeInfo``."""
         self.absorb(sched)
-        col = self.svc_cols.get(service_id)
-        if col is None:
-            if len(self.svc_cols) >= SVC_CACHE_CAP:
-                self.svc_cols.pop(next(iter(self.svc_cols)))
-                self.stats["svc_evictions"] += 1
-            col = np.zeros(self.nb, np.int32)
-            for i, info in enumerate(self.infos):
-                c = info.active_tasks_count_by_service.get(service_id, 0)
-                if c:
-                    col[i] = c
-            self.svc_cols[service_id] = col
+        col = np.zeros(self.nb, np.int32)
+        self._count("svc_cols_builds")
+        rows = self.svc_rows.get(service_id)
+        if rows:
+            infos = self.infos
+            for i in rows:
+                col[i] = infos[i].active_tasks_count_by_service[service_id]
+            self._count("svc_col_rows", len(rows))
         return col
 
     def fill_failures(self, failures: np.ndarray, ts: float, t) -> None:

@@ -88,11 +88,16 @@ def contend(store, holder: str = "lock-holder",
     assert not t.is_alive()
 
 
+def _numbers(stats: dict) -> dict:
+    return {k: v for k, v in stats.items() if isinstance(v, (int, float))}
+
+
 def traced_deploy() -> dict:
     """{"spans": [(thread, name, start, end, args, span_id, parent_id,
     cpu)], "services": {"device": id, "host": id, "stack": [id, id]},
     "counters":
-    {"scheduler.stats": growth}, "wall": (t0, t1), "doc": chrome trace,
+    {"scheduler.stats": growth, "planner.stats": growth}, "wall": (t0, t1),
+    "doc": chrome trace,
     "stats": the scheduler's counters at the end}."""
     # nodes without an agent must not be marked DOWN while the test runs
     mgr = Manager(dispatcher_config=Config_(heartbeat_period=600.0))
@@ -112,8 +117,8 @@ def traced_deploy() -> dict:
         deploy_stack(mgr, "warm-stack")
         tracer.reset()
         tracer.enable()
-        before = {k: v for k, v in mgr.scheduler.stats.items()
-                  if isinstance(v, (int, float))}
+        before = _numbers(mgr.scheduler.stats)
+        planned0 = _numbers(planner.stats)
         t0 = time.time()
         ids = {}
         for key, k in (("device", DEVICE_REPLICAS), ("host", HOST_REPLICAS)):
@@ -128,8 +133,8 @@ def traced_deploy() -> dict:
             time.sleep(0.02)
         time.sleep(0.15)
         t1 = time.time()
-        after = {k: v for k, v in mgr.scheduler.stats.items()
-                 if isinstance(v, (int, float))}
+        after = _numbers(mgr.scheduler.stats)
+        planned = _numbers(planner.stats)
         tracer.disable()
         doc = tracer.to_chrome()
         spans = [(s.thread, s.name, s.start, s.end, s.args, s.span_id,
@@ -140,5 +145,7 @@ def traced_deploy() -> dict:
         mgr.stop()
     return {"spans": spans, "services": ids, "wall": (t0, t1), "doc": doc,
             "counters": {"scheduler.stats": {
-                k: after[k] - before.get(k, 0) for k in after}},
+                k: after[k] - before.get(k, 0) for k in after},
+                "planner.stats": {
+                k: planned[k] - planned0.get(k, 0) for k in planned}},
             "stats": after}

@@ -747,7 +747,9 @@ def test_node_side_csi_staging_with_process_executor(tmp_path):
              timeout=10, msg="task should see the volume path env")
         vol_path = marker.read_text().strip()
         assert os.path.isdir(vol_path), vol_path
-        assert os.path.exists(os.path.join(vol_path, "wrote"))
+        # the task's shell writes the marker first and touches this next
+        poll(lambda: os.path.exists(os.path.join(vol_path, "wrote")),
+             timeout=10, msg="task should write into the volume")
         assert agent.volumes.ready(vid)
 
         # removal: task goes away, node unstages, path is gone

@@ -15,6 +15,7 @@ corrupted resident row cannot hide.
 
 import json
 import os
+import random
 import subprocess
 import sys
 
@@ -33,6 +34,8 @@ from swarmkit_tpu.ops import TPUPlanner
 from swarmkit_tpu.ops.streaming import ResidentState
 from swarmkit_tpu.scheduler import Scheduler
 from swarmkit_tpu.scheduler.deltatrack import DeltaTracker
+from swarmkit_tpu.scheduler.nodeinfo import NodeInfo
+from swarmkit_tpu.scheduler.nodeset import NodeSet
 from swarmkit_tpu.sim.scenario import run_scenario
 from swarmkit_tpu.state import MemoryStore
 from swarmkit_tpu.state.events import (
@@ -318,6 +321,360 @@ def test_resident_columns_match_full_rebuild(frozen_clock):
     ros, rarch = st.platform_hashes()
     np.testing.assert_array_equal(ros, os_h)
     np.testing.assert_array_equal(rarch, arch_h)
+
+
+# ------------------------------------------------- the service index
+
+def _walk_col(infos, nb, sid):
+    """``svc_tasks_col``'s oracle: the walk of every ``NodeInfo`` that
+    the tracker-less loops of ``_build_device_inputs`` and ``build_run``
+    take."""
+    want = np.zeros(nb, np.int32)
+    for i, info in enumerate(infos):
+        c = info.active_tasks_count_by_service.get(sid, 0)
+        if c:
+            want[i] = c
+    return want
+
+
+def _assert_index_is_the_walk(st, sched):
+    """Every service's column from the index is the walk's, byte for
+    byte (one never seen too), and the index then holds exactly the live
+    (service, row) pairs: no stale pair, no emptied entry."""
+    nodes = sched.node_set.nodes
+    sids = {"never-seen"}
+    for info in nodes.values():
+        sids.update(info.active_tasks_count_by_service)
+    for sid in sorted(sids):
+        got = st.svc_tasks_col(sched, sid)
+        # the absorb is the call's: only now is st.infos the mirror's
+        assert [info.node.id for info in st.infos] == list(nodes)
+        want = _walk_col(st.infos, st.nb, sid)
+        assert got.dtype == want.dtype and got.shape == want.shape
+        assert got.tobytes() == want.tobytes(), sid
+    live = {(sid, i) for i, info in enumerate(st.infos)
+            for sid, c in info.active_tasks_count_by_service.items() if c}
+    held = {(sid, i) for sid, rows in st.svc_rows.items() for i in rows}
+    assert held == live
+    assert all(st.svc_rows.values()), "an emptied entry was kept"
+    assert len(st.row_svcs) == st.n
+    assert {(sid, i) for i, row in enumerate(st.row_svcs)
+            for sid in row} == live
+
+
+def _index_sched(n_nodes=24, resident=True):
+    """(store, scheduler, event feed, resident tier) after a cold tick
+    placed ``_build_store``'s three services; without ``resident`` the
+    scheduler has no tracker and the planner walks (no tier: None)."""
+    store, _specs, seqs = _build_store(n_nodes)
+    planner = TPUPlanner()
+    planner.enable_small_group_routing = False
+    sched = Scheduler(store, batch_planner=planner, pipeline_depth=1)
+    _, sub = store.view_and_watch(
+        lambda tx: sched._setup_tasks_list(tx), accepts_blocks=True)
+    if not resident:
+        sched.delta = None
+    assert sched.tick() == sum(seqs.values())
+    return store, sched, sub, planner._streaming
+
+
+def _probe_task(sid, k=0):
+    return Task(id=f"{sid}-x{k:04d}", service_id=sid, slot=1000 + k,
+                desired_state=TaskState.RUNNING,
+                spec=TaskSpec(resources=_RES),
+                spec_version=Version(index=1),
+                status=TaskStatus(state=TaskState.PENDING))
+
+
+def _step_hook_add(store, sched, sub, st, rng):
+    """Tasks of a placed service and of a new one land on some nodes
+    through ``NodeInfo.add_task`` (its hook marks the row)."""
+    infos = list(sched.node_set.nodes.values())
+    for k in range(6):
+        sid = rng.choice(["sva", "hooked", f"hooked{rng.randrange(3)}"])
+        rng.choice(infos).add_task(_probe_task(sid, rng.randrange(10 ** 6)))
+
+
+def _step_hook_remove(store, sched, sub, st, rng):
+    """Some nodes lose a task through ``NodeInfo.remove_task``."""
+    infos = [i for i in sched.node_set.nodes.values() if i.tasks]
+    for info in rng.sample(infos, min(5, len(infos))):
+        info.remove_task(rng.choice(sorted(info.tasks.values(),
+                                           key=lambda t: t.id)))
+
+
+def _step_apply_mid_tick(store, sched, sub, st, rng):
+    """Two groups one after the other inside one tick: the second's
+    column is built over the rows the first's apply marked (the batched
+    mirror arithmetic of ``_apply_assignments``), and every service's
+    is checked between the two and before the tick ends."""
+    planner = sched.batch_planner
+    for sid in ("svb", f"fresh{rng.randrange(10 ** 6)}"):
+        store.update(lambda tx: [
+            tx.create(_probe_task(sid, rng.randrange(10 ** 6)))
+            for _k in range(rng.randrange(3, 9))])
+    _pump(sched, sub)
+    planner.begin_tick(sched)
+    try:
+        for group in list(sched.unassigned_groups.values()):
+            decisions = {}
+            assert planner.schedule_group(sched, group, decisions)
+            assert len(decisions) == len(group)
+            assert sched.delta.pending, "the apply marked no row"
+            _assert_index_is_the_walk(st, sched)
+    finally:
+        planner.end_tick()
+    sched.unassigned_groups.clear()
+
+
+def _step_node_append(store, sched, sub, st, rng):
+    """A node joins (an appended row, no rebuild) and takes tasks."""
+    i = 1 + max(int(nid[1:]) for nid in sched.node_set.nodes)
+    full = st.stats["full"]
+    store.update(lambda tx: tx.create(_mk_node(i)))
+    _pump(sched, sub)
+    st.absorb(sched)
+    assert st.stats["full"] == full and st.node_ids[-1] == f"n{i:04d}"
+    info = sched.node_set.nodes[f"n{i:04d}"]
+    info.add_task(_probe_task("sva", rng.randrange(10 ** 6)))
+    info.add_task(_probe_task("joined", rng.randrange(10 ** 6)))
+
+
+def _step_node_remove(store, sched, sub, st, rng):
+    """A node that holds tasks leaves: later rows shift, a rebuild."""
+    held = [i for i, row in enumerate(st.row_svcs[:-1]) if row]
+    nid = st.node_ids[rng.choice(held)]
+    full = st.stats["full"]
+    store.update(lambda tx: tx.delete(Node, nid))
+    _pump(sched, sub)
+    st.absorb(sched)
+    assert st.stats["full"] == full + 1 and nid not in st.row_of
+
+
+def _step_scale_to_nought(store, sched, sub, st, rng):
+    """Every task of one service goes: its entry goes with the last."""
+    st.absorb(sched)
+    sid = rng.choice(sorted(st.svc_rows))
+    for info in sched.node_set.nodes.values():
+        for t in [t for t in info.tasks.values() if t.service_id == sid]:
+            info.remove_task(t)
+    st.absorb(sched)
+    assert sid not in st.svc_rows
+    assert any(sid in info.active_tasks_count_by_service
+               for info in sched.node_set.nodes.values()), \
+        "NodeInfo keeps the zero count: the index must not"
+
+
+_INDEX_STEPS = {
+    "hook-add": _step_hook_add, "hook-remove": _step_hook_remove,
+    "apply-mid-tick": _step_apply_mid_tick,
+    "node-append": _step_node_append, "node-remove": _step_node_remove,
+    "scale-to-nought": _step_scale_to_nought}
+
+
+@pytest.mark.parametrize("step", sorted(_INDEX_STEPS))
+def test_the_service_index_column_is_the_walks_after(frozen_clock, step):
+    store, sched, sub, st = _index_sched()
+    _assert_index_is_the_walk(st, sched)
+    _INDEX_STEPS[step](store, sched, sub, st, random.Random(39))
+    _assert_index_is_the_walk(st, sched)
+
+
+@pytest.mark.parametrize("seed", [39, 3900, 390039])
+def test_the_service_index_holds_through_a_seeded_sequence(frozen_clock,
+                                                           seed):
+    """Thirty steps drawn by ``seed``, every kind among them, the walk
+    held against every service's column after each."""
+    rng = random.Random(seed)
+    store, sched, sub, st = _index_sched()
+    names = sorted(_INDEX_STEPS)
+    drawn = names + [rng.choice(names) for _ in range(30 - len(names))]
+    rng.shuffle(drawn)
+    for name in drawn:
+        _INDEX_STEPS[name](store, sched, sub, st, rng)
+        _assert_index_is_the_walk(st, sched)
+    assert st.stats["full"] >= 1 + drawn.count("node-remove")
+
+
+def _rebuild_cold(sched, st):
+    st.infos = None
+
+
+def _rebuild_epoch(sched, st):
+    sched._tick_epoch = (sched._tick_epoch or 0) + 1
+    sched.delta.mark(st.node_ids[0])
+
+
+def _rebuild_no_tracker(sched, st):
+    sched.delta = None
+
+
+def _rebuild_tracker_swap(sched, st):
+    sched.delta = DeltaTracker()
+
+
+def _rebuild_divergence(sched, st):
+    # a node in the mirror that no note_add announced
+    node = _mk_node(999)
+    sched.node_set.nodes[node.id] = NodeInfo(node)
+    sched.delta.mark(st.node_ids[0])
+
+
+def _rebuild_swapped_info(sched, st):
+    # the row's NodeInfo replaced, not mutated: another object's counts
+    nid = st.node_ids[3]
+    sched.node_set.nodes[nid] = NodeInfo(sched.node_set.nodes[nid].node)
+    sched.delta.mark(nid)
+
+
+def _rebuild_demanded(reason):
+    def demand(sched, st):
+        sched.delta.require_full(reason)
+    return demand
+
+
+_REBUILDS = {
+    "cold": _rebuild_cold, "epoch": _rebuild_epoch,
+    "no-tracker": _rebuild_no_tracker,
+    "tracker-swap": _rebuild_tracker_swap,
+    "divergence": _rebuild_divergence,
+    "swapped-info": _rebuild_swapped_info,
+    "node-remove": _rebuild_demanded("node-remove"),
+    "add-overflow": _rebuild_demanded("add-overflow"),
+    "resync-store": _rebuild_demanded("resync-store")}
+
+
+@pytest.mark.parametrize("reason", sorted(_REBUILDS))
+def test_a_rebuild_lays_the_service_index_again(frozen_clock, reason):
+    """A ``_rebuild`` by each reason, over a mirror that moved since the
+    index was laid: the index is the new mirror's, nothing of the old."""
+    store, sched, sub, st = _index_sched()
+    rng = random.Random(39)
+    _step_hook_add(store, sched, sub, st, rng)
+    _assert_index_is_the_walk(st, sched)
+    # moved behind the tier's back: only a rebuild can see these
+    for info in list(sched.node_set.nodes.values())[::5]:
+        hook, info.on_dirty = info.on_dirty, None
+        info.add_task(_probe_task("unseen", rng.randrange(10 ** 6)))
+        for t in [t for t in info.tasks.values() if t.service_id == "svc"]:
+            info.remove_task(t)
+        info.on_dirty = hook
+    full = st.stats["full"]
+    _REBUILDS[reason](sched, st)
+    _assert_index_is_the_walk(st, sched)
+    assert st.stats["full"] > full
+    assert "unseen" in st.svc_rows
+
+
+def test_the_node_bucket_overflows_into_a_rebuilt_index(frozen_clock):
+    """The 1,025th node outgrows the 1,024 bucket: the columns and the
+    index are laid again at 2,048."""
+    store, sched, sub, st = _index_sched(n_nodes=1024)
+    assert st.nb == 1024 and st.n == 1024
+    full = st.stats["full"]
+    store.update(lambda tx: tx.create(_mk_node(1024)))
+    _pump(sched, sub)
+    sched.node_set.nodes["n1024"].add_task(_probe_task("joined"))
+    _assert_index_is_the_walk(st, sched)
+    assert (st.nb, st.n, st.stats["full"]) == (2048, 1025, full + 1)
+    assert list(st.svc_rows["joined"]) == [1024]
+
+
+class _Mirror:
+    """As much of a scheduler as the resident tier reads."""
+
+    def __init__(self, n_nodes):
+        self.delta = DeltaTracker()
+        self.node_set = NodeSet()
+        self.node_set.tracker = self.delta
+        for i in range(n_nodes):
+            self.node_set.add_or_update_node(NodeInfo(_mk_node(i)))
+
+
+@pytest.mark.parametrize("n_nodes, nb", [(1000, 1024), (70000, 131072)])
+def test_a_service_that_holds_nothing_reads_no_row(n_nodes, nb):
+    """The first sight of a service costs the rows that hold it: none,
+    at 1,000 nodes and at the 131,072 bucket alike; a placed service's
+    costs its own rows and not the cluster's."""
+    counted = {}
+
+    def count(key, delta=1):
+        counted[key] = counted.get(key, 0) + delta
+    sched = _Mirror(n_nodes)
+    st = ResidentState(lambda info, key: None, device=False, count=count)
+    infos = list(sched.node_set.nodes.values())
+    for info in infos[7::n_nodes // 9]:
+        info.add_task(_probe_task("placed"))
+    col = st.svc_tasks_col(sched, "fresh")
+    assert col.shape == (nb,) and col.dtype == np.int32 and not col.any()
+    assert counted == {"svc_cols_builds": 1}
+    col = st.svc_tasks_col(sched, "placed")
+    assert col.tobytes() == _walk_col(st.infos, nb, "placed").tobytes()
+    assert counted == {"svc_cols_builds": 2, "svc_col_rows": int(col.sum())}
+    assert 9 <= counted["svc_col_rows"] <= 10
+    assert st.stats["full"] == 1 and set(st.svc_rows) == {"placed"}
+
+
+def _second_wave(resident):
+    """(scheduler in a tick, planner, the tick's groups): further tasks
+    of ``_build_store``'s three placed services and a first group of a
+    new one pending, on the resident tier or on the walk."""
+    store, sched, sub, st = _index_sched(resident=resident)
+    assert (st is not None) == resident
+    store.update(lambda tx: [
+        tx.create(_probe_task(sid, k))
+        for sid in ("sva", "svb", "svc", "svd") for k in range(6)])
+    _pump(sched, sub)
+    planner = sched.batch_planner
+    planner.begin_tick(sched)
+    groups = list(sched.unassigned_groups.values())
+    assert len(groups) == 4
+    return sched, planner, groups
+
+
+def test_build_device_inputs_svc_tasks_with_the_tier_and_without(
+        frozen_clock):
+    """``_build_device_inputs``' ``svc_tasks`` from the index and from
+    its tracker-less loop: the same bytes for a placed service and for
+    a new one, the second group's over the first's applied rows."""
+    (sched_r, planner_r, groups_r), (sched_w, planner_w, groups_w) = \
+        _second_wave(True), _second_wave(False)
+    seen = 0
+    for group_r, group_w in zip(groups_r, groups_w):
+        t_r, t_w = (next(iter(g.values())) for g in (group_r, group_w))
+        assert t_r.id == t_w.id
+        got = planner_r._build_device_inputs(sched_r, t_r, len(group_r))
+        want = planner_w._build_device_inputs(sched_w, t_w, len(group_w))
+        assert got[7].svc_tasks.tobytes() == want[7].svc_tasks.tobytes()
+        seen += bool(got[7].svc_tasks.any())
+        for sched, planner, group in ((sched_r, planner_r, group_r),
+                                     (sched_w, planner_w, group_w)):
+            decisions = {}
+            assert planner.schedule_group(sched, group, decisions)
+            assert len(decisions) == len(group)
+    assert seen == 3    # sva, svb, svc hold tasks; svd is a first sight
+    assert planner_w.stats["svc_cols_builds"] == 0
+    assert planner_r.stats["svc_col_rows"] > 0
+
+
+def test_build_run_svc0_with_the_tier_and_without(frozen_clock):
+    """``build_run``'s ``svc0`` from the index, a slot a service, and
+    from its tracker-less loop: the same bytes."""
+    from swarmkit_tpu.ops import fusedbatch
+    runs = []
+    for resident in (True, False):
+        sched, planner, groups = _second_wave(resident)
+        specs = planner.probe_fused_run(sched, groups, 0)
+        assert len(specs) == 4
+        before = planner.stats["svc_cols_builds"]
+        runs.append((fusedbatch.build_run(planner, sched, specs),
+                     planner.stats["svc_cols_builds"] - before))
+    (got, builds_r), (want, builds_w) = runs
+    assert got.shared.svc0.shape == want.shared.svc0.shape == (4, 1024)
+    assert got.shared.svc0.tobytes() == want.shared.svc0.tobytes()
+    assert got.shared.svc0[:3].any(axis=1).all()
+    assert not got.shared.svc0[3].any()
+    assert (builds_r, builds_w) == (4, 0)
 
 
 # ------------------------------------------- resident spread-tree twin
