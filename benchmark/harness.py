@@ -413,21 +413,51 @@ class Profiled:
 
 # -------------------------------------------------------------- counters
 
+#: the sources a counter metric may read (``num`` / ``den`` ``source``)
+#: and the ``window counters`` line prints; the two ``raft.`` ones only
+#: where the configuration runs raft members
+COUNTER_SOURCES = ("planner.stats", "scheduler.stats", "compile_ledger",
+                   "raft.leader", "raft.followers")
+
+
+def _numbers(stats: dict) -> Dict[str, float]:
+    return {k: v for k, v in stats.items() if isinstance(v, (int, float))}
+
+
 def counter_tables(served: Served) -> Dict[str, Dict[str, float]]:
+    """{source: {key: value}} as the run stands.  With raft members, beside
+    the program's planner and scheduler: ``raft.leader``, the
+    ``RaftNode.stats`` of the member the harness drives, and
+    ``raft.followers``, the same keys summed over the others."""
     from swarmkit_tpu.obs import devicetelemetry
     ledger = devicetelemetry.compile_cache_snapshot()
-    stats = served.scheduler.stats
-    return {
-        "planner.stats": {k: v for k, v in served.planner.stats.items()
-                          if isinstance(v, (int, float))},
-        "scheduler.stats": {k: v for k, v in stats.items()
-                            if isinstance(v, (int, float))},
+    tables = {
+        "planner.stats": _numbers(served.planner.stats),
+        "scheduler.stats": _numbers(served.scheduler.stats),
         "compile_ledger": {
             "compiles": sum(r["compiles"] for r in ledger.values()),
             "dispatches": sum(r["hits"] + r["misses"]
                               for r in ledger.values())},
         "compiled": {b: r["compiles"] for b, r in ledger.items()},
     }
+    if served.members is not None:
+        driven = served.members.nodes[served.members.leader]
+        tables["raft.leader"] = _numbers(driven.stats)
+        followers: Dict[str, float] = {}
+        for node in served.members.nodes:
+            if node is not driven:
+                for k, v in _numbers(node.stats).items():
+                    followers[k] = followers.get(k, 0) + v
+        tables["raft.followers"] = followers
+    return tables
+
+
+def counters_line(grown: Dict[str, Dict[str, float]]) -> str:
+    """The ``window counters`` line of stderr: what grew over the window,
+    source by source, of those the run has."""
+    return "window counters " + json.dumps(
+        {src: {k: v for k, v in grown[src].items() if v}
+         for src in COUNTER_SOURCES if src in grown})
 
 
 def growth(before: Dict[str, Dict[str, float]],
@@ -722,10 +752,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
         obs.slice_dispatches = profiled.dispatches()
         obs.peak_bytes_per_s = peaks["hbm_bytes_per_s"] if peaks else None
         metrics = readers.read_all(name, obs, bench["per_layer"])
-        log("window counters " + json.dumps(
-            {src: {k: v for k, v in obs.counters[src].items() if v}
-             for src in ("planner.stats", "scheduler.stats",
-                         "compile_ledger")}))
+        log(counters_line(obs.counters))
         if obs.roofline_of:
             log("trace roofline " + json.dumps(obs.roofline_of))
     else:

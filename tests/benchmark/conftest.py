@@ -1,9 +1,13 @@
 """The two trees every test of ``BENCHMARK.json`` and of a metric's file is
 made on: the repo's benchmark, and the repo's with one more of everything
-(``one_more.py``: a configuration, a traffic file, a cell, its cut, two
-per-layer metrics and three lists one item longer, from new data only),
-which is what a later PR that may edit nothing here hands in.  A test that
-passes on the first tree and fails on the second is a pin."""
+(``one_more.py``: a configuration and its three-manager twin, a traffic
+file, a cell of each with its cut, three per-layer metrics, the added
+cell's name on every list cell 1 reports that a CPU run of it can read and
+the twin's on three, from new data only), which is what a later PR that
+may edit nothing here hands in.  A test that passes on the first tree and
+fails on the second is a pin: a test of a list or of a cell's managers
+holds the cells it names to what their own files say, never a list to a
+fixed set of cells or a position."""
 
 import os
 import sys

@@ -63,7 +63,7 @@ def deploy() -> dict:
 
 def served(cell: str, shrink: dict) -> dict:
     """Build the cell's ``harness.Served`` at its cut, say what it is
-    made of, and stop it."""
+    made of (the managers it started among it), and stop it."""
     from benchmark import cluster, harness, traffic
     entry = {w["name"]: w for w in harness.load_benchmark()["workloads"]}[
         cell]
@@ -73,6 +73,8 @@ def served(cell: str, shrink: dict) -> dict:
     try:
         mgr = made.mgr
         return {"members": made.members is not None,
+                "managers": 1 if made.members is None
+                else len(made.members.nodes),
                 "raft": mgr.raft is not None,
                 "proposer": mgr.store._proposer is not None,
                 "leader": mgr.is_leader,

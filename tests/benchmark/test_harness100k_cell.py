@@ -11,6 +11,7 @@ rehearsal too drives the two-level label at the 4,096 leaf bucket and the
 searches' scatter form) one plain and one traced run go through
 ``harness.run_cell`` in ``rehearse_cells.py``'s process."""
 
+import copy
 import json
 import os
 import subprocess
@@ -25,6 +26,7 @@ sys.path.insert(1, HERE)
 
 from benchmark import cluster, harness, kernel_bytes, traffic, warmup  # noqa: E402
 import contract  # noqa: E402
+import one_more  # noqa: E402
 from rehearse_cells import load_shrinks  # noqa: E402
 
 CELL = "harness-100k.sparse"
@@ -64,26 +66,64 @@ def test_the_cell_is_upstream_s_cluster_under_cell_1_s_mix():
         int(sustained * share) // 50 * 50 for share in (0.8, 0.6, 0.5)}
 
 
-def test_the_cell_lists_what_both_cells_list_and_the_router_s_readings():
+#: the ladder's other two cells
+OTHERS = {"swarm-10k.deploys", "swarm-1k.deploys-1k"}
+
+
+def lists_as_the_ladder_does(bench: dict) -> None:
+    """The cell is on each list both other cells of the ladder are on,
+    and on cell 1's route shares and the router's two sides; the
+    transfer counters list the ladder's three cells, and the wide
+    tree's two list this cell and neither of the others.  What other
+    cells a list holds, and where, is free."""
+    lists = {m["name"]: set(m["workloads"]) for m in bench["per_layer"]}
+    for name, listed in lists.items():
+        if name in NEW:
+            continue
+        assert (CELL in listed) == (OTHERS <= listed or name in (
+            "device_route_pct", "host_route_groups_pct",
+            "route_host_est_ms", "route_device_est_ms")), name
+    for name in ("h2d_mb_per_tick", "d2h_mb_per_tick"):
+        assert lists[name] >= OTHERS | {CELL}, name
+    for name in ("wide_tree_group_ms", "wide_tree_groups_pct"):
+        assert CELL in lists[name] and not OTHERS & lists[name], name
+    e2e = {m["name"]: m for m in bench["end_to_end"]}
+    assert CELL in e2e["assign_p50_ms"]["workloads"]
+
+
+@pytest.mark.parametrize("bench", one_more.TREES, indirect=True)
+def test_the_cell_lists_what_both_cells_list_and_the_router_s_readings(
+        bench):
     """Beside what both cells list: cell 1's route shares (far above the
     crossover) and the break-even's two sides as the router saw them
     (every group launched on its own passes ``plan.route``); not
     ``host_route_ms`` / ``route_switches_per_tick``, which read 0 where
     no group rides the host."""
-    lists = {m["name"]: set(m["workloads"]) for m in BENCH["per_layer"]}
-    others = {"swarm-10k.deploys", "swarm-1k.deploys-1k"}
-    for name, listed in lists.items():
-        if name in NEW:
-            continue
-        assert (CELL in listed) == (others <= listed or name in (
-            "device_route_pct", "host_route_groups_pct",
-            "route_host_est_ms", "route_device_est_ms")), name
-    assert lists["h2d_mb_per_tick"] == lists["d2h_mb_per_tick"] \
-        == others | {CELL}
-    assert lists["wide_tree_group_ms"] == lists["wide_tree_groups_pct"] \
-        == {CELL}
-    e2e = {m["name"]: m for m in BENCH["end_to_end"]}
-    assert CELL in e2e["assign_p50_ms"]["workloads"]
+    lists_as_the_ladder_does(bench)
+
+
+def _spoiled(name, drop=(), add=()):
+    bench = copy.deepcopy(BENCH)
+    entry = {m["name"]: m for m in bench["per_layer"]}[name]
+    for cell in drop:
+        entry["workloads"].remove(cell)
+    entry["workloads"].extend(add)
+    return bench
+
+
+@pytest.mark.parametrize("spoiled", [
+    _spoiled("h2d_mb_per_tick", drop=["swarm-10k.deploys"]),
+    _spoiled("d2h_mb_per_tick", drop=[CELL]),
+    _spoiled("wide_tree_group_ms", add=["swarm-10k.deploys"]),
+    _spoiled("wide_tree_groups_pct", drop=[CELL]),
+    _spoiled("tick_ms", drop=[CELL]),
+    _spoiled("host_route_ms", add=[CELL])],
+    ids=["h2d_without_cell_1", "d2h_without_sparse", "wide_with_cell_1",
+         "wide_without_sparse", "tick_without_sparse", "host_route_ms"])
+def test_a_list_the_ladder_rule_does_not_hold_is_caught(spoiled):
+    lists_as_the_ladder_does(BENCH)
+    with pytest.raises(AssertionError):
+        lists_as_the_ladder_does(spoiled)
 
 
 def test_the_enumeration_at_the_real_size_names_the_wide_tree():

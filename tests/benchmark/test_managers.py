@@ -1,12 +1,16 @@
 """The managers a configuration asks for (``benchmark/managers.py``, PR 40):
 the ``manager`` block read or refused by name, the raft members' delayed
 links, the read-back from every member's store in ``reference.compare``,
-and on the forced CPU at a test's size, in ``rehearse_cells.py``'s process:
-the four cells' served path pinned to the one standalone manager it always
-was, the one-more configuration's three-manager twin run whole and
-correct, and its control, a follower that drops its task writes, not
-correct on the new number alone."""
+the raft members' counters in ``harness.counter_tables``, and on the
+forced CPU at a test's size, in ``rehearse_cells.py``'s process: every
+cell of the one-more tree (the repo's, the added one and its three-manager
+twin) served by the managers its own configuration asks for, so that a
+cell of one manager is pinned to the standalone manager it always was and
+a cell of three to raft members, the twin run whole and correct, and its
+control, a follower that drops its task writes, not correct on the new
+number alone."""
 
+import copy
 import json
 import os
 import re
@@ -14,6 +18,7 @@ import subprocess
 import sys
 import threading
 import time
+import types
 
 import pytest
 
@@ -27,14 +32,26 @@ from benchmark.control import Rehearsal  # noqa: E402
 import contract  # noqa: E402
 import one_more  # noqa: E402
 
-CELLS = [w["name"] for w in harness.load_benchmark()["workloads"]]
+#: the cells of the one-more tree: the repo's, then the two it adds
+SERVED = [w["name"] for w in harness.load_benchmark()["workloads"]] \
+    + [one_more.CELL, one_more.CELL3]
 
 # ------------------------------------------------------ the manager block
 
-def test_every_configuration_asks_for_the_one_manager_it_runs():
-    for cell in harness.load_benchmark()["workloads"]:
-        config = cluster.load_config(cell["config"])
-        assert managers.plan(config["manager"]) == {"managers": 1}
+@pytest.mark.parametrize("bench", one_more.TREES, indirect=True)
+def test_every_configuration_asks_for_the_one_manager_it_runs(bench):
+    """Every configuration's ``manager`` block is one ``managers.plan``
+    honours, and plans the managers it states: exactly the one standalone
+    manager where it says 1, raft members with their settings where it
+    says more."""
+    for entry in bench["configs"]:
+        block = cluster.load_config(entry["name"])["manager"]
+        planned = managers.plan(block)
+        assert planned["managers"] == block["managers"]
+        if block["managers"] == 1:
+            assert planned == {"managers": 1}
+        else:
+            assert set(planned) == {"managers", *managers.RAFT_KEYS}
 
 
 @pytest.mark.parametrize("block,plan", [
@@ -293,14 +310,23 @@ def test_a_leader_change_is_not_correct():
 
 # ------------------------------------------------------------ whole runs
 
+def _block(bench: dict, tree: str, cell: str) -> dict:
+    """The ``manager`` block of the cell's configuration in ``tree``."""
+    config = {w["name"]: w for w in bench["workloads"]}[cell]["config"]
+    with open(os.path.join(tree, "benchmark", "configs",
+                           f"{config}.json")) as f:
+        return json.load(f)["manager"]
+
+
 @pytest.fixture(scope="module")
 def runs(one_more_tree):
     """In one process of ``rehearse_cells.py`` on the one-more tree (the
-    repo's data files byte for byte, and the twin): ``Served`` of each of
-    the four cells at its cut, the twin's plain run and its run under the
-    follower fault.  ({name: (code, line)}, the process's stderr)."""
+    repo's data files byte for byte, and the two cells it adds):
+    ``Served`` of each of its cells at its cut, the twin's plain run and
+    its run under the follower fault.  ({name: (code, line)}, the
+    process's stderr)."""
     tree, _ = one_more_tree
-    names = [f"served:{cell}" for cell in CELLS] + [
+    names = [f"served:{cell}" for cell in SERVED] + [
         f"{one_more.CELL3}:plain", f"{one_more.CELL3}:follower_skips_tasks"]
     done = subprocess.run(
         [sys.executable, os.path.join(HERE, "rehearse_cells.py"),
@@ -312,18 +338,57 @@ def runs(one_more_tree):
     return out, done.stderr
 
 
-@pytest.mark.parametrize("cell", CELLS)
-def test_the_four_cells_serve_from_one_standalone_manager(runs, cell):
-    """``manager.managers`` 1: ``Served`` builds the ``Manager()`` it built
-    before PR 40, with no raft node and a store with no proposer."""
+def served_as_configured(made: dict, block: dict) -> None:
+    """What ``rehearse_cells.served`` says a cell's ``Served`` was made
+    of, against its configuration's ``manager`` block.  ``managers`` 1:
+    the standalone ``Manager()``, with no raft node and a store
+    with no proposer.  3 or more: that many raft members, the driven
+    manager the leader, its store proposing through its raft node."""
+    assert made["managers"] == block["managers"]
+    assert made["leader"] is True
+    assert made["heartbeat_period"] == block["heartbeat_period_s"]
+    one = block["managers"] == 1
+    assert made["members"] is not one and made["raft"] is not one
+    assert made["proposer"] is not one
+
+
+@pytest.mark.parametrize("cell", SERVED)
+def test_the_four_cells_serve_from_one_standalone_manager(runs, cell,
+                                                          one_more_tree):
+    """Each cell of the tree served as its own configuration says: the
+    repo's four and the added cell from the one standalone manager, the
+    twin from three raft members."""
+    tree, bench = one_more_tree
     code, made = runs[0][f"served:{cell}"]
     assert code == 0
-    assert made["members"] is False and made["raft"] is False
-    assert made["proposer"] is False and made["leader"] is True
-    config = cluster.load_config(
-        {w["name"]: w for w in harness.load_benchmark()["workloads"]}[cell][
-            "config"])
-    assert made["heartbeat_period"] == config["manager"]["heartbeat_period_s"]
+    served_as_configured(made, _block(bench, tree, cell))
+
+
+#: (the cell whose configuration is held, what is spoiled in cell 1's or
+#: the twin's ``Served`` as the rehearsal reported it)
+SPOILED = {
+    "one_with_members": ("swarm-10k.deploys", dict(
+        members=True, managers=3, raft=True, proposer=True)),
+    "one_with_a_raft_node": ("swarm-10k.deploys", dict(raft=True)),
+    "one_with_a_proposer": ("swarm-10k.deploys", dict(proposer=True)),
+    "three_standalone": (one_more.CELL3, dict(
+        members=False, managers=1, raft=False, proposer=False)),
+    "three_driving_a_follower": (one_more.CELL3, dict(leader=False)),
+    "three_with_five": (one_more.CELL3, dict(managers=5)),
+}
+
+
+@pytest.mark.parametrize("case", list(SPOILED))
+def test_a_served_that_is_not_its_configuration_s_fails_the_pin(
+        runs, one_more_tree, case):
+    tree, bench = one_more_tree
+    cell, spoil = SPOILED[case]
+    made = copy.deepcopy(runs[0][f"served:{cell}"][1])
+    block = _block(bench, tree, cell)
+    served_as_configured(made, block)
+    made.update(spoil)
+    with pytest.raises(AssertionError):
+        served_as_configured(made, block)
 
 
 def test_the_three_manager_twin_runs_whole_and_every_member_holds_it(runs):
@@ -359,12 +424,85 @@ def test_a_follower_that_drops_its_task_writes_fails_on_that_number_alone(
 
 
 def test_the_runs_leave_no_raft_log_behind(runs, one_more_tree):
+    tree, bench = one_more_tree
     wal_dirs = re.findall(r"wal_dir=(\S+)", runs[1])
-    assert len(wal_dirs) == 2
-    tree = os.path.realpath(one_more_tree[0])
+    # each ``Served`` of raft members, and the twin's two runs
+    assert len(wal_dirs) == 2 + sum(
+        _block(bench, tree, cell)["managers"] > 1 for cell in SERVED)
+    tree = os.path.realpath(tree)
     for path in wal_dirs:
         assert not os.path.exists(path)
         assert not os.path.realpath(path).startswith(tree)
         assert not os.path.realpath(path).startswith(REPO)
     for root, _, files in os.walk(tree):
         assert "wal.jsonl" not in files, root
+
+
+# --------------------------------------------------- the raft counters
+
+def _served(members=None):
+    return types.SimpleNamespace(
+        planner=types.SimpleNamespace(stats={
+            "groups_planned": 3, "h2d_bytes": 1024, "breaker": "closed",
+            "last_error": None, "groups_fused": 0}),
+        scheduler=types.SimpleNamespace(stats={
+            "ticks": 2, "events_handled": 7, "mode": "streaming"}),
+        members=members)
+
+
+@pytest.fixture
+def ledger(monkeypatch):
+    from swarmkit_tpu.obs import devicetelemetry
+    monkeypatch.setattr(devicetelemetry, "compile_cache_snapshot", lambda: {
+        "nb1024_a": {"compiles": 1, "hits": 4, "misses": 1},
+        "stream_b": {"compiles": 0, "hits": 2, "misses": 0}})
+
+
+#: what the parent of the raft sources gave for ``_served()``, key for key
+#: and in its order, and the line it printed
+ONE_MANAGER = {
+    "planner.stats": {"groups_planned": 3, "h2d_bytes": 1024,
+                      "groups_fused": 0},
+    "scheduler.stats": {"ticks": 2, "events_handled": 7},
+    "compile_ledger": {"compiles": 1, "dispatches": 7},
+    "compiled": {"nb1024_a": 1, "stream_b": 0}}
+ONE_MANAGER_LINE = (
+    'window counters {"planner.stats": {"groups_planned": 3, '
+    '"h2d_bytes": 1024}, "scheduler.stats": {"ticks": 2, '
+    '"events_handled": 7}, "compile_ledger": {"compiles": 1, '
+    '"dispatches": 7}}')
+
+
+def test_one_manager_s_counter_tables_and_line_are_as_before(ledger):
+    tables = harness.counter_tables(_served())
+    assert tables == ONE_MANAGER and list(tables) == list(ONE_MANAGER)
+    for src, table in tables.items():
+        assert list(table) == list(ONE_MANAGER[src])
+    assert harness.counters_line(tables) == ONE_MANAGER_LINE
+
+
+def _member(applied, snapshots):
+    return types.SimpleNamespace(stats={
+        "applied": applied, "snapshots": snapshots,
+        "stale_epoch_rejects": 0, "state": "follower"})
+
+
+def test_raft_members_add_the_driven_member_and_the_followers_summed(
+        ledger):
+    members = types.SimpleNamespace(
+        nodes=[_member(40, 1), _member(50, 2), _member(38, 0)], leader=1)
+    tables = harness.counter_tables(_served(members))
+    assert list(tables) == list(ONE_MANAGER) + ["raft.leader",
+                                                "raft.followers"]
+    assert {k: tables[k] for k in ONE_MANAGER} == ONE_MANAGER
+    assert tables["raft.leader"] == {"applied": 50, "snapshots": 2,
+                                     "stale_epoch_rejects": 0}
+    assert tables["raft.followers"] == {"applied": 78, "snapshots": 1,
+                                        "stale_epoch_rejects": 0}
+    grown = harness.growth(harness.counter_tables(_served(members)), tables)
+    assert grown["raft.leader"]["applied"] == 0
+    line = harness.counters_line(tables)
+    assert line.startswith(ONE_MANAGER_LINE[:-1] + ", ")
+    assert line.endswith(
+        '"raft.leader": {"applied": 50, "snapshots": 2}, '
+        '"raft.followers": {"applied": 78, "snapshots": 1}}')
