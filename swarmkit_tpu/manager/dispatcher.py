@@ -11,7 +11,9 @@ Responsibilities (matching the reference):
   node DOWN (dispatcher.go:1317, :29-34).
 * ``open_assignments`` — a stream of COMPLETE + INCREMENTAL assignment
   diffs (tasks >= ASSIGNED on the node, plus referenced secrets/configs),
-  batched 100ms / 100 modifications (dispatcher.go:1013, assignments.go).
+  at most 100 modifications a message, every node's from one fan-out
+  flushed each ``process_updates_interval`` (dispatcher.go:1013,
+  assignments.go).
 * ``update_task_status`` — validated, batched status writeback; status only
   moves forward (dispatcher.go:607, :726).
 * down-node tracking — nodes DOWN longer than ``orphan_timeout`` get their
@@ -20,7 +22,8 @@ Responsibilities (matching the reference):
 Transport: in-process method calls shaped like the gRPC surface (register /
 session stream / assignments stream / unary status updates) so a network
 transport can wrap this object 1:1.  All timers (heartbeat TTLs, orphan
-deadlines, status-update batching) run on one worker thread.
+deadlines, status-update batching) and the assignments fan-out's flushes
+run on one worker thread.
 """
 
 from __future__ import annotations
@@ -42,7 +45,7 @@ from ..obs.trace import tracer
 from ..state import serde as _serde
 from ..state.events import Event, EventSnapshotRestore, EventTaskBlock
 from ..state.store import Batch, ByNode, MemoryStore
-from ..state.watch import Closed, Subscription
+from ..state.watch import Closed
 from ..utils import new_id
 from ..utils.metrics import registry as _metrics
 
@@ -59,7 +62,6 @@ class Config_:
     rate_limit_period: float = 8.0
     process_updates_interval: float = 0.100
     max_batch_items: int = 100
-    assignment_batching_wait: float = 0.100
     modification_batch_limit: int = 100
     orphan_timeout: float = 24 * 3600.0
     # --- overload protection (backpressure plane).  All defaults keep
@@ -168,7 +170,7 @@ def _note_assignment_ship(msg: "AssignmentsMessage") -> None:
 
 class AssignmentStream:
     """Server-side push stream of AssignmentsMessage, one per Assignments
-    call; a thread in the dispatcher feeds it."""
+    call; the dispatcher's fan-out feeds it."""
 
     def __init__(self, node_id: str):
         self.node_id = node_id
@@ -229,8 +231,8 @@ class _AssignmentSet:
         # (reference: assignments.go assignSecret): (task_id, secret_id)
         # -> combined assignment id
         self._secret_alias: Dict[Tuple[str, str], str] = {}
-        # tasks whose driver-secret fetch failed; the assignments loop
-        # retries them on idle ticks until the provider recovers
+        # tasks whose driver-secret fetch failed; the fan-out's flush
+        # retries them until the provider recovers
         self.pending_secret_retry: Set[str] = set()
 
     # --- dependencies
@@ -294,9 +296,9 @@ class _AssignmentSet:
                 d = self.driver_provider.new_secret_driver(obj.spec.driver)
                 value, no_reuse = d.get(obj.spec, t)
             except Exception:
-                # fetch errors skip the assignment; the assignments loop
-                # retries on idle ticks, so the task (shipped without its
-                # secret, hence PREPARING) recovers with the provider
+                # fetch errors skip the assignment; the fan-out's flush
+                # retries, so the task (shipped without its secret, hence
+                # PREPARING) recovers with the provider
                 log.exception("fetching driver secret %s failed",
                               secret_id[:8])
                 self.pending_secret_retry.add(t.id)
@@ -418,25 +420,22 @@ class _AssignmentSet:
         self._release_task_deps(t)
         return True
 
-    def message(self, type_, applies_to, results_in) -> AssignmentsMessage:
-        changes = list(self.changes.values())
-        self.changes = {}
-        return AssignmentsMessage(type_, applies_to, results_in, changes)
-
 
 class BatchedAssignmentFanout:
-    """Batched, threadless assignment fan-out (ISSUE 12 satellite,
-    ROADMAP direction 3 residual).
+    """The dispatcher's one assignments fan-out: every node's stream is
+    served from it, whether there is one session or ten thousand.
 
-    The classic ``open_assignments`` path runs one thread per node
-    stream — fine for five agents, wrong for a thousand, and an
-    autoscaler burst multiplies per-task sends.  This fan-out keeps ONE
-    store subscription, routes events into per-node ``_AssignmentSet``
-    diffs, and ``flush()`` (driven from ``process_deadlines`` — the
-    worker thread in production, the control step in the sim) sends at
-    most ceil(pending / modification_batch_limit) INCREMENTAL messages
-    per node per flush: N task assignments to one node cost
-    <= ceil(N/batch) sends, not N round-trips.
+    ONE store subscription for all of them, and no thread of its own:
+    its predicate passes scheduler blocks, volumes, and a Task event only
+    for a node with an open stream, so a commit that concerns no agent
+    buffers nothing and wakes nobody.  ``flush()`` — driven from
+    ``process_deadlines``, the dispatcher's worker thread at least every
+    ``process_updates_interval`` in production, the control step in the
+    sim — routes what the subscription holds into per-node
+    ``_AssignmentSet`` diffs and sends at most
+    ceil(pending / modification_batch_limit) INCREMENTAL messages per
+    node: N task assignments to one node cost <= ceil(N/batch) sends,
+    not N round-trips.
 
     Leader-gap discipline mirrors the status-flush re-queue machinery:
     diffs accumulate while a stream is down and the re-registered
@@ -459,11 +458,20 @@ class BatchedAssignmentFanout:
         self._seq: Dict[str, int] = {}
         self._applies: Dict[str, str] = {}
         self.stats = {"sends": 0, "complete_sends": 0, "compactions": 0}
-        self._sub = dispatcher.store.queue.subscribe(
-            lambda ev: isinstance(ev, EventTaskBlock)
-            or (isinstance(ev, Event)
-                and isinstance(ev.obj, (Task, Volume))),
-            accepts_blocks=True)
+        self._sub = dispatcher.store.queue.subscribe(self._wants,
+                                                     accepts_blocks=True)
+
+    def _wants(self, ev) -> bool:
+        """The subscription's predicate.  It runs on the committing
+        writer's thread, so it stays O(1) whatever the session count."""
+        if isinstance(ev, EventTaskBlock):
+            return True   # routed per node at flush, off the block's grouping
+        if not isinstance(ev, Event):
+            return False
+        obj = ev.obj
+        if isinstance(obj, Task):
+            return obj.node_id in self._sets
+        return isinstance(obj, Volume)   # matched to tracked deps at flush
 
     # ------------------------------------------------------------- streams
 
@@ -489,18 +497,27 @@ class BatchedAssignmentFanout:
                 if rn is None or rn.session_id != session_id:
                     raise ErrSessionInvalid(node_id)
                 rn.streams.append(stream)
-            initial = self.d.store.view(
-                lambda vx: list(vx.find(Task, ByNode(node_id))))
-            tx = self.d.store.view()
-            for t in initial:
-                aset.add_or_update_task(tx, t)
+            # registered before the snapshot: from here on the predicate
+            # keeps this node's Task events, so a commit the snapshot
+            # misses reaches the next flush (one in both is re-added,
+            # which is idempotent)
             with self._mu:
                 self._sets[node_id] = aset
                 self._streams[node_id] = stream
                 self._seq[node_id] = 0
                 self._applies[node_id] = ""
-            self._send(node_id, aset, stream,
-                       AssignmentsMessage.COMPLETE)
+            try:
+                initial = self.d.store.view(
+                    lambda vx: list(vx.find(Task, ByNode(node_id))))
+                tx = self.d.store.view()
+                for t in initial:
+                    aset.add_or_update_task(tx, t)
+                self._send(node_id, aset, stream,
+                           AssignmentsMessage.COMPLETE)
+            except BaseException as e:
+                self._drop(node_id)
+                stream.close(e)
+                raise
             self.stats["complete_sends"] += 1
         return stream
 
@@ -519,14 +536,21 @@ class BatchedAssignmentFanout:
             if type_ == AssignmentsMessage.INCREMENTAL \
                     and not aset.changes:
                 return
-            chunk: Dict[tuple, tuple] = {}
-            for key in list(aset.changes)[:limit]:
-                chunk[key] = aset.changes.pop(key)
-            self._seq[node_id] += 1
-            results_in = str(self._seq[node_id])
-            msg = AssignmentsMessage(type_, self._applies[node_id],
-                                     results_in, list(chunk.values()))
-            stream._push(msg)
+            # diff build (assignments.go message assembly) + delivery
+            t0 = time.perf_counter()
+            with tracer.span("dispatcher.assignments_send", "dispatcher",
+                             type=type_) as sp:
+                chunk: Dict[tuple, tuple] = {}
+                for key in list(aset.changes)[:limit]:
+                    chunk[key] = aset.changes.pop(key)
+                self._seq[node_id] += 1
+                results_in = str(self._seq[node_id])
+                msg = AssignmentsMessage(type_, self._applies[node_id],
+                                         results_in, list(chunk.values()))
+                if sp is not None:
+                    sp.args["changes"] = len(msg.changes)
+                stream._push(msg)
+            self.d._build_timer.observe(time.perf_counter() - t0)
             self._applies[node_id] = results_in
             self.stats["sends"] += 1
             _metrics.counter(
@@ -549,18 +573,26 @@ class BatchedAssignmentFanout:
         for an unknown node that registers a moment later."""
         t0 = time.perf_counter()
         with self._drain_mu:
-            self._flush_locked()
+            with tracer.span("dispatcher.fanout_flush", "dispatcher") as sp:
+                sends0 = self.stats["sends"]
+                events, streams = self._flush_locked()
+                if sp is not None:
+                    sp.args = {"events": events, "streams": streams,
+                               "sends": self.stats["sends"] - sends0}
         _planes.plane(_planes.DISPATCHER).note_busy(
             time.perf_counter() - t0)
 
-    def _flush_locked(self) -> None:
+    def _flush_locked(self) -> Tuple[int, int]:
+        """Route, retry, send; returns (events drained, live sets)."""
         with self._mu:
             live = dict(self._sets)
         tx = None
+        drained = 0
         while True:
             ev = self._sub.poll()
             if ev is None:
                 break
+            drained += 1
             if isinstance(ev, EventTaskBlock):
                 per_node = ev.per_node()
                 for node_id, aset in live.items():
@@ -592,9 +624,12 @@ class BatchedAssignmentFanout:
             if stream is None or stream.closed:
                 self._drop(node_id)
                 continue
+            if aset.pending_secret_retry:
+                aset.retry_pending_secrets(self.d.store.view())
             if aset.changes:
                 self._send(node_id, aset, stream,
                            AssignmentsMessage.INCREMENTAL)
+        return drained, len(live)
 
     def stop(self) -> None:
         with self._mu:
@@ -664,10 +699,9 @@ class Dispatcher:
         self._running = False
         self._stop = threading.Event()
         self._worker: Optional[threading.Thread] = None
-        self._streams_threads: List[threading.Thread] = []
-        #: batched assignment fan-out (enable_batched_fanout): replaces
-        #: the thread-per-stream assignments loop with one subscription
-        #: + per-node batched flushes driven from process_deadlines
+        #: the assignments fan-out every stream is served from: one store
+        #: subscription + per-node batched flushes driven from
+        #: process_deadlines; made by run(), torn down by stop()
         self.fanout: Optional[BatchedAssignmentFanout] = None
         self.stats = {"heartbeats": 0, "expirations": 0,
                       "sheds": 0, "hb_stretches": 0,
@@ -738,6 +772,7 @@ class Dispatcher:
                 accepts_blocks=True)   # blocks are never cluster events
             self._load_cluster_config()
             self._mark_nodes_unknown()
+            self.enable_batched_fanout()
             if start_worker:
                 self._worker = threading.Thread(
                     target=self._worker_loop, name="dispatcher",
@@ -766,8 +801,8 @@ class Dispatcher:
                 self._push_deadline(deadline, "reg", n.id)
 
     def enable_batched_fanout(self) -> "BatchedAssignmentFanout":
-        """Switch ``open_assignments`` to the batched, threadless
-        fan-out (call after ``run``).  Idempotent."""
+        """The assignments fan-out, made here if it is not there yet
+        (``run`` makes it).  Idempotent."""
         if self.fanout is None:
             self.fanout = BatchedAssignmentFanout(self)
         return self.fanout
@@ -1289,157 +1324,10 @@ class Dispatcher:
     def open_assignments(self, node_id: str,
                          session_id: str) -> AssignmentStream:
         """Start an assignments stream for the node
-        (reference: dispatcher.go:1013).  With the batched fan-out
-        enabled there is no per-stream thread — diffs flow through the
-        shared flush pass."""
-        if self.fanout is not None:
-            return self.fanout.open(node_id, session_id)
-        self._check_session(node_id, session_id)
-        stream = AssignmentStream(node_id)
-        with self._mu:
-            rn = self._nodes.get(node_id)
-            if rn is None or rn.session_id != session_id:
-                raise ErrSessionInvalid(node_id)
-            rn.streams.append(stream)
-        t = threading.Thread(
-            target=self._assignments_loop, args=(stream, node_id, session_id),
-            name=f"assignments-{node_id[:8]}", daemon=True)
-        t.start()
-        return stream
-
-    def _assignments_loop(self, stream: AssignmentStream, node_id: str,
-                          session_id: str) -> None:
-        aset = _AssignmentSet(node_id,
-                              driver_provider=self.driver_provider,
-                              terminal_bound=self.config
-                              .max_terminal_tasks)
-        sequence = 0
-        applies_to = ""
-
-        def send(type_) -> None:
-            nonlocal sequence, applies_to
-            sequence += 1
-            results_in = str(sequence)
-            # diff build (assignments.go message assembly) + delivery
-            t0 = time.perf_counter()
-            with tracer.span("dispatcher.assignments_send", "dispatcher",
-                             type=type_) as sp:
-                msg = aset.message(type_, applies_to, results_in)
-                if sp is not None:
-                    sp.args["changes"] = len(msg.changes)
-                stream._push(msg)
-            self._build_timer.observe(time.perf_counter() - t0)
-            _metrics.counter(
-                f'swarm_dispatcher_assignments_sent{{type="{type_}"}}')
-            _metrics.counter("swarm_dispatcher_assignment_changes",
-                             len(msg.changes))
-            _note_assignment_ship(msg)
-            applies_to = results_in
-
-        def pred(ev):
-            if isinstance(ev, EventTaskBlock):
-                # deliver every block; the session loop probes its own
-                # node against the block's shared per-node grouping on
-                # the CONSUMER thread — predicates run on the committing
-                # writer's thread, which must stay O(1) per subscriber
-                return True
-            if not isinstance(ev, Event):
-                return False
-            if isinstance(ev.obj, Volume):
-                return True   # filtered against tracked deps in the loop
-            return (isinstance(ev.obj, Task)
-                    and ev.obj.node_id == node_id)
-
-        def init(tx):
-            return list(tx.find(Task, ByNode(node_id)))
-
-        try:
-            initial, sub = self.store.view_and_watch(init, predicate=pred,
-                                                     accepts_blocks=True)
-        except Exception as e:
-            stream.close(e)
-            return
-        # dependency assembly — including possibly-slow driver-secret
-        # plugin fetches — runs OUTSIDE view_and_watch's init callback:
-        # init holds the store's update lock, and a slow (or store-
-        # calling) provider plugin must not stall or deadlock every
-        # store write.  Events queued since the snapshot replay after
-        # and re-adds are idempotent.
-        tx0 = self.store.view()
-        for t in initial:
-            aset.add_or_update_task(tx0, t)
-        try:
-            send(AssignmentsMessage.COMPLETE)
-            cfg = self.config
-            while not stream.closed and not self._stop.is_set():
-                try:
-                    self._check_session(node_id, session_id)
-                except DispatcherError as e:
-                    stream.close(e)
-                    return
-                modifications = 0
-                deadline = None
-                while modifications < cfg.modification_batch_limit:
-                    if stream.closed or self._stop.is_set():
-                        return
-                    timeout = 0.2 if deadline is None else \
-                        max(0.0, min(0.2, deadline - now()))
-                    try:
-                        ev = sub.get(timeout=timeout) if timeout > 0 \
-                            else None
-                    except TimeoutError:
-                        if deadline is None:
-                            if aset.pending_secret_retry and \
-                                    aset.retry_pending_secrets(
-                                        self.store.view()):
-                                modifications += 1
-                                deadline = now() + \
-                                    cfg.assignment_batching_wait
-                            continue
-                        ev = None
-                    except Closed:
-                        stream.close()
-                        return
-                    if ev is None:
-                        if deadline is not None and now() >= deadline:
-                            break
-                        if aset.pending_secret_retry and \
-                                aset.retry_pending_secrets(
-                                    self.store.view()):
-                            modifications += 1
-                            deadline = now() + \
-                                cfg.assignment_batching_wait
-                        continue
-                    if isinstance(ev, EventTaskBlock):
-                        # scheduler block: only this node's slice matters;
-                        # raw_get materializes each task lazily from the
-                        # store overlay (the same object every reader sees)
-                        tx = self.store.view()
-                        modified = False
-                        for old, _ver in ev.per_node().get(node_id, ()):
-                            t = self.store.raw_get(Task, old.id)
-                            if t is None:
-                                continue
-                            modified |= aset.add_or_update_task(tx, t)
-                        if modified:
-                            modifications += 1
-                            deadline = now() + cfg.assignment_batching_wait
-                        continue
-                    t = ev.obj
-                    if isinstance(t, Volume):
-                        modified = (ev.action != "delete"
-                                    and aset.update_volume(t))
-                    elif ev.action == "delete":
-                        modified = aset.remove_task(t)
-                    else:
-                        tx = self.store.view()
-                        modified = aset.add_or_update_task(tx, t)
-                    if modified:
-                        modifications += 1
-                        deadline = now() + cfg.assignment_batching_wait
-                    if stream.closed or self._stop.is_set():
-                        return
-                if modifications > 0:
-                    send(AssignmentsMessage.INCREMENTAL)
-        finally:
-            self.store.queue.unsubscribe(sub)
+        (reference: dispatcher.go:1013): a COMPLETE set now, then
+        INCREMENTAL diffs from the dispatcher's one fan-out.  No stream
+        has a thread or a store subscription of its own."""
+        fanout = self.fanout
+        if fanout is None:
+            raise DispatcherError("dispatcher is not running")
+        return fanout.open(node_id, session_id)

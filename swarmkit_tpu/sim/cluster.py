@@ -41,7 +41,6 @@ from __future__ import annotations
 
 import heapq
 import json
-import os
 from typing import Callable, Dict, List, Optional
 
 from ..manager.dispatcher import Config_ as DispatcherConfig, Dispatcher, \
@@ -1868,13 +1867,10 @@ class RaftControlPlane:
         d.reg_grace_check = \
             lambda nid: self.session_owner.get(nid) is None
         self.apply_overload_seams(d)
+        # run() brings the plane's assignments fan-out up with it: one
+        # store subscription per plane, per-node batched flushes driven
+        # from process_deadlines — the ≤⌈N/batch⌉-sends contract
         d.run(start_worker=False)
-        if os.environ.get("SWARM_BATCH_FANOUT", "1") != "0":
-            # batched assignment fan-out is the DEFAULT consumer plane
-            # (ISSUE 13 satellite; opt-out escape hatch only): one store
-            # subscription per plane, per-node batched flushes driven
-            # from process_deadlines — the ≤⌈N/batch⌉-sends contract
-            d.enable_batched_fanout()
         self._planes[m.id] = (m.store, d)
         return d
 

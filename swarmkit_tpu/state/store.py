@@ -1100,7 +1100,8 @@ class MemoryStore:
                     else:
                         apply_chunk()
                     committed_idx.extend(committed)
-                with tracer.span("commit.publish", "commit"):
+                with tracer.span("commit.publish", "commit",
+                                 subscribers=self.queue.subscriber_count()):
                     if want_events:
                         publish = self.queue.publish
                         for ev in events:
@@ -1253,7 +1254,8 @@ class MemoryStore:
                                 ("block", base, olds_c, nids_c,
                                  int(state), message, ts),
                                 len(committed_idx))
-            with tracer.span("commit.publish", "commit"):
+            with tracer.span("commit.publish", "commit",
+                             subscribers=self.queue.subscriber_count()):
                 if olds_c and self.queue.has_subscribers():
                     # one coalesced event for the whole block; per-task
                     # events synthesize lazily, shared across subscribers
@@ -1368,7 +1370,9 @@ class MemoryStore:
                     return False
                 committed_idx.extend(chunk)
                 if self.queue.has_subscribers():
-                    with tracer.span("commit.publish", "commit"):
+                    with tracer.span(
+                            "commit.publish", "commit",
+                            subscribers=self.queue.subscriber_count()):
                         self.queue.publish(EventTaskBlock(
                             olds_c, nids_c, cb_base, state, message, ts))
                 return True
@@ -1456,14 +1460,17 @@ class MemoryStore:
                         continue
                     committed_idx.extend(chunk)
                     if self.queue.has_subscribers():
-                        with tracer.span("commit.publish", "commit"):
+                        with tracer.span(
+                                "commit.publish", "commit",
+                                subscribers=self.queue.subscriber_count()):
                             self.queue.publish(EventTaskBlock(
                                 olds_c, nids_c, chunk_base, state,
                                 message, ts))
                 chunk_base += len(chunk)
             while pending:
                 reap(pending.popleft())
-            with tracer.span("commit.publish", "commit"):
+            with tracer.span("commit.publish", "commit",
+                             subscribers=self.queue.subscriber_count()):
                 self.queue.publish(EventCommit(self._version))
         for old, nid in missing:
             on_missing(old, nid)

@@ -276,6 +276,9 @@ class Queue:
     def has_subscribers(self) -> bool:
         return bool(self._subs)
 
+    def subscriber_count(self) -> int:
+        return len(self._subs)
+
     def publish_all(self, events: Iterable[Any]) -> None:
         for e in events:
             self.publish(e)

@@ -622,7 +622,7 @@ def test_csi_volume_lifecycle_e2e_from_cli():
 
     m = Manager(dispatcher_config=Config_(
         heartbeat_period=0.3, heartbeat_epsilon=0.02,
-        process_updates_interval=0.02, assignment_batching_wait=0.02),
+        process_updates_interval=0.02),
         use_device_scheduler=False)
     m.run()
     api2 = m.control_api
@@ -708,7 +708,7 @@ def test_node_side_csi_staging_with_process_executor(tmp_path):
 
     m = Manager(dispatcher_config=Config_(
         heartbeat_period=0.3, heartbeat_epsilon=0.02,
-        process_updates_interval=0.02, assignment_batching_wait=0.02),
+        process_updates_interval=0.02),
         use_device_scheduler=False)
     m.run()
     api2 = m.control_api

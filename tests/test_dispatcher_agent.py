@@ -6,6 +6,7 @@ restart elsewhere (mirrors manager/dispatcher/dispatcher_test.go and
 integration/integration_test.go behaviors).
 """
 
+import threading
 import time
 
 import pytest
@@ -43,7 +44,7 @@ def store():
 def fast_config(**kw):
     defaults = dict(heartbeat_period=0.3, heartbeat_epsilon=0.02,
                     grace_multiplier=3, process_updates_interval=0.02,
-                    assignment_batching_wait=0.02, orphan_timeout=2.0)
+                    orphan_timeout=2.0)
     defaults.update(kw)
     return Config_(**defaults)
 
@@ -163,6 +164,10 @@ def test_assignments_stream_complete_and_incremental(store):
     try:
         session, _ = d.register(node.id)
         stream = d.open_assignments(node.id, session)
+        # served by the dispatcher's one fan-out, not a thread of its own
+        assert d.fanout._streams[node.id] is stream
+        assert not [t for t in threading.enumerate()
+                    if t.name.startswith("assignments-")]
         msg = stream.get(timeout=2)
         assert msg.type == "complete"
         assert [obj.id for _, kind, obj in msg.changes
@@ -225,6 +230,7 @@ def test_assignments_stream_from_block_commit_with_raft(store, tmp_path):
         s2, _ = d.register(n2.id)
         st1 = d.open_assignments(n1.id, s1)
         st2 = d.open_assignments(n2.id, s2)
+        assert d.fanout._streams == {n1.id: st1, n2.id: st2}
         assert st1.get(timeout=2).type == "complete"
         assert st2.get(timeout=2).type == "complete"
 
@@ -505,9 +511,8 @@ def test_driver_secret_fetch_error_skips_assignment(store):
 
 
 def test_driver_secret_retries_until_provider_recovers(store):
-    """A transient provider outage heals: the assignments loop retries
-    failed fetches on idle ticks and ships the secret once the provider
-    answers."""
+    """A transient provider outage heals: the fan-out's flush retries
+    failed fetches and ships the secret once the provider answers."""
     from swarmkit_tpu.manager.drivers import DriverProvider
     from swarmkit_tpu.models import Secret
     from swarmkit_tpu.models.specs import ContainerSpec, SecretSpec, TaskSpec
@@ -544,11 +549,12 @@ def test_driver_secret_retries_until_provider_recovers(store):
     try:
         session, _ = d.register(node.id)
         stream = d.open_assignments(node.id, session)
+        assert d.fanout._streams[node.id] is stream
         msg = stream.get(timeout=2)
         assert msg.type == "complete"
         assert not [o for _, k, o in msg.changes if k == "secret"]
 
-        # the loop's idle-tick retry eventually ships it
+        # a later flush's retry ships it
         deadline = time.time() + 10
         got = None
         while time.time() < deadline:

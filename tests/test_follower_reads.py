@@ -702,17 +702,14 @@ def test_checker_fires_on_dropped_resume_token():
 # ----------------------------------------------------------- slow sweeps
 
 @pytest.mark.slow
-def test_follower_planes_get_batched_fanout_by_default(monkeypatch):
-    """ISSUE 13 satellite: the follower-served dispatcher planes come up
-    with the batched assignment fan-out ON (opt-out via
-    SWARM_BATCH_FANOUT=0, not opt-in), and a session gap through a
-    plane rebuilds a COMPLETE set with nothing lost or duplicated."""
+def test_follower_planes_get_batched_fanout_by_default():
+    """The follower-served dispatcher planes come up with the assignment
+    fan-out, the one path there is, each over its own store, and a
+    session gap through a plane rebuilds a COMPLETE set with nothing
+    lost or duplicated."""
     _quiet()
     from swarmkit_tpu.models import TaskState, TaskStatus
     from swarmkit_tpu.state.store import ByNode
-
-    # pin the default-on half against an inherited escape hatch
-    monkeypatch.delenv("SWARM_BATCH_FANOUT", raising=False)
 
     def _mk_assigned(sim, nid, start, n):
         """Assigned tasks for ``nid`` written through the LEADER store
@@ -789,15 +786,19 @@ def test_follower_planes_get_batched_fanout_by_default(monkeypatch):
                      if kind == "task")
         assert got == want and len(want) == 8
         assert len(got) == len(set(got))
-    # opt-out: the escape hatch restores the thread-per-stream plane
-    monkeypatch.setenv("SWARM_BATCH_FANOUT", "0")
+    # no opt-out: every plane's fan-out subscribes to its own member's
+    # store, and the accessor hands back that one fan-out
     with _agentless_sim(seed=5) as sim2:
         cp2 = sim2.cp
         cp2.enable_follower_reads()
         leader2 = sim2.leader()
         follower2 = next(m for m in sim2.managers if m is not leader2)
         plane2 = cp2.plane_for(follower2)
-        assert plane2 is not None and plane2.fanout is None
+        assert plane2 is not None and plane2.fanout is not None
+        assert plane2.enable_batched_fanout() is plane2.fanout
+        assert plane2.store is follower2.store
+        assert plane2.fanout._sub in follower2.store.queue._subs
+        assert plane2.fanout._sub not in leader2.store.queue._subs
 
 
 def test_read_scenarios_20_seed_sweep_byte_identical():

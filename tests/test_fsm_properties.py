@@ -95,8 +95,7 @@ def test_fsm_invariants_under_cluster_churn():
 
     d = Dispatcher(store, Config_(heartbeat_period=0.3,
                                   heartbeat_epsilon=0.02,
-                                  process_updates_interval=0.02,
-                                  assignment_batching_wait=0.02))
+                                  process_updates_interval=0.02))
     d.run()
     alloc = Allocator(store)
     sched = Scheduler(store)

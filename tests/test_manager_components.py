@@ -39,8 +39,7 @@ requires_crypto = pytest.mark.skipif(
 
 def fast_cfg():
     return Config_(heartbeat_period=0.3, heartbeat_epsilon=0.02,
-                   process_updates_interval=0.02,
-                   assignment_batching_wait=0.02)
+                   process_updates_interval=0.02)
 
 
 # --------------------------------------------------------------- CA / tokens
