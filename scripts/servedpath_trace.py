@@ -21,7 +21,9 @@ and, weighted by the tasks a span carried, as means over tasks; with the
 client's side of the same path at the median: the generator's lateness
 and the lag to PENDING), the traced window's own ``assign_p50_ms`` (a
 ``--trace 1`` line has the per-layer metrics alone), the CPU seconds by
-thread, and the largest service of the window (to follow).
+thread and by the thread's family (``obs/threadcpu.py``), what the
+accountant's snapshots cost (``threadcpu``), and the largest service of
+the window (to follow).
 
 ``--kept`` runs a cell that ``BENCHMARK.json`` does not list, kept as data
 files (``tests/benchmark/rehearse_cells.py KEPT``), through
@@ -143,6 +145,12 @@ def summary(doc: dict, series: dict = None) -> dict:
             who = re.sub(r"-\d+$", "", thread_of.get(e["tid"], "?"))
             lock_by_waiter[who] = lock_by_waiter.get(who, 0.0) \
                 + e["dur"] / 1e6
+    from swarmkit_tpu.obs import threadcpu
+    by_family = {}
+    for name, used in ((doc.get("otherData") or {}).get("thread_cpu_s")
+                       or {}).items():
+        fam = threadcpu.family(name)
+        by_family[fam] = round(by_family.get(fam, 0.0) + used, 6)
     legs = before_the_tick(events)
     series = series or {}
     for key, name in (("generator_late_p50_ms", "generator_late_s"),
@@ -179,6 +187,8 @@ def summary(doc: dict, series: dict = None) -> dict:
         if series.get("assign_s") else None,
         "thread_cpu_s": dict(sorted(threads.items(),
                                     key=lambda kv: -kv[1])),
+        "thread_cpu_s_by_family": dict(sorted(by_family.items(),
+                                              key=lambda kv: -kv[1])),
         "dropped_spans": (doc.get("otherData") or {}).get(
             "dropped_spans"),
         "largest_service": [largest, deploys.get(largest)],
@@ -221,7 +231,7 @@ def main(argv=None) -> int:
             if args.cpu else None, require_tpu=not args.cpu,
             cell=rehearse_cells.KEPT.get(args.workload))
     from benchmark import harness
-    from swarmkit_tpu.obs import tracer
+    from swarmkit_tpu.obs import threadcpu, tracer
     # what ``run_cell`` hands the readers and does not return: the
     # clients' series of the window
     observed, read_all = [], harness.readers.read_all
@@ -240,9 +250,10 @@ def main(argv=None) -> int:
     os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
     with open(args.out + ".trace.json", "w") as f:
         json.dump(doc, f, separators=(",", ":"))
-    print("servedpath " + json.dumps(
-        summary(doc, observed[-1].series if observed else None)),
-        flush=True)
+    made = summary(doc, observed[-1].series if observed else None)
+    # the accountant's own cost over the traced window
+    made["threadcpu"] = dict(threadcpu.stats)
+    print("servedpath " + json.dumps(made), flush=True)
     return code
 
 

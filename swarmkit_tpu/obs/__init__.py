@@ -11,12 +11,14 @@ invariant violations).  ``HealthEvaluator``
 judges declarative SLO checks over the registry and serves
 ``/debug/health``.  Metrics counters and timers live in
 ``utils.metrics.registry`` — this package adds the span/trace dimension
-and the derived planes on top.
+and the derived planes on top.  ``threadcpu`` says which families of
+threads used the CPU while a span's thread waited.
 """
 
 from . import debugpages  # noqa: F401  (installs /debug/* endpoint hook)
 from . import devicetelemetry  # noqa: F401  (device-plane ledger)
 from . import planes  # noqa: F401  (per-plane saturation signals)
+from . import threadcpu  # noqa: F401  (CPU by thread family, a span)
 from .flightrec import FlightRecorder, flightrec
 from .health import Check, HealthEvaluator
 from .journey import JourneyLedger, journeys
@@ -33,5 +35,6 @@ __all__ = [
     "LifecycleTracker", "Sampler", "Span", "Tracer",
     "devicetelemetry", "diff_phase_tables", "flightrec",
     "format_diff", "format_table", "journeys",
-    "phase_table", "planes", "tracer", "validate_chrome_trace",
+    "phase_table", "planes", "threadcpu", "tracer",
+    "validate_chrome_trace",
 ]

@@ -64,6 +64,7 @@ import time
 from typing import Any, Dict, List, Optional
 
 from ..models import types as _types
+from .threadcpu import read_threads
 
 
 class Span:
@@ -131,18 +132,9 @@ class _SpanCtx:
 def _thread_cpu_table() -> Dict[str, float]:
     """CPU seconds of every live thread so far, summed by thread name;
     empty where the platform has no per-thread CPU clock."""
-    clock_id = getattr(time, "pthread_getcpuclockid", None)
     out: Dict[str, float] = {}
-    if clock_id is None:
-        return out
-    for t in threading.enumerate():
-        if t.ident is None:
-            continue
-        try:
-            used = time.clock_gettime(clock_id(t.ident))
-        except (OSError, ValueError, OverflowError):
-            continue       # the thread ended between the two calls
-        out[t.name] = out.get(t.name, 0.0) + used
+    for name, used in read_threads().values():
+        out[name] = out.get(name, 0.0) + used
     return out
 
 

@@ -27,6 +27,7 @@ from ..models.types import (
     Resources, TaskState, TaskStatus, now, time_source_installed,
 )
 from ..obs import planes as _planes
+from ..obs import threadcpu
 from ..obs.trace import tracer
 from ..utils.metrics import registry as _metrics
 from ..utils.pipeline import default_pipeline_depth
@@ -743,6 +744,9 @@ class Scheduler:
         """Schedule the unassigned queue; returns number of decisions."""
         from ..utils.gctune import paused_gc
         t0 = now()
+        # the other threads' CPU while the tick ran: who held the
+        # interpreter while it was off the CPU (nothing read when off)
+        threads0 = threadcpu.begin()
         with paused_gc(), tracer.span("sched.tick", "sched") as sp:
             n = self._tick_inner()
             if sp is not None:
@@ -751,6 +755,7 @@ class Scheduler:
             # wall minus thread CPU: what the tick spent off the CPU
             # (the GIL, the update lock, the device, the committer)
             sp.args["offcpu_ms"] = round((sp.duration - sp.cpu) * 1e3, 3)
+            threadcpu.end(sp, threads0)
         _dt = now() - t0
         _TICK_TIMER.observe(_dt)
         _planes.plane(_planes.SCHEDULER).note_busy(_dt)

@@ -1100,14 +1100,28 @@ class MemoryStore:
                     else:
                         apply_chunk()
                     committed_idx.extend(committed)
-                with tracer.span("commit.publish", "commit",
-                                 subscribers=self.queue.subscriber_count()):
+                with self._publishing() as delivered:
                     if want_events:
                         publish = self.queue.publish
                         for ev in events:
-                            publish(ev)
-                    self.queue.publish(EventCommit(self._version))
+                            publish(ev, delivered)
+                    self.queue.publish(EventCommit(self._version), delivered)
         return committed_idx, failed_idx
+
+    @contextlib.contextmanager
+    def _publishing(self):
+        """The ``commit.publish`` span around a commit's publish.  It
+        carries ``subscribers``, the queue's subscriptions, and
+        ``delivered``, those that buffered at least one of the events
+        published inside it (a block buffered whole for a per-item
+        consumer among them): the threads the commit wakes.  Yields the
+        set to hand ``Queue.publish``; None with the tracer off."""
+        with tracer.span("commit.publish", "commit",
+                         subscribers=self.queue.subscriber_count()) as sp:
+            delivered = None if sp is None else set()
+            yield delivered
+            if sp is not None:
+                sp.args["delivered"] = len(delivered)
 
     @property
     def supports_block_commit(self) -> bool:
@@ -1254,14 +1268,14 @@ class MemoryStore:
                                 ("block", base, olds_c, nids_c,
                                  int(state), message, ts),
                                 len(committed_idx))
-            with tracer.span("commit.publish", "commit",
-                             subscribers=self.queue.subscriber_count()):
+            with self._publishing() as delivered:
                 if olds_c and self.queue.has_subscribers():
                     # one coalesced event for the whole block; per-task
                     # events synthesize lazily, shared across subscribers
                     self.queue.publish(EventTaskBlock(
-                        olds_c, nids_c, base, int(state), message, ts))
-                self.queue.publish(EventCommit(self._version))
+                        olds_c, nids_c, base, int(state), message, ts),
+                        delivered)
+                self.queue.publish(EventCommit(self._version), delivered)
         for old, nid in missing:
             on_missing(old, nid)
         return committed_idx, failed_idx
@@ -1370,11 +1384,10 @@ class MemoryStore:
                     return False
                 committed_idx.extend(chunk)
                 if self.queue.has_subscribers():
-                    with tracer.span(
-                            "commit.publish", "commit",
-                            subscribers=self.queue.subscriber_count()):
+                    with self._publishing() as delivered:
                         self.queue.publish(EventTaskBlock(
-                            olds_c, nids_c, cb_base, state, message, ts))
+                            olds_c, nids_c, cb_base, state, message, ts),
+                            delivered)
                 return True
 
             pos = 0
@@ -1460,18 +1473,15 @@ class MemoryStore:
                         continue
                     committed_idx.extend(chunk)
                     if self.queue.has_subscribers():
-                        with tracer.span(
-                                "commit.publish", "commit",
-                                subscribers=self.queue.subscriber_count()):
+                        with self._publishing() as delivered:
                             self.queue.publish(EventTaskBlock(
                                 olds_c, nids_c, chunk_base, state,
-                                message, ts))
+                                message, ts), delivered)
                 chunk_base += len(chunk)
             while pending:
                 reap(pending.popleft())
-            with tracer.span("commit.publish", "commit",
-                             subscribers=self.queue.subscriber_count()):
-                self.queue.publish(EventCommit(self._version))
+            with self._publishing() as delivered:
+                self.queue.publish(EventCommit(self._version), delivered)
         for old, nid in missing:
             on_missing(old, nid)
         return committed_idx, failed_idx

@@ -45,7 +45,9 @@ class Subscription:
         self.accepts_blocks = accepts_blocks
 
     # -- producer side -----------------------------------------------------
-    def _publish(self, event: Any) -> None:
+    def _publish(self, event: Any) -> bool:
+        """Buffer ``event`` if it is for this subscription; True where it
+        was buffered, so the consumer's thread will wake."""
         if not self.accepts_blocks \
                 and getattr(event, "expand_events", None) is not None:
             # coalesced block for a per-item consumer: buffer the block
@@ -56,33 +58,34 @@ class Subscription:
             # only its own predicate filter.
             with self._cond:
                 if self._closed:
-                    return
+                    return False
                 if self._limit is not None and \
                         len(self._buf) + len(event) > self._limit:
                     self.overflowed = True
                     self._closed = True
                     self._cond.notify_all()
-                    return
+                    return False
                 self._buf.append(event)
                 self._cond.notify()
-            return
+            return True
         if self._predicate is not None:
             try:
                 if not self._predicate(event):
-                    return
+                    return False
             except Exception:
-                return
+                return False
         with self._cond:
             if self._closed:
-                return
+                return False
             if self._limit is not None and len(self._buf) >= self._limit:
                 # close-on-overflow: consumer must resync
                 self.overflowed = True
                 self._closed = True
                 self._cond.notify_all()
-                return
+                return False
             self._buf.append(event)
             self._cond.notify()
+        return True
 
     # -- consumer side -----------------------------------------------------
     def _needs_expand(self, item: Any) -> bool:
@@ -267,11 +270,14 @@ class Queue:
         self._lock = threading.Lock()
         self._subs: List[Subscription] = []
 
-    def publish(self, event: Any) -> None:
+    def publish(self, event: Any, delivered: Optional[set] = None) -> None:
+        """Hand ``event`` to every subscription; where ``delivered`` is a
+        set, add to it each subscription that buffered the event."""
         with self._lock:
             subs = list(self._subs)
         for sub in subs:
-            sub._publish(event)
+            if sub._publish(event) and delivered is not None:
+                delivered.add(sub)
 
     def has_subscribers(self) -> bool:
         return bool(self._subs)
